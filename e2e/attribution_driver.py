@@ -122,7 +122,7 @@ def run() -> dict:
         # -- 4: attribution fractions reconstruct the measured step ----------
         cost = price_callable(train_step, w, x, name="train_step",
                               kind="step")
-        report = attribution_report([cost], clock=clock)
+        report = attribution_report([cost], clock=clock, generation="v5e")
         frac_sum = sum(report.fractions.values())
         assert abs(frac_sum - 1.0) < 1e-6, report.fractions
         reconstructed = sum(report.measured.values())

@@ -10,9 +10,9 @@ the standard anti-hoist carry and host-fetch barrier:
   optimizer  AdamW update alone over the full param set
 
 The full-step reference point is the bench itself (`BENCH_MODEL=gpt
-python bench.py`, ~218 ms at 42.4% MFU). NOTE the towers are bounds, not
-addends: 24 x block measured ABOVE the full step — XLA schedules the full
-graph better than any isolated piece (BASELINE.md round-4 notes).
+python bench.py`). NOTE the towers are bounds, not addends: 24 x block has
+measured ABOVE the full step — XLA schedules the full graph better than any
+isolated piece (BASELINE.md, older findings).
 
 Run:  python -m e2e.gpt_profile [--batch 8] [--seq 1024]
 """
@@ -27,8 +27,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
-# one copy of the honest timing harness (and its compile-cache setup):
-# importing ceiling applies the jax_compilation_cache_dir config too
+# one copy of the honest timing harness
 from e2e.ceiling import _timed as _scan_time
 
 
@@ -135,6 +134,9 @@ def profile(batch: int = 8, seq: int = 1024, steps: int = 20) -> List[Dict[str, 
 
 
 def main(argv=None) -> int:
+    from kubeflow_tpu.tpu.env import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=1024)

@@ -116,7 +116,9 @@ def run() -> dict:
     api_port = _free_port()
     base = f"http://127.0.0.1:{api_port}"
     wal_dir = tempfile.mkdtemp(prefix="ha-chaos-wal-")
-    api_env = {**os.environ, "API_PORT": str(api_port),
+    # control-plane roles need no chip, and a chip belongs to one process
+    child_env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    api_env = {**child_env, "API_PORT": str(api_port),
                "APISERVER_WAL_DIR": wal_dir,
                "APISERVER_WAL_SNAPSHOT_EVERY": SNAPSHOT_EVERY}
     procs: dict = {}
@@ -130,7 +132,7 @@ def run() -> dict:
         sched_ops[key] = f"http://127.0.0.1:{_free_port()}"
         procs[key] = subprocess.Popen(
             [sys.executable, "-m", "kubeflow_tpu.scheduler.core"],
-            env={**os.environ, "APISERVER_URL": base,
+            env={**child_env, "APISERVER_URL": base,
                  "METRICS_PORT": sched_ops[key].rsplit(":", 1)[1],
                  "ENABLE_LEADER_ELECTION": "true",
                  "LEASE_DURATION": LEASE_DURATION,

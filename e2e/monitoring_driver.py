@@ -30,6 +30,7 @@ tiny config, ~tens of seconds.
 from __future__ import annotations
 
 import json
+import os
 import subprocess
 import sys
 import threading
@@ -179,6 +180,8 @@ def run() -> dict:
         for i in range(OPS_PROCS):
             proc = subprocess.Popen(
                 [sys.executable, "-c", _OPS_SCRIPT],
+                # this process hosts the fleet; a chip has one owner
+                env={**os.environ, "JAX_PLATFORMS": "cpu"},
                 stdout=subprocess.PIPE, text=True)
             procs.append(proc)
             port = int(proc.stdout.readline().strip())

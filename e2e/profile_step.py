@@ -31,13 +31,6 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-try:
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ.get("JAX_COMPILATION_CACHE_DIR", "/tmp/jax-cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:
-    pass
-
 
 def _scan_time(fn, args, steps: int) -> float:
     out = fn(*args)
@@ -121,6 +114,9 @@ def profile(batch: int = 256, steps: int = 30) -> Dict[str, Any]:
 
 
 def main() -> None:
+    from kubeflow_tpu.tpu.env import enable_compile_cache
+
+    enable_compile_cache()
     out = profile(batch=int(os.environ.get("PROFILE_BATCH", "256")))
     rows = out["seconds"]
     full = rows["full_step"]

@@ -1,4 +1,4 @@
-"""Serving benchmarks on the real chip (VERDICT r2 #4 / round-1 ask #7).
+"""Serving benchmarks on the chip (VERDICT r2 #4 / round-1 ask #7).
 
 Two rows, mirroring the reference's serving e2e shape
 (testing/test_tf_serving.py:108-133 — HTTP predict against a served model):
@@ -15,7 +15,7 @@ Two rows, mirroring the reference's serving e2e shape
    (models/gpt.py:generate) — steady-state decode tokens/s at batch 1/8.
 
 Run via ``BENCH_MODEL=serving python bench.py`` or directly. Prints a table
-plus one JSON line per row; BASELINE.md records the measured numbers.
+plus one JSON line per row.
 """
 
 from __future__ import annotations
@@ -29,13 +29,6 @@ from typing import Any, Dict, List
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ.get("JAX_COMPILATION_CACHE_DIR", "/tmp/jax-cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:
-    pass
 
 SEQ = 128
 
@@ -83,7 +76,7 @@ def bench_bert_http(batches=(1, 8, 32), requests_per_batch: int = 40) -> List[Di
             lat = sorted(request() for _ in range(requests_per_batch))
             p50 = statistics.median(lat)
             # With 40 samples, index 37 is a real p95; a "p99" here would
-            # just be the max (one tunnel hiccup), so report p95 + max.
+            # just be the max (one hiccup), so report p95 + max.
             p95 = lat[min(len(lat) - 1, int(len(lat) * 0.95) - 1)]
             rows.append({
                 "batch": batch,
@@ -385,6 +378,10 @@ def bench_disagg(slots: int = 8, n_requests: int = 24,
 
 
 def main() -> int:
+    from kubeflow_tpu.tpu.env import enable_compile_cache, require_tpu
+
+    require_tpu()
+    enable_compile_cache()
     bert = bench_bert_http()
     print(f"{'BERT-base predict (HTTP)':28s} {'p50':>8s} {'p95':>8s} {'max':>8s} {'seq/s':>8s}")
     for r in bert:

@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import time
 from functools import partial
 from typing import Any, Dict, List
@@ -29,13 +28,6 @@ from typing import Any, Dict, List
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-
-try:
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ.get("JAX_COMPILATION_CACHE_DIR", "/tmp/jax-cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:
-    pass
 
 
 class StemTower(nn.Module):
@@ -162,6 +154,9 @@ def time_tower(module: nn.Module, x_shape, steps: int) -> Dict[str, Any]:
 
 
 def main(argv=None) -> int:
+    from kubeflow_tpu.tpu.env import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=256)
     ap.add_argument("--steps", type=int, default=20)

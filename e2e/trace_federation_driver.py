@@ -113,13 +113,15 @@ def run() -> dict:
     closers: list = []
     try:
         # -- three processes: this driver, a real apiserver, a real scheduler
+        # (this process serves a model, so it alone may hold a chip)
+        child_env = {**os.environ, "JAX_PLATFORMS": "cpu"}
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "kubeflow_tpu.apiserver"],
-            env={**os.environ, "API_PORT": str(api_port)}))
+            env={**child_env, "API_PORT": str(api_port)}))
         RemoteStore(base).wait_ready(timeout=60.0)
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "kubeflow_tpu.scheduler.core"],
-            env={**os.environ, "APISERVER_URL": base,
+            env={**child_env, "APISERVER_URL": base,
                  "METRICS_PORT": str(ops_port)}))
         def ops_up():
             try:

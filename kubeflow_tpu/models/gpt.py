@@ -75,12 +75,9 @@ class GptConfig:
 def _kv_kernel_enabled() -> bool:
     """``KUBEFLOW_TPU_KV_KERNEL=1`` routes per-slot KV writes through the
     Pallas row-update kernel (ops/kv_cache.py); default is the whole-cache
-    where-select. Measured on the round-5 dev backend
-    (e2e/kv_update_probe.py): the two are within noise in-model (3.58 vs
-    3.66 ms/token at depth-3 pipelining) because the dispatch round trip,
-    not the on-device write, dominates — the kernel's 44x cache-traffic
-    saving is kept opt-in for direct-attached deployments where HBM
-    traffic is the decode bound."""
+    where-select. The kernel touches 44x less cache per write; whether
+    that shows in a decode step on today's chip is not measured
+    (e2e/kv_update_probe.py is the probe, ROADMAP C3 the decision)."""
     import os
 
     return os.environ.get("KUBEFLOW_TPU_KV_KERNEL", "0") == "1"

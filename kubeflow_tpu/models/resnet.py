@@ -212,11 +212,11 @@ class ResNet(nn.Module):
     """stem="s2d" folds the input 2x2 space-to-depth and runs the stem as a
     4x4/1 conv on 12 channels instead of 7x7/2 on 3 — the same receptive
     field (the 7x7 kernel zero-padded to 8x8 and regrouped onto the
-    half-res grid), but with 4x the channels feeding the MXU. Measured on
-    v5e (e2e/conv_experiments.py): the 3-channel 7x7 sustains 5.7 TF/s in
-    isolation vs 44.1 for the s2d form; in the full train step the win is
-    ~1% (XLA already treats the in-model stem better than the standalone
-    probe suggested — BASELINE.md round-4 notes). Default stays "conv7x7":
+    half-res grid), but with 4x the channels feeding the MXU. In isolation
+    (e2e/conv_experiments.py) the s2d form has measured several times the
+    3-channel 7x7's rate; in the full train step the win was ~1% (XLA
+    treats the in-model stem better than the standalone probe suggests —
+    BASELINE.md, older findings). Default stays "conv7x7":
     the s2d stem renames/reshapes conv_init in the param tree, which would
     silently break existing checkpoints and torchvision weight-shape
     parity; perf-sensitive callers (bench.py) opt in explicitly."""

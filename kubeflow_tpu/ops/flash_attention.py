@@ -73,10 +73,7 @@ def _auto_tile_cap() -> int:
     # get a 256 cap so the auto default stays within what the old 128x128
     # tiles compiled under (ADVICE r3: the big cap was a silent portability
     # regression for earlier generations).
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return 1024
+    kind = jax.devices()[0].device_kind.lower()
     return 256 if ("v2" in kind or "v3" in kind) else 1024
 
 

@@ -38,6 +38,15 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+
+def _mxu(w):
+    """Weights cross into the kernel already in the MXU dtype: the kernels
+    cast to bf16 before every dot anyway, so casting outside is the same
+    math with half the weight DMA and VMEM — as f32 the stage-4 blocks'
+    weights alone (17-25 MB) overflow the compiler's 16 MiB VMEM scope."""
+    return w.astype(jnp.bfloat16)
 
 
 def _pdot(a, b):
@@ -156,7 +165,7 @@ def fused_bottleneck(
         out_specs=pl.BlockSpec((1, hw, hw, cin), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
-    )(x, w1, s1, w2r, s2, w3, s3)
+    )(x, _mxu(w1), s1, _mxu(w2r), s2, _mxu(w3), s3)
 
 
 @jax.custom_vjp
@@ -234,9 +243,34 @@ def reference_bottleneck(x, w1, scale1, bias1, w2, scale2, bias2,
 # ---------------------------------------------------------------------------
 
 
+def _lane_group_scratch(h: int, w: int, c: int):
+    """f32 VMEM scratch holding an [h, w, c] image as [groups, h, w, width]
+    channel slabs. Mosaic's strided load wants a base ref whose minor dim is
+    exactly one 128-lane tile, so ResNet's widths (all multiples of 128 at
+    the stride-2 heads) park as c/128 slabs. Other widths stay one slab:
+    fine under the interpreter, refused by the chip's compiler."""
+    groups, width = (c // 128, 128) if c % 128 == 0 else (1, c)
+    return pltpu.VMEM((groups, h, w, width), jnp.float32)
+
+
+def _store_lane_groups(ref, v, hw: int):
+    """Write ``v`` [hw, hw, c] into ``ref`` [groups, >=hw, >=hw, width]."""
+    groups, width = ref.shape[0], ref.shape[-1]
+    for g in range(groups):
+        ref[g, 0:hw, 0:hw, :] = v[:, :, g * width:(g + 1) * width]
+
+
+def _strided_taps(ref, r0: int, c0: int, rows: int, cols: int):
+    """``[r0::2, c0::2]`` (``rows`` x ``cols`` taps) of the [h, w, c] image
+    parked in ``ref`` as lane groups -> [rows, cols, c]."""
+    return jnp.concatenate(
+        [ref[g, pl.ds(r0, rows, stride=2), pl.ds(c0, cols, stride=2), :]
+         for g in range(ref.shape[0])], axis=-1)
+
+
 def _transition_kernel(x_ref, w1_ref, s1_ref, w2_ref, s2_ref, w3_ref, s3_ref,
-                       wp_ref, sp_ref, o_ref,
-                       *, hw: int, ho: int, cin: int, cmid: int, cout: int,
+                       wp_ref, sp_ref, o_ref, *scratch,
+                       hw: int, ho: int, cin: int, cmid: int, cout: int,
                        stride: int, dot_dtype):
     x = x_ref[0]                                    # [hw, hw, cin]
     xm = x.reshape(hw * hw, cin)
@@ -246,17 +280,27 @@ def _transition_kernel(x_ref, w1_ref, s1_ref, w2_ref, s2_ref, w3_ref, s3_ref,
 
     # Strided implicit-GEMM 3x3. XLA SAME padding for stride 2, kernel 3 on
     # an even input is (lo=0, hi=1): out(i,j) taps in_pad[2i+di, 2j+dj].
-    # The 9 tap views become strided static slices of the padded h1 — the
-    # lane (channel) dim is untouched, so Mosaic lowers them directly.
-    h1sq = h1.reshape(hw, hw, cmid).astype(dot_dtype)
+    h1sq = h1.reshape(hw, hw, cmid)
     if stride == 1:
-        h1p = jnp.pad(h1sq, ((1, 1), (1, 1), (0, 0)))
+        h1p = jnp.pad(h1sq.astype(dot_dtype), ((1, 1), (1, 1), (0, 0)))
         views = [h1p[di:di + ho, dj:dj + ho, :]
                  for di in range(3) for dj in range(3)]
+        xs_ref = None
     else:
-        h1p = jnp.pad(h1sq, ((0, 2), (0, 2), (0, 0)))
-        views = [h1p[di:di + 2 * ho:2, dj:dj + 2 * ho:2, :]
+        # Mosaic has no strided slice of a VALUE (it lowers to a gather it
+        # refuses), but it does have strided loads from a REF, for 32-bit
+        # data only: park the padded h1 and x in f32 VMEM scratch and read
+        # each tap as one stride-2 load. bf16 -> f32 -> bf16 is exact.
+        h1p_ref, xs_ref = scratch
+        groups, _, _, width = h1p_ref.shape
+        h1p_ref[:, hw:hw + 2, :, :] = jnp.zeros(
+            (groups, 2, hw + 2, width), jnp.float32)
+        h1p_ref[:, 0:hw, hw:hw + 2, :] = jnp.zeros(
+            (groups, hw, 2, width), jnp.float32)
+        _store_lane_groups(h1p_ref, h1sq, hw)
+        views = [_strided_taps(h1p_ref, di, dj, ho, ho).astype(dot_dtype)
                  for di in range(3) for dj in range(3)]
+        _store_lane_groups(xs_ref, x.astype(jnp.float32), hw)
     cols = jnp.concatenate(
         [v.reshape(ho * ho, cmid) for v in views], axis=1)   # [ho*ho, 9*cmid]
     w2m = w2_ref[...].astype(dot_dtype).reshape(9 * cmid, cmid)
@@ -264,12 +308,10 @@ def _transition_kernel(x_ref, w1_ref, s1_ref, w2_ref, s2_ref, w3_ref, s3_ref,
     h2 = jnp.maximum(acc * s2_ref[0] + s2_ref[1], 0.0)
     h2 = h2.astype(dot_dtype)
 
-    # Projection shortcut input: a 1x1 stride-s SAME conv reads every s-th
-    # pixel, so the subsample is a plain strided slice of x.
-    xs = x if stride == 1 else x[::2, ::2, :]       # [ho, ho, cin]
-
     # Expand + projection in row chunks (same VMEM-peak argument as the
     # identity kernel, with the projection dot riding the same row group).
+    # Projection shortcut input: a 1x1 stride-s SAME conv reads every s-th
+    # pixel of x.
     w3 = w3_ref[...].astype(dot_dtype)              # [cmid, cout]
     wp = wp_ref[...].astype(dot_dtype)              # [cin, cout]
     rows_per_chunk = _expand_rows_per_chunk(ho)
@@ -278,10 +320,14 @@ def _transition_kernel(x_ref, w1_ref, s1_ref, w2_ref, s2_ref, w3_ref, s3_ref,
     for r in range(n_chunks):
         y = _pdot(h2[r * m:(r + 1) * m], w3)
         y = y * s3_ref[0] + s3_ref[1]               # bn3 folded (zero-init)
-        xs_r = xs[r * rows_per_chunk:(r + 1) * rows_per_chunk]
+        r0 = r * rows_per_chunk
+        if stride == 1:
+            xs_r = x[r0:r0 + rows_per_chunk]
+        else:
+            xs_r = _strided_taps(xs_ref, 2 * r0, 0, rows_per_chunk, ho)
         proj = _pdot(xs_r.reshape(m, cin).astype(dot_dtype), wp)
         proj = proj * sp_ref[0] + sp_ref[1]         # bn_proj folded
-        o_ref[0, r * rows_per_chunk:(r + 1) * rows_per_chunk] = (
+        o_ref[0, r0:r0 + rows_per_chunk] = (
             jnp.maximum(proj + y, 0.0)
             .reshape(rows_per_chunk, ho, cout).astype(o_ref.dtype))
 
@@ -322,6 +368,9 @@ def fused_transition(
     kernel = functools.partial(
         _transition_kernel, hw=hw, ho=ho, cin=cin, cmid=cmid, cout=cout,
         stride=stride, dot_dtype=jnp.bfloat16)
+    scratch_shapes = () if stride == 1 else (
+        _lane_group_scratch(hw + 2, hw + 2, cmid),   # zero-padded h1
+        _lane_group_scratch(hw, hw, cin))            # x, for the proj taps
     return pl.pallas_call(
         kernel,
         grid=(n,),
@@ -338,8 +387,9 @@ def fused_transition(
         ],
         out_specs=pl.BlockSpec((1, ho, ho, cout), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, ho, ho, cout), x.dtype),
+        scratch_shapes=scratch_shapes,
         interpret=interpret,
-    )(x, w1, s1, w2r, s2, w3, s3, wp, sp)
+    )(x, _mxu(w1), s1, _mxu(w2r), s2, _mxu(w3), s3, _mxu(wp), sp)
 
 
 def _transition_composite_f32(stride, x, w1, scale1, bias1, w2, scale2, bias2,

@@ -18,10 +18,8 @@ makes the update in place (no fresh cache buffer, no full-cache pass).
 Per step it moves S*block_t*h*d elements instead of S*max_seq*h*d — for
 the serving bench shapes that is 44x less cache traffic per layer.
 
-The round-5 fused-bottleneck study (BASELINE.md) showed Pallas *streaming*
-runs at ~0.5-0.7x XLA's HBM rate on this backend — which is exactly why
-this kernel wins: it removes the stream entirely instead of re-emitting it
-through Pallas.
+The kernel's case does not rest on Pallas streaming HBM as fast as XLA
+does: it removes the stream entirely instead of re-emitting it.
 
 No reference analog: the reference (equinor/kubeflow) contains no serving
 kernels; this is TPU-first infrastructure for the crud-web-app-adjacent

@@ -47,7 +47,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kubeflow_tpu.parallel._compat import shard_map_unchecked
 from kubeflow_tpu.parallel.mesh import AXIS_PIPE
 
 
@@ -276,7 +275,7 @@ def pipeline_apply(
             )
     if param_specs is None:
         param_specs = jax.tree_util.tree_map(stage_param_spec, stage_params)
-    fn = shard_map_unchecked(
+    fn = jax.shard_map(
         functools.partial(
             _local_pipeline,
             stage_fn=stage_fn,
@@ -289,5 +288,6 @@ def pipeline_apply(
         mesh=mesh,
         in_specs=(param_specs, x_spec),
         out_specs=out_spec,
+        check_vma=False,
     )
     return fn(stage_params, x)

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kubeflow_tpu.parallel._compat import pcast_varying, shard_map_unchecked
 from kubeflow_tpu.parallel.mesh import AXIS_MODEL, AXIS_SEQ, BATCH_AXES
 
 _NEG_BIG = -1e30
@@ -52,9 +51,9 @@ def _ring_attention_local(
     # pcast-to-varying marks them device-varying over the ring axis so the
     # fori_loop carry type stays fixed once ppermute'd blocks mix in.
     vary = vary_axes or (BATCH_AXES + (axis_name,))
-    o = pcast_varying(jnp.zeros((b, h, lq, d), jnp.float32), vary)
-    m = pcast_varying(jnp.full((b, h, lq), _NEG_BIG, jnp.float32), vary)
-    l = pcast_varying(jnp.zeros((b, h, lq), jnp.float32), vary)
+    o = lax.pcast(jnp.zeros((b, h, lq, d), jnp.float32), vary, to="varying")
+    m = lax.pcast(jnp.full((b, h, lq), _NEG_BIG, jnp.float32), vary, to="varying")
+    l = lax.pcast(jnp.zeros((b, h, lq), jnp.float32), vary, to="varying")
 
     def step(i, carry):
         o, m, l, k_cur, v_cur = carry
@@ -108,7 +107,7 @@ def ring_attention(
     head_axes = AXIS_MODEL if model_size > 1 and heads % model_size == 0 else None
     spec = P(BATCH_AXES, axis_name, head_axes, None)
     vary_axes = BATCH_AXES + (axis_name,) + ((head_axes,) if head_axes else ())
-    fn = shard_map_unchecked(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attention_local,
             axis_name=axis_name,
@@ -119,6 +118,7 @@ def ring_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     return fn(q, k, v)
 

@@ -529,7 +529,9 @@ def main() -> None:
     import argparse
 
     from ..runtime.bootstrap import block_forever
+    from ..tpu.env import enable_compile_cache
 
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description="JAX model server")
     parser.add_argument("--model",
                         default=os.environ.get("MODEL_NAME", "gpt"))

@@ -1,4 +1,5 @@
-"""JAX/TPU environment generation for multi-host pod slices.
+"""JAX/TPU environment: what a pod-slice worker is given, and what a process
+that is about to use the chip checks and sets for itself.
 
 The reference era injected free-form GPU env (``NVIDIA_VISIBLE_DEVICES``,
 NCCL vars via images — example-notebook-servers/jupyter-pytorch/cuda.Dockerfile).
@@ -14,9 +15,13 @@ twice must be a no-op.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+import os
+from pathlib import Path
+from typing import Any, Dict, List, Optional
 
 from .topology import SliceTopology
+
+ENV_COMPILE_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 JAX_COORDINATOR_PORT = 8476  # jax.distributed default
 ENV_COORDINATOR_ADDRESS = "JAX_COORDINATOR_ADDRESS"
@@ -68,3 +73,34 @@ def jax_worker_env(
 
 def env_list_to_dict(env: List[Dict[str, str]]) -> Dict[str, str]:
     return {e["name"]: e.get("value", "") for e in env}
+
+
+def require_tpu() -> Any:
+    """The first JAX device, which must be a TPU. Benchmarks, the chip smoke
+    and anything else whose output is read as a device result call this
+    before building a model: on a CPU they stop here instead of printing a
+    row nobody can tell from a chip's."""
+    import jax
+
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        raise RuntimeError(
+            f"no TPU: jax.devices()[0] is {device.platform!r} "
+            f"({device.device_kind}); this entry point runs on the chip only")
+    return device
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside and JAX
+    reads it itself, so nothing is set in code. Unset, the cache goes to
+    ``<checkout>/.jax_cache``: a fixed path, because the path is part of
+    the cache key and a directory that moves never hits."""
+    import jax
+
+    path = os.environ.get(ENV_COMPILE_CACHE_DIR)
+    if not path:
+        path = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

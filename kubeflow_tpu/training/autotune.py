@@ -347,6 +347,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     if not args.quick:
         parser.error("only --quick is wired for standalone runs; the full "
                      "sweep runs inside bench.py (BENCH_AUTOTUNE=1)")
+    from kubeflow_tpu.tpu.env import enable_compile_cache
+
+    enable_compile_cache()
     out: Dict[str, Any] = {}
     if args.family in ("resnet", "all"):
         out["resnet"] = autotune_resnet_quick(steps=args.steps).to_dict()

@@ -94,21 +94,17 @@ def mfu(
     return flops_per_step / (step_seconds * num_chips * peak_flops_per_chip(generation))
 
 
-def detect_generation(default: str = "v5e") -> str:
-    """Map the live JAX device to a catalog generation (bench runs)."""
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return default
+def detect_generation() -> str:
+    """Map the live JAX device to a catalog generation. A device the
+    catalog does not know raises: a default here would price a bench row
+    against the wrong peak. CPU callers pass ``generation=`` themselves."""
+    kind = jax.devices()[0].device_kind.lower()
+    squashed = kind.replace(" ", "").replace("lite", "e")
     for gen in ACCELERATORS:
-        if gen in kind.replace(" ", "").replace("lite", "e"):
+        if gen in squashed:
             return gen
-    if "v5 lite" in kind or "v5lite" in kind:
-        return "v5e"
-    if "v6" in kind:
-        return "v6e"
-    if "v4" in kind:
-        return "v4"
-    if "v5" in kind:
+    if "v5" in kind:  # v5p reports plain "TPU v5"
         return "v5p"
-    return default
+    raise ValueError(
+        f"device kind {kind!r} is not in the accelerator catalog "
+        f"({sorted(ACCELERATORS)}); pass generation= explicitly")

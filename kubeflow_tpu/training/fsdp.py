@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kubeflow_tpu.parallel._compat import shard_map_unchecked
 from kubeflow_tpu.parallel.mesh import AXIS_FSDP
 
 FSDP_GATHER_MODES = ("eager", "overlap")
@@ -187,11 +186,12 @@ def make_fsdp_train_step(cfg: FsdpConfig, mesh: Mesh, lr: float = 0.1,
     specs = _block_specs()
     h_spec = P(AXIS_FSDP, None, None)
 
-    stack = shard_map_unchecked(
+    stack = jax.shard_map(
         lambda p, hh: _stack_fn(cfg, p, hh, gather_mode=gather_mode),
         mesh=mesh,
         in_specs=(specs, h_spec),
         out_specs=h_spec,
+        check_vma=False,
     )
 
     def loss_fn(params, ids):

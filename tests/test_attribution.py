@@ -133,7 +133,8 @@ def test_gpt_walk_counts_the_scanned_stack():
     assert one_layer > 0
     head = next(c for c in costs if c.kind == "loss_head")
     assert head.fused and head.detail == "blockwise"
-    unfused = attribute_gpt(cfg, batch=2, seq=32, fused_loss=False)
+    unfused = attribute_gpt(cfg, batch=2, seq=32, fused_loss=False,
+                            generation="v5e")
     assert not next(c for c in unfused if c.kind == "loss_head").fused
 
 
@@ -154,7 +155,8 @@ class TestAttributionReport:
 
     def test_fractions_sum_to_one_and_match_the_clock(self, resnet_costs):
         clock = self._clock()
-        report = attribution_report(resnet_costs, clock=clock)
+        report = attribution_report(resnet_costs, clock=clock,
+                                    generation="v5e")
         assert sum(report.fractions.values()) == pytest.approx(1.0)
         # the decomposition must reconstruct the measured step within 5%
         reconstructed = report.step_seconds * sum(report.fractions.values())
@@ -167,9 +169,10 @@ class TestAttributionReport:
 
     def test_steps_per_record_normalizes_bench_windows(self, resnet_costs):
         clock = self._clock(steps=2)
-        whole = attribution_report(resnet_costs, clock=clock)
+        whole = attribution_report(resnet_costs, clock=clock,
+                                   generation="v5e")
         per_10 = attribution_report(resnet_costs, clock=clock,
-                                    steps_per_record=10)
+                                    steps_per_record=10, generation="v5e")
         assert per_10.step_seconds == pytest.approx(whole.step_seconds / 10)
 
     def test_render_and_to_dict(self, resnet_costs):
@@ -189,7 +192,7 @@ class TestAttributionReport:
         assert "fused coverage: 16/16" in report.render()
 
     def test_without_clock_everything_is_unfused_compute(self):
-        report = attribution_report([], step_seconds=0.2)
+        report = attribution_report([], step_seconds=0.2, generation="v5e")
         assert report.fractions == {"data_wait": 0.0, "fused_compute": 0.0,
                                     "unfused_compute": 1.0, "other": 0.0}
 
@@ -234,12 +237,12 @@ def gate_mod():
 
 
 class TestBenchGate:
-    def test_r05_flags_the_serving_regressions(self, gate_mod):
+    def test_r05_flags_the_serving_regressions(self, gate_mod, bench_history):
         # with r06 (the paged-KV recovery round), r07 (the autotuner round)
         # and r08 (the disaggregated-serving round) excluded, the history
         # ends at r05 and the gate must still retroactively flag the
         # r04->r05 slide
-        rounds = gate_mod.load_history(ROOT, ["r06", "r07", "r08"])
+        rounds = gate_mod.load_history(bench_history, ["r06", "r07", "r08"])
         results, rc = gate_mod.gate(rounds)
         assert rc == 1
         fails = {r["metric"] for r in results if r["verdict"] == "FAIL"}
@@ -250,10 +253,10 @@ class TestBenchGate:
         assert oks["resnet50_train_mfu"] in ("OK", "IMPROVED")
         assert oks["hpo_trials_per_hour"] == "OK"
 
-    def test_r06_recovers_without_waivers(self, gate_mod):
-        # the committed r06 round beats the r04 serving numbers outright, so
-        # the history rewound to r06 gates green with zero waivers
-        rounds = gate_mod.load_history(ROOT, ["r07", "r08"])
+    def test_r06_recovers_without_waivers(self, gate_mod, bench_history):
+        # the r06 round beats the r04 serving numbers outright, so the
+        # history rewound to r06 gates green with zero waivers
+        rounds = gate_mod.load_history(bench_history, ["r07", "r08"])
         results, rc = gate_mod.gate(rounds)
         assert rc == 0
         assert max(rounds) == 6
@@ -264,10 +267,10 @@ class TestBenchGate:
         assert verdicts["serving_ttft_p99_s"] == "BASELINE"
         assert verdicts["spec_accept_rate"] == "BASELINE"
 
-    def test_r07_breaks_the_training_plateau(self, gate_mod):
+    def test_r07_breaks_the_training_plateau(self, gate_mod, bench_history):
         # rewound to r07, the history gates green with zero waivers, and the
         # autotuner round clears the new absolute flagship floors outright
-        rounds = gate_mod.load_history(ROOT, ["r08"])
+        rounds = gate_mod.load_history(bench_history, ["r08"])
         results, rc = gate_mod.gate(rounds)
         assert rc == 0
         assert max(rounds) == 7
@@ -282,11 +285,11 @@ class TestBenchGate:
             assert by[metric]["floor"] == gate_mod.FLOORS[metric][0]
             assert by[metric]["floor_breached"] is False
 
-    def test_r08_disagg_round_gates_green(self, gate_mod):
+    def test_r08_disagg_round_gates_green(self, gate_mod, bench_history):
         # the full history gates green with zero waivers: the disaggregated
         # round's heterogeneous-mix SLIs enter as baselines, and the
         # distilled draft clears the new spec_accept_rate floor outright
-        rounds = gate_mod.load_history(ROOT, [])
+        rounds = gate_mod.load_history(bench_history, [])
         results, rc = gate_mod.gate(rounds)
         assert rc == 0
         assert max(rounds) == 8
@@ -299,8 +302,9 @@ class TestBenchGate:
             "spec_accept_rate"][0]
         assert by["spec_accept_rate"]["floor_breached"] is False
 
-    def test_excluding_r05_passes(self, gate_mod):
-        rounds = gate_mod.load_history(ROOT, ["r05", "r06", "r07", "r08"])
+    def test_excluding_r05_passes(self, gate_mod, bench_history):
+        rounds = gate_mod.load_history(
+            bench_history, ["r05", "r06", "r07", "r08"])
         results, rc = gate_mod.gate(rounds)
         assert rc == 0
         assert max(rounds) == 4
@@ -311,8 +315,8 @@ class TestBenchGate:
         gpt = next(r for r in results if r["metric"] == "gpt2_medium_mfu_pct")
         assert gpt["verdict"] == "BASELINE"
 
-    def test_waivers_turn_known_fails_green(self, gate_mod):
-        rounds = gate_mod.load_history(ROOT, ["r06", "r07", "r08"])
+    def test_waivers_turn_known_fails_green(self, gate_mod, bench_history):
+        rounds = gate_mod.load_history(bench_history, ["r06", "r07", "r08"])
         waivers = [f"{m}@r05" for m in (
             "serving_bert_p50_ms_b8",
             "serving_decode_tokens_per_sec_b8",
@@ -359,17 +363,16 @@ class TestBenchGate:
                "parsed": None}
         assert gate_mod.extract_metrics(doc) == {"ok_metric": 2.0}
 
-    def test_cli_exit_codes_and_table(self):
-        strict = subprocess.run(
-            [sys.executable, "tools/bench_gate.py"], cwd=ROOT,
-            capture_output=True, text=True)
+    def test_cli_exit_codes_and_table(self, bench_history):
+        cli = [sys.executable, "tools/bench_gate.py",
+               "--history-dir", str(bench_history)]
+        strict = subprocess.run(cli, cwd=ROOT, capture_output=True, text=True)
         assert strict.returncode == 0
         assert "serving_decode_tokens_per_sec_b8" in strict.stdout
         assert "gate PASSED" in strict.stdout
         # rewinding to the r05 regression round: rc=1 + table
         rewound = subprocess.run(
-            [sys.executable, "tools/bench_gate.py",
-             "--exclude", "r06", "--exclude", "r07", "--exclude", "r08"],
+            cli + ["--exclude", "r06", "--exclude", "r07", "--exclude", "r08"],
             cwd=ROOT, capture_output=True, text=True)
         assert rewound.returncode == 1
         assert "serving_bert_p50_ms_b8" in rewound.stdout
@@ -410,3 +413,24 @@ class TestBenchGate:
         rounds = gate_mod.load_history(tmp_path, [])
         results, rc = gate_mod.gate(rounds)
         assert results == [] and rc == 0
+
+
+def test_detect_generation_refuses_a_device_the_catalog_does_not_know():
+    # the test session's devices are CPUs: no default may price them as v5e
+    from kubeflow_tpu.training.flops import detect_generation
+
+    with pytest.raises(ValueError, match="not in the accelerator catalog"):
+        detect_generation()
+
+
+@pytest.mark.parametrize("kind,gen", [
+    ("TPU v5 lite", "v5e"), ("TPU v5e", "v5e"), ("TPU v5", "v5p"),
+    ("TPU v5p", "v5p"), ("TPU v6 lite", "v6e"), ("TPU v4", "v4")])
+def test_detect_generation_maps_device_kinds(monkeypatch, kind, gen):
+    from types import SimpleNamespace
+
+    from kubeflow_tpu.training import flops
+
+    monkeypatch.setattr(flops.jax, "devices",
+                        lambda: [SimpleNamespace(device_kind=kind)])
+    assert flops.detect_generation() == gen

@@ -1,0 +1,111 @@
+"""The main path's Pallas kernels, compiled at real widths by the TPU's own
+compiler for a described (not attached) v5e — guide ``on-chip-measurement``
+§2.3. Interpret mode passes kernels the chip refuses: a strided slice Mosaic
+cannot lower, a block that overflows VMEM. Nothing runs here, so a pass says
+"lowers and fits", never "correct" or "fast"."""
+
+from __future__ import annotations
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from kubeflow_tpu.ops.flash_attention import flash_attention
+from kubeflow_tpu.ops.fused_bottleneck import fused_bottleneck, fused_transition
+from kubeflow_tpu.ops.kv_cache import (
+    kv_block_update, kv_block_update_quant, kv_row_update)
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one described v5e device. The persistent compile cache is
+    off around the module: an entry written without a chip cannot be read
+    back and only warns."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or one that cannot describe a v5e
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+def _bottleneck_shapes(n, hw, cin, cmid, cout, proj):
+    shapes = [((n, hw, hw, cin), BF16),
+              ((cin, cmid), F32), ((cmid,), F32), ((cmid,), F32),
+              ((3, 3, cmid, cmid), F32), ((cmid,), F32), ((cmid,), F32),
+              ((cmid, cout), F32), ((cout,), F32), ((cout,), F32)]
+    if proj:
+        shapes += [((cin, cout), F32), ((cout,), F32), ((cout,), F32)]
+    return shapes
+
+
+def test_flash_attention_fwd_bwd_gpt_train_shape(chip):
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(F32).sum()
+
+    qkv = [((8, 1024, 16, 64), BF16)] * 3   # the GPT row: b8 L1024 h16 d64
+    assert _compile(chip, jax.grad(loss, argnums=(0, 1, 2)), *qkv) == 3
+
+
+# 8 slots x 1024 positions of 16 x 64 heads: the serving rows' cache
+KV = (8, 1024, 16, 64)
+ARENA = (8 * 64 + 1, 16, 16, 64)   # the same capacity in 16-token blocks
+KV_ROW = [((8, 16, 64), BF16), ((8,), I32)]
+KV_PAGED = KV_ROW + [((8, 64), I32)]
+
+
+@pytest.mark.parametrize("fn,shapes", [
+    (lambda c, n, cur: kv_row_update(c, n, cur, interpret=False),
+     [(KV, BF16)] + KV_ROW),
+    (lambda a, n, cur, t: kv_block_update(
+        a, n, cur, t, max_seq=1024, interpret=False),
+     [(ARENA, BF16)] + KV_PAGED),
+    (lambda a, s, n, cur, t: kv_block_update_quant(
+        a, s, n, cur, t, max_seq=1024, interpret=False),
+     [(ARENA, I8), (ARENA[:3] + (1,), F32)] + KV_PAGED),
+], ids=["kv_row_update", "kv_block_update", "kv_block_update_quant"])
+def test_kv_write_kernels_serving_shape(chip, fn, shapes):
+    assert _compile(chip, fn, *shapes) == 1
+
+
+def test_fused_bottleneck_identity_stage4(chip):
+    # hw7, 2048 -> 512 -> 2048: the widest weights (the VMEM-binding stage)
+    shapes = _bottleneck_shapes(256, 7, 2048, 512, 2048, proj=False)
+    assert _compile(
+        chip, lambda *a: fused_bottleneck(*a, interpret=False), *shapes) == 1
+
+
+@pytest.mark.parametrize("hw,cin,cmid,cout,stride", [
+    (56, 64, 64, 256, 1),       # stage-1 head: channel-expanding, stride 1
+    (56, 256, 128, 512, 2),     # stage-2 head
+    (14, 1024, 512, 2048, 2),   # stage-4 head: the VMEM-binding one
+])
+def test_fused_transition_resnet50_heads(chip, hw, cin, cmid, cout, stride):
+    shapes = _bottleneck_shapes(256, hw, cin, cmid, cout, proj=True)
+    assert _compile(
+        chip,
+        lambda *a: fused_transition(*a, stride=stride, interpret=False),
+        *shapes) == 1

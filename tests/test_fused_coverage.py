@@ -210,8 +210,9 @@ class TestModelCoverage:
         from kubeflow_tpu.training.attribution import (
             attribute_resnet, attribution_report)
 
-        costs = attribute_resnet(batch=1, image=224)
-        report = attribution_report(costs, step_seconds=0.1)
+        costs = attribute_resnet(batch=1, image=224, generation="v5e")
+        report = attribution_report(costs, step_seconds=0.1,
+                                    generation="v5e")
         cov = report.coverage()
         assert cov["total"] == 16
         assert cov["fused"] >= 14

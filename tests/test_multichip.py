@@ -116,7 +116,7 @@ def test_bench_multichip_emits_throughput_row(monkeypatch):
         monkeypatch.setenv(k, v)
     from bench import _run_multichip
 
-    row = _run_multichip("cpu")
+    row = _run_multichip("cpu", "v5e")
     assert "error" not in row, row
     assert row["metric"] == "multichip_composite_tokens_per_sec_per_chip_8dev"
     assert row["value"] > 0

@@ -228,11 +228,8 @@ class TestAutoTiling:
 
 class TestFusedBottleneck:
     """Parity of the fused bottleneck kernel (ops/fused_bottleneck.py)
-    against the XLA composite of the same math. The kernel exists as the
-    measured answer to VERDICT r4 #1 — see e2e/fused_bottleneck_probe.py
-    and BASELINE.md round 5 for the on-chip verdict (refuted: Pallas HBM
-    streaming on this backend runs at ~0.5x XLA's rate, cancelling the
-    fusion's 1.9x traffic saving)."""
+    against the XLA composite of the same math
+    (e2e/fused_bottleneck_probe.py is its on-chip probe)."""
 
     def test_parity_vs_xla_composite(self):
         import numpy as np
