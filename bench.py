@@ -330,14 +330,16 @@ def gpt_train_step(cfg, opt, fused_loss: bool = True):
     def loss_fn(p, ids):
         if fused_loss:
             hidden = model.apply({"params": p}, ids, return_hidden=True)
-            return blockwise_causal_lm_loss(
-                hidden, p["embedding"]["embedding"], ids)
+            with jax.named_scope("loss"):
+                return blockwise_causal_lm_loss(
+                    hidden, p["embedding"]["embedding"], ids)
         return causal_lm_loss(model.apply({"params": p}, ids), ids)
 
     def train_step(params, opt_state, ids):
         loss, grads = jax.value_and_grad(loss_fn)(params, ids)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss
 
     return model, train_step
 

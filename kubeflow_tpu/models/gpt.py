@@ -210,33 +210,35 @@ class GptAttention(nn.Module):
             use_kernel = (
                 _kv_kernel_enabled() if self.kv_kernel is None else self.kv_kernel
             )
-            if seg_len == 1:
-                if use_kernel:
-                    # Pallas row-update kernel: touches ONE [1,8,h,d] tile
-                    # per row instead of a full-cache pass per layer
-                    # (ops/kv_cache.py; the where-select below reads+writes
-                    # the whole [b,max,h,d] cache every layer — round-4's
-                    # measured 8.2 vs 3.3 ms/step gap)
-                    from ..ops.kv_cache import kv_row_update
+            with jax.named_scope("kv_write"):
+                if seg_len == 1:
+                    if use_kernel:
+                        # Pallas row-update kernel: touches ONE [1,8,h,d]
+                        # tile per row instead of a full-cache pass per
+                        # layer (ops/kv_cache.py; the where-select below
+                        # reads+writes the whole [b,max,h,d] cache every
+                        # layer — round-4's measured 8.2 vs 3.3 ms/step gap)
+                        from ..ops.kv_cache import kv_row_update
 
-                    keys = kv_row_update(cache_k.value, k[:, 0], start)
-                    values = kv_row_update(cache_v.value, v[:, 0], start)
+                        keys = kv_row_update(cache_k.value, k[:, 0], start)
+                        values = kv_row_update(cache_v.value, v[:, 0], start)
+                    else:
+                        # broadcast-select instead of vmapped
+                        # dynamic_update_slice: the vmap form lowers to a
+                        # scatter (measured ~3x slower per decode step); a
+                        # where over the cache fuses into one elementwise
+                        # pass
+                        at = (jnp.arange(cfg.max_seq)[None, :, None, None]
+                              == start[:, None, None, None])        # [b,max,1,1]
+                        keys = jnp.where(at, k, cache_k.value)
+                        values = jnp.where(at, v, cache_v.value)
                 else:
-                    # broadcast-select instead of vmapped dynamic_update_slice:
-                    # the vmap form lowers to a scatter (measured ~3x slower
-                    # per decode step); a where over the cache fuses into one
-                    # elementwise pass
-                    at = (jnp.arange(cfg.max_seq)[None, :, None, None]
-                          == start[:, None, None, None])            # [b,max,1,1]
-                    keys = jnp.where(at, k, cache_k.value)
-                    values = jnp.where(at, v, cache_v.value)
-            else:
-                upd = jax.vmap(
-                    lambda cache_row, seg, s: jax.lax.dynamic_update_slice(
-                        cache_row, seg, (s, 0, 0))
-                )
-                keys = upd(cache_k.value, k, start)
-                values = upd(cache_v.value, v, start)
+                    upd = jax.vmap(
+                        lambda cache_row, seg, s: jax.lax.dynamic_update_slice(
+                            cache_row, seg, (s, 0, 0))
+                    )
+                    keys = upd(cache_k.value, k, start)
+                    values = upd(cache_v.value, v, start)
             mask = (jnp.arange(cfg.max_seq)[None, None, None, :]
                     <= seg_positions[:, None, :, None])             # [b,1,L,max]
         else:
@@ -246,8 +248,9 @@ class GptAttention(nn.Module):
             q = rope(dense(name="query")(x), seg_positions, cfg.rope_theta)
             k = rope(dense(name="key")(x), seg_positions, cfg.rope_theta)
             v = dense(name="value")(x)
-            keys = jax.lax.dynamic_update_slice(cache_k.value, k, (0, start, 0, 0))
-            values = jax.lax.dynamic_update_slice(cache_v.value, v, (0, start, 0, 0))
+            with jax.named_scope("kv_write"):
+                keys = jax.lax.dynamic_update_slice(cache_k.value, k, (0, start, 0, 0))
+                values = jax.lax.dynamic_update_slice(cache_v.value, v, (0, start, 0, 0))
             mask = (jnp.arange(cfg.max_seq)[None, None, None, :]
                     <= seg_positions[None, None, :, None])
         # flax init runs the forward once for shapes/params — the cache must
@@ -260,19 +263,30 @@ class GptAttention(nn.Module):
             else:
                 cursor.value = start + seg_len
 
-        scale = cfg.head_dim**-0.5
-        scores = (
-            jnp.einsum(
-                "bqhd,bkhd->bhqk",
-                q.astype(jnp.float32),
-                keys.astype(jnp.float32),
-            )
-            * scale
-        )
-        scores = jnp.where(mask, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, values.astype(jnp.float32))
-        return self._out_proj(ctx.astype(cfg.dtype))
+        return self._out_proj(self._masked_attention(q, keys, values, mask))
+
+    def _masked_attention(self, q: jax.Array, keys: jax.Array,
+                          values: jax.Array, mask: jax.Array) -> jax.Array:
+        """Scores, mask, softmax and context in float32 over the whole
+        [b, max_seq] view, for both decode paths; returns the context in
+        the compute type. The float32 conversion of the view belongs to
+        ``kv_gather`` and the rest to ``attn_scores``. Each operation is
+        traced where it always was, so the names move no instruction."""
+        f32 = jnp.float32
+        scale = self.cfg.head_dim**-0.5
+        with jax.named_scope("attn_scores"):
+            qf = q.astype(f32)
+        with jax.named_scope("kv_gather"):
+            kf = keys.astype(f32)
+        with jax.named_scope("attn_scores"):
+            scores = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) * scale
+            scores = jnp.where(mask, scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+        with jax.named_scope("kv_gather"):
+            vf = values.astype(f32)
+        with jax.named_scope("attn_scores"):
+            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, vf)
+        return ctx.astype(self.cfg.dtype)
 
     def _paged_decode_attention(self, x: jax.Array, dense,
                                 block_tables: jax.Array) -> jax.Array:
@@ -315,35 +329,36 @@ class GptAttention(nn.Module):
         from ..ops.kv_cache import (kv_block_update, kv_block_update_quant,
                                     kv_block_update_ref, quantize_kv)
 
-        if quant:
-            if seg_len == 1 and use_kernel:
-                keys_arena, k_scales = kv_block_update_quant(
-                    cache_k.value, scale_k.value, k[:, 0], start,
-                    block_tables, max_seq=cfg.max_seq)
-                vals_arena, v_scales = kv_block_update_quant(
-                    cache_v.value, scale_v.value, v[:, 0], start,
-                    block_tables, max_seq=cfg.max_seq)
+        with jax.named_scope("kv_write"):
+            if quant:
+                if seg_len == 1 and use_kernel:
+                    keys_arena, k_scales = kv_block_update_quant(
+                        cache_k.value, scale_k.value, k[:, 0], start,
+                        block_tables, max_seq=cfg.max_seq)
+                    vals_arena, v_scales = kv_block_update_quant(
+                        cache_v.value, scale_v.value, v[:, 0], start,
+                        block_tables, max_seq=cfg.max_seq)
+                else:
+                    kq, ks = quantize_kv(k)
+                    vq, vs = quantize_kv(v)
+                    keys_arena = kv_block_update_ref(
+                        cache_k.value, kq, start, block_tables, max_seq=cfg.max_seq)
+                    vals_arena = kv_block_update_ref(
+                        cache_v.value, vq, start, block_tables, max_seq=cfg.max_seq)
+                    k_scales = kv_block_update_ref(
+                        scale_k.value, ks, start, block_tables, max_seq=cfg.max_seq)
+                    v_scales = kv_block_update_ref(
+                        scale_v.value, vs, start, block_tables, max_seq=cfg.max_seq)
+            elif seg_len == 1 and use_kernel:
+                keys_arena = kv_block_update(
+                    cache_k.value, k[:, 0], start, block_tables, max_seq=cfg.max_seq)
+                vals_arena = kv_block_update(
+                    cache_v.value, v[:, 0], start, block_tables, max_seq=cfg.max_seq)
             else:
-                kq, ks = quantize_kv(k)
-                vq, vs = quantize_kv(v)
                 keys_arena = kv_block_update_ref(
-                    cache_k.value, kq, start, block_tables, max_seq=cfg.max_seq)
+                    cache_k.value, k, start, block_tables, max_seq=cfg.max_seq)
                 vals_arena = kv_block_update_ref(
-                    cache_v.value, vq, start, block_tables, max_seq=cfg.max_seq)
-                k_scales = kv_block_update_ref(
-                    scale_k.value, ks, start, block_tables, max_seq=cfg.max_seq)
-                v_scales = kv_block_update_ref(
-                    scale_v.value, vs, start, block_tables, max_seq=cfg.max_seq)
-        elif seg_len == 1 and use_kernel:
-            keys_arena = kv_block_update(
-                cache_k.value, k[:, 0], start, block_tables, max_seq=cfg.max_seq)
-            vals_arena = kv_block_update(
-                cache_v.value, v[:, 0], start, block_tables, max_seq=cfg.max_seq)
-        else:
-            keys_arena = kv_block_update_ref(
-                cache_k.value, k, start, block_tables, max_seq=cfg.max_seq)
-            vals_arena = kv_block_update_ref(
-                cache_v.value, v, start, block_tables, max_seq=cfg.max_seq)
+                    cache_v.value, v, start, block_tables, max_seq=cfg.max_seq)
         if not self.is_initializing():
             cache_k.value = keys_arena
             cache_v.value = vals_arena
@@ -355,32 +370,23 @@ class GptAttention(nn.Module):
         bt = arena_shape[1]
         mb = block_tables.shape[1]
         view = (b, mb * bt, cfg.n_heads, cfg.head_dim)
-        if quant:
-            # load-dequantized read: gather values + scales through the same
-            # table, dequantize to f32 (the einsum below is f32 regardless)
-            sview = (b, mb * bt, cfg.n_heads, 1)
-            keys = (keys_arena[block_tables].reshape(view).astype(jnp.float32)
-                    * k_scales[block_tables].reshape(sview))
-            values = (vals_arena[block_tables].reshape(view).astype(jnp.float32)
-                      * v_scales[block_tables].reshape(sview))
-        else:
-            keys = keys_arena[block_tables].reshape(view)
-            values = vals_arena[block_tables].reshape(view)
+        with jax.named_scope("kv_gather"):
+            # every slot's whole view out of the arena
+            if quant:
+                # load-dequantized read: gather values + scales through
+                # the same table, dequantize to f32 (the einsums are f32
+                # regardless)
+                sview = (b, mb * bt, cfg.n_heads, 1)
+                keys = (keys_arena[block_tables].reshape(view).astype(jnp.float32)
+                        * k_scales[block_tables].reshape(sview))
+                values = (vals_arena[block_tables].reshape(view).astype(jnp.float32)
+                          * v_scales[block_tables].reshape(sview))
+            else:
+                keys = keys_arena[block_tables].reshape(view)
+                values = vals_arena[block_tables].reshape(view)
         mask = (jnp.arange(mb * bt)[None, None, None, :]
                 <= seg_positions[:, None, :, None])             # [b,1,L,mb*bt]
-        scale = cfg.head_dim**-0.5
-        scores = (
-            jnp.einsum(
-                "bqhd,bkhd->bhqk",
-                q.astype(jnp.float32),
-                keys.astype(jnp.float32),
-            )
-            * scale
-        )
-        scores = jnp.where(mask, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, values.astype(jnp.float32))
-        return self._out_proj(ctx.astype(cfg.dtype))
+        return self._out_proj(self._masked_attention(q, keys, values, mask))
 
 
 class GptMlp(nn.Module):
@@ -511,7 +517,8 @@ class GptLM(nn.Module):
             return x.astype(jnp.float32)
         # tied LM head in f32 (embed.attend would compute in the module's
         # bf16 dtype; the final softmax wants full precision)
-        logits = x.astype(jnp.float32) @ embed.embedding.T.astype(jnp.float32)
+        with jax.named_scope("lm_head"):
+            logits = x.astype(jnp.float32) @ embed.embedding.T.astype(jnp.float32)
         return logits
 
 

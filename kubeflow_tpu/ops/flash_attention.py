@@ -214,6 +214,7 @@ def _fwd(
             pltpu.VMEM((bq, _LANE), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     return jnp.swapaxes(out, 1, 2), lse
 
@@ -364,6 +365,7 @@ def _bwd(
         out_shape=jax.ShapeDtypeStruct((b, h, lq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse, delta)
 
     # k-major grid: the q loop is the accumulating (minor) dim for dk/dv.
@@ -388,6 +390,7 @@ def _bwd(
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lse, delta)
 
     return (

@@ -222,26 +222,28 @@ def _block(cfg: CompositeConfig, h, ln1, ln2, wqkv, wo, w1, w2):
         return (x - mu) * jax.lax.rsqrt(var + 1e-5) * scale
 
     # attention: column-split QKV -> local heads; causal; row-split WO
-    x = ln(h, ln1)
-    qkv = jnp.einsum("bsd,drh->bsrh", x, wqkv)       # [mb, s, 3, d/tp]
-    dl = qkv.shape[-1]                               # d/tp local width
-    q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-    hd = cfg.d_model // cfg.n_heads
-    nh = dl // hd                                    # local heads
-    mb, s, _ = q.shape
-    q = q.reshape(mb, s, nh, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(mb, s, nh, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(mb, s, nh, hd).transpose(0, 2, 1, 3)
-    scores = (q @ k.transpose(0, 1, 3, 2)) * (hd ** -0.5)
-    mask = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(mask, scores, -1e30)
-    attn = jax.nn.softmax(scores, axis=-1) @ v       # [mb, nh, s, hd]
-    attn = attn.transpose(0, 2, 1, 3).reshape(mb, s, dl)
-    # row-split output proj: partial sums reduced over the model axis
-    h = h + lax.psum(attn @ wo, AXIS_MODEL)
+    with jax.named_scope("attn"):
+        x = ln(h, ln1)
+        qkv = jnp.einsum("bsd,drh->bsrh", x, wqkv)       # [mb, s, 3, d/tp]
+        dl = qkv.shape[-1]                               # d/tp local width
+        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+        hd = cfg.d_model // cfg.n_heads
+        nh = dl // hd                                    # local heads
+        mb, s, _ = q.shape
+        q = q.reshape(mb, s, nh, hd).transpose(0, 2, 1, 3)
+        k = k.reshape(mb, s, nh, hd).transpose(0, 2, 1, 3)
+        v = v.reshape(mb, s, nh, hd).transpose(0, 2, 1, 3)
+        scores = (q @ k.transpose(0, 1, 3, 2)) * (hd ** -0.5)
+        mask = jnp.tril(jnp.ones((s, s), bool))
+        scores = jnp.where(mask, scores, -1e30)
+        attn = jax.nn.softmax(scores, axis=-1) @ v       # [mb, nh, s, hd]
+        attn = attn.transpose(0, 2, 1, 3).reshape(mb, s, dl)
+        # row-split output proj: partial sums reduced over the model axis
+        h = h + lax.psum(attn @ wo, AXIS_MODEL)
     # mlp: column-split W1 (no comm), row-split W2 (+psum)
-    x = ln(h, ln2)
-    h = h + lax.psum(jax.nn.gelu(x @ w1) @ w2, AXIS_MODEL)
+    with jax.named_scope("mlp"):
+        x = ln(h, ln2)
+        h = h + lax.psum(jax.nn.gelu(x @ w1) @ w2, AXIS_MODEL)
     return h
 
 
@@ -346,7 +348,8 @@ def make_train_step(
 
     def loss_fn(params, ids):
         # GSPMD region: embedding lookup, vocab sharded over `model`
-        h = jnp.take(params["embed"], ids, axis=0)  # [M, mb, s, d]
+        with jax.named_scope("embed"):
+            h = jnp.take(params["embed"], ids, axis=0)  # [M, mb, s, d]
         h = pipeline_apply(
             lambda p, hh: _stage_fn(cfg, p, hh, gather_mode=inner_mode),
             params["stages"],
@@ -359,14 +362,18 @@ def make_train_step(
             mask_bubbles=mask_bubbles,
             stage_prepare=stage_prepare,
         )
-        logits = h @ params["embed"].T  # [M, mb, s, vocab]
-        targets = jnp.roll(ids, -1, axis=-1)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        return -jnp.mean(jnp.take_along_axis(logp, targets[..., None], axis=-1))
+        with jax.named_scope("unembed"):
+            logits = h @ params["embed"].T  # [M, mb, s, vocab]
+            targets = jnp.roll(ids, -1, axis=-1)
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+            return -jnp.mean(
+                jnp.take_along_axis(logp, targets[..., None], axis=-1))
 
     def step(params, ids):
         loss, grads = jax.value_and_grad(loss_fn)(params, ids)
-        params = jax.tree_util.tree_map(lambda p, g: p - lr * g, params, grads)
+        with jax.named_scope("optimizer"):
+            params = jax.tree_util.tree_map(
+                lambda p, g: p - lr * g, params, grads)
         return params, loss
 
     in_sharding = (param_shardings(cfg, mesh), NamedSharding(mesh, batch_spec))

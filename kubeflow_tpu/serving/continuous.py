@@ -47,6 +47,7 @@ import numpy as np
 from ..models.gpt import GptConfig, GptLM
 from ..runtime.metrics import METRICS
 from ..runtime.tracing import TRACER, Span
+from ..tpu import profiling
 from .errors import (DeadlineExceeded, EngineClosed, FleetSaturated,
                      RequestCancelled)
 from .paged import KVBlockAllocator, KVReservation
@@ -87,6 +88,8 @@ MAX_GROUP = 8
 #: the worker stops admitting, finishes in-flight slots, then parks the
 #: unserved pendings for handoff instead of failing them
 _DRAIN = object()
+#: "no item in hand" while draining the queue (``None`` is the shutdown sentinel)
+_NOTHING = object()
 
 
 def _bucket_for(n: int) -> int:
@@ -545,14 +548,15 @@ class ContinuousBatcher:
                     {"params": params, "cache": cache}, tok[:, None],
                     mutable=["cache"], **kwargs
                 )
-                lg = logits[:, -1]                               # [slots, vocab]
-                greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-                pairs = jax.vmap(jax.random.split)(rngs)   # [slots, 2, 2]
-                rngs, keys = pairs[:, 0], pairs[:, 1]
-                sampled = jax.vmap(
-                    lambda k, l, t: jax.random.categorical(k, l / jnp.maximum(t, 1e-6))
-                )(keys, lg, temps).astype(jnp.int32)
-                nxt = jnp.where(temps > 0.0, sampled, greedy)
+                with jax.named_scope("sample"):
+                    lg = logits[:, -1]                           # [slots, vocab]
+                    greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+                    pairs = jax.vmap(jax.random.split)(rngs)   # [slots, 2, 2]
+                    rngs, keys = pairs[:, 0], pairs[:, 1]
+                    sampled = jax.vmap(
+                        lambda k, l, t: jax.random.categorical(k, l / jnp.maximum(t, 1e-6))
+                    )(keys, lg, temps).astype(jnp.int32)
+                    nxt = jnp.where(temps > 0.0, sampled, greedy)
                 return (updated["cache"], nxt, rngs), nxt
 
             (cache, tok, rngs), toks = jax.lax.scan(
@@ -612,13 +616,14 @@ class ContinuousBatcher:
                 {"params": params, "cache": cache}, seg,
                 mutable=["cache"], **kwargs)
             cache = updated["cache"]
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, k]
-            pairs = jax.vmap(jax.random.split)(rngs)
-            rngs, keys = pairs[:, 0], pairs[:, 1]
-            sampled = jax.vmap(
-                lambda k_, l, t: jax.random.categorical(
-                    k_, l / jnp.maximum(t, 1e-6))
-            )(keys, logits[:, 0], temps).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, k]
+                pairs = jax.vmap(jax.random.split)(rngs)
+                rngs, keys = pairs[:, 0], pairs[:, 1]
+                sampled = jax.vmap(
+                    lambda k_, l, t: jax.random.categorical(
+                        k_, l / jnp.maximum(t, 1e-6))
+                )(keys, logits[:, 0], temps).astype(jnp.int32)
             match = (drafts[:, :k - 1] == greedy[:, :k - 1]).astype(jnp.int32)
             m_greedy = 1 + jnp.sum(jnp.cumprod(match, axis=1), axis=1)
             m = jnp.where(temps > 0.0, 1, m_greedy).astype(jnp.int32)  # [S]
@@ -1029,6 +1034,17 @@ class ContinuousBatcher:
 
     # -- engine loop ---------------------------------------------------------
     def _admit_wave(self, reqs: List[_Request]) -> List[Tuple[str, Any, Any]]:
+        """One admission wave as one ``serving.engine.admit`` region:
+        ``requests`` offered, and the largest prompt ``bucket`` it prefilled
+        in a batch (0: every request went the chunked way or back)."""
+        with profiling.annotate("serving.engine.admit",
+                                requests=len(reqs)) as region:
+            events, buckets = self._admit_groups(reqs)
+            region.set_metadata(bucket=max(buckets, default=0))
+        return events
+
+    def _admit_groups(self, reqs: List[_Request]
+                      ) -> Tuple[List[Tuple[str, Any, Any]], List[int]]:
         """Admit up to ``len(self._free)`` requests together: one batched
         prefill + one adopt per same-prompt-bucket group instead of the
         round-4 per-request dispatch chain (~141 ms each). Fully async —
@@ -1219,7 +1235,7 @@ class ContinuousBatcher:
                 self._pending.appendleft(r)
             self._set_queue_gauge()
         self._set_occupancy()
-        return events
+        return events, list(by_bucket)
 
     # -- KV handoff: prefill-role export (ISSUE 18) --------------------------
     def _export_group(self, group, small, first) -> None:
@@ -1744,6 +1760,7 @@ class ContinuousBatcher:
                         f"engine queue full ({depth} >= {cap} "
                         f"for priority={req.priority})"))
                     continue
+            _ev(req, "dequeued")
             self._pending.append(req)
 
     def _next_wave(self, n: int) -> List[_Request]:
@@ -1800,44 +1817,70 @@ class ContinuousBatcher:
         tail; a row adopted after the dispatch is not in the snapshot."""
         kind, dev, meta, dispatched_at = event
         widths = None
-        if kind == "spec":
-            # one speculative round: [slots, spec_k] candidate tokens plus
-            # the per-slot accepted width m (1..spec_k) — only the first
-            # m are real, the rest were refuted by the verify forward
-            toks_dev, acc_dev = dev
-            block = np.asarray(toks_dev)
-            widths = np.asarray(acc_dev)
-        else:
-            block = np.asarray(dev)  # host fetch (async copy started at dispatch)
+        with profiling.annotate("serving.engine.fetch", kind=kind):
+            if kind == "spec":
+                # one speculative round: [slots, spec_k] candidate tokens
+                # plus the per-slot accepted width m (1..spec_k) — only the
+                # first m are real, the rest were refuted by the verify
+                # forward
+                toks_dev, acc_dev = dev
+                block = np.asarray(toks_dev)
+                widths = np.asarray(acc_dev)
+            else:
+                # host fetch (async copy started at dispatch)
+                block = np.asarray(dev)
         now = time.perf_counter()
-        if kind == "first":
-            for (req, slot), tok in zip(meta, block):
-                if req.done.is_set():
-                    # reaped (deadline/cancel) between admission and this
-                    # event — its prefill token was computed for nobody
-                    if req.finish_reason in ("deadline", "cancelled"):
-                        METRICS.counter(
-                            "serving_wasted_decode_tokens_total").inc()
-                    continue
-                req.tokens.append(int(tok))
-                req.first_token_at = req.last_token_at = now
-                METRICS.counter("serving_tokens_out_total").inc()
-                if req.submit_at is not None:
-                    METRICS.histogram(
-                        "serving_ttft_seconds", buckets=TTFT_BUCKETS
-                    ).observe(now - req.submit_at, trace_id=_trace_id(req))
-                _ev(req, "first_token")
-                hit_eos = req.eos_id is not None and req.tokens[-1] == req.eos_id
-                if req.max_new_tokens <= 1 or hit_eos:
-                    # the slot was activated at admission, so the normal
-                    # retirement path applies
-                    self._retire(slot)
-            return
-        # dispatch→fetch-complete latency of one pipelined decode chunk
-        METRICS.histogram(
-            "serving_decode_chunk_seconds", buckets=DECODE_CHUNK_BUCKETS
-        ).observe(now - dispatched_at)
-        for slot, req in meta.items():
+        with profiling.annotate("serving.engine.deliver", kind=kind,
+                                rows=int(block.size)) as span:
+            if kind == "first":
+                tokens, retired = self._deliver_first(meta, block, now)
+            else:
+                # dispatch→fetch-complete latency of one pipelined decode
+                # chunk (``now`` is where the fetch region closed)
+                METRICS.histogram(
+                    "serving_decode_chunk_seconds",
+                    buckets=DECODE_CHUNK_BUCKETS
+                ).observe(now - dispatched_at)
+                tokens, retired = self._deliver_block(
+                    meta, block, widths, now)
+            span.set_metadata(tokens=tokens, retired=retired)
+
+    def _deliver_first(self, pairs, block, now: float) -> Tuple[int, int]:
+        """An admission group's first tokens to their requests. Returns
+        (tokens appended to live requests, requests retired)."""
+        tokens = retired = 0
+        for (req, slot), tok in zip(pairs, block):
+            if req.done.is_set():
+                # reaped (deadline/cancel) between admission and this
+                # event — its prefill token was computed for nobody
+                if req.finish_reason in ("deadline", "cancelled"):
+                    METRICS.counter(
+                        "serving_wasted_decode_tokens_total").inc()
+                continue
+            req.tokens.append(int(tok))
+            tokens += 1
+            req.first_token_at = req.last_token_at = now
+            METRICS.counter("serving_tokens_out_total").inc()
+            if req.submit_at is not None:
+                METRICS.histogram(
+                    "serving_ttft_seconds", buckets=TTFT_BUCKETS
+                ).observe(now - req.submit_at, trace_id=_trace_id(req))
+            _ev(req, "first_token")
+            hit_eos = req.eos_id is not None and req.tokens[-1] == req.eos_id
+            if req.max_new_tokens <= 1 or hit_eos:
+                # the slot was activated at admission, so the normal
+                # retirement path applies
+                self._retire(slot)
+                retired += 1
+        return tokens, retired
+
+    def _deliver_block(self, snapshot, block, widths, now: float
+                       ) -> Tuple[int, int]:
+        """One decode chunk's (or speculative round's) token block to the
+        requests of its dispatch-time snapshot. Returns (tokens appended
+        to live requests, requests retired)."""
+        tokens = retired = 0
+        for slot, req in snapshot.items():
             # usable tokens this row produced: the whole chunk, or the
             # accepted prefix of a speculative round
             width = int(widths[slot]) if widths is not None else block.shape[1]
@@ -1869,6 +1912,7 @@ class ContinuousBatcher:
                 tok = int(block[slot, j])
                 req.tokens.append(tok)
                 appended += 1
+                tokens += 1
                 hit_eos = req.eos_id is not None and tok == req.eos_id
                 if len(req.tokens) >= req.max_new_tokens or hit_eos:
                     # inter-token latency amortized over the block BEFORE
@@ -1876,6 +1920,7 @@ class ContinuousBatcher:
                     # per-token path must not pay per-token metric calls)
                     self._note_tokens(req, appended, now)
                     self._retire(slot)
+                    retired += 1
                     METRICS.counter(
                         "serving_discarded_tail_tokens_total"
                     ).inc(width - j - 1)
@@ -1883,6 +1928,7 @@ class ContinuousBatcher:
                     break
             if appended:
                 self._note_tokens(req, appended, now)
+        return tokens, retired
 
     def _note_tokens(self, req: _Request, n: int, now: float) -> None:
         METRICS.counter("serving_tokens_out_total").inc(n)
@@ -1894,84 +1940,111 @@ class ContinuousBatcher:
         req.last_token_at = now
 
     def _loop(self) -> None:
+        """The engine thread: turns until shutdown or a completed drain.
+        Every phase of a turn is a ``serving.engine.*`` region of the
+        profiler's trace (docs/OBSERVABILITY.md), so a capture says what
+        the host was doing whenever the device sat idle."""
         events: "collections.deque[Tuple[str, Any, Any, float]]" = collections.deque()
+        while True:
+            with profiling.annotate("serving.engine.turn"):
+                if not self._turn(events):
+                    return
+
+    def _take(self, item: Any) -> int:
+        """One item off the thread-safe queue into the engine's own state.
+        Returns how many requests it carried."""
+        if item is _DRAIN:
+            # submits racing the drain land BEFORE the sentinel (submit
+            # checks _closed under the lock that also enqueues it), so
+            # everything still queued here is part of the handoff set
+            self._draining = True
+            return 0
+        if isinstance(item, _Import):
+            self._imports.append(item)
+            return 1
+        self._enqueue_pendings(item)
+        return len(item)
+
+    def _turn(self, events: "collections.deque[Tuple[str, Any, Any, float]]"
+              ) -> bool:
+        """One pass of the engine loop; False once the loop is over."""
 
         def chunk_depth() -> int:
             return sum(1 for kind, _, _, _ in events
                        if kind in ("chunk", "spec"))
 
-        while True:
-            # drain arrivals into the pending deque; block only when fully
-            # idle (no busy-wait). Coalescing the drain is what lets a burst
-            # of single submits admit as ONE batched prefill.
-            try:
-                timeout = (None if not (self._active or self._pending
-                                        or events or self._draining
-                                        or self._chunked or self._imports)
-                           else 0.0)
-                while True:
-                    item = self._queue.get(timeout=timeout) if timeout is None \
-                        else self._queue.get_nowait()
-                    if item is None:
-                        self._shutdown("batcher closed mid-flight")
-                        return
-                    if item is _DRAIN:
-                        # submits racing the drain land BEFORE the sentinel
-                        # (submit checks _closed under the lock that also
-                        # enqueues it), so everything still queued here is
-                        # part of the handoff set
-                        self._draining = True
-                    elif isinstance(item, _Import):
-                        self._imports.append(item)
-                    else:
-                        self._enqueue_pendings(item)
-                    timeout = 0.0
-            except queue.Empty:
-                pass
-            self._set_queue_gauge()
-            try:
-                if self.fail_next_step:
-                    # chaos crash_replica_mid_decode: poison the iteration;
-                    # the handler below fails everything and closes the
-                    # engine, exactly like a real device/RPC death
-                    self.fail_next_step = False
-                    raise RuntimeError("chaos: replica crashed mid-decode")
-                if self.step_delay_s > 0:
-                    # chaos slow_replica: stall the dispatch loop so
-                    # deadlines expire and the fleet's breaker sees a
-                    # slow replica
-                    time.sleep(min(self.step_delay_s, 5.0))
-                # reap BEFORE admission: an expired queued request must
-                # never take a slot, and an expired/abandoned in-flight
-                # one frees its slot for this very wave
+        # drain arrivals into the pending deque; block only when fully
+        # idle (no busy-wait). Coalescing the drain is what lets a burst
+        # of single submits admit as ONE batched prefill.
+        item = _NOTHING
+        if not (self._active or self._pending or events or self._draining
+                or self._chunked or self._imports):
+            with profiling.annotate("serving.engine.idle"):
+                item = self._queue.get()
+        with profiling.annotate("serving.engine.drain") as span:
+            arrivals = 0
+            while True:
+                if item is _NOTHING:
+                    try:
+                        item = self._queue.get_nowait()
+                    except queue.Empty:
+                        break
+                if item is None:
+                    self._shutdown("batcher closed mid-flight")
+                    return False
+                arrivals += self._take(item)
+                item = _NOTHING
+            span.set_metadata(arrivals=arrivals)
+        self._set_queue_gauge()
+        try:
+            if self.fail_next_step:
+                # chaos crash_replica_mid_decode: poison the iteration;
+                # the handler below fails everything and closes the
+                # engine, exactly like a real device/RPC death
+                self.fail_next_step = False
+                raise RuntimeError("chaos: replica crashed mid-decode")
+            if self.step_delay_s > 0:
+                # chaos slow_replica: stall the dispatch loop so
+                # deadlines expire and the fleet's breaker sees a
+                # slow replica
+                time.sleep(min(self.step_delay_s, 5.0))
+            # reap BEFORE admission: an expired queued request must
+            # never take a slot, and an expired/abandoned in-flight
+            # one frees its slot for this very wave
+            with profiling.annotate("serving.engine.reap"):
                 self._reap_pending()
                 self._reap_active()
-                dispatched = False
-                if self._imports and self._free and not self._draining:
-                    # wire imports admit before fresh prompts: their
-                    # prefill compute is already spent — leaving them
-                    # queued behind new admissions would waste it twice
+            dispatched = False
+            if self._imports and self._free and not self._draining:
+                # wire imports admit before fresh prompts: their
+                # prefill compute is already spent — leaving them
+                # queued behind new admissions would waste it twice
+                with profiling.annotate("serving.engine.import"):
                     events.extend(self._admit_imports())
-                    dispatched = True
-                if self._free and self._pending and not self._draining:
-                    wave = self._next_wave(len(self._free))
-                    self._set_queue_gauge()
-                    events.extend(self._admit_wave(wave))
-                    dispatched = True
-                if self._chunked is not None:
-                    # ONE prefill chunk per iteration, interleaved between
-                    # decode dispatches — TTFT of the chatty slots stops
-                    # being hostage to the longest prompt (drain included:
-                    # the mid-prefill request is in-flight work)
+                dispatched = True
+            if self._free and self._pending and not self._draining:
+                wave = self._next_wave(len(self._free))
+                self._set_queue_gauge()
+                events.extend(self._admit_wave(wave))
+                dispatched = True
+            if self._chunked is not None:
+                # ONE prefill chunk per iteration, interleaved between
+                # decode dispatches — TTFT of the chatty slots stops
+                # being hostage to the longest prompt (drain included:
+                # the mid-prefill request is in-flight work)
+                with profiling.annotate("serving.engine.prefill_chunk"):
                     events.extend(self._advance_chunked())
-                    dispatched = True
-                if self._active:
-                    # one CHUNK of decode steps (or one speculative round)
-                    # for every slot (inactive rows compute too — static
-                    # shapes are the TPU contract; their outputs are
-                    # discarded when processed against the snapshot)
-                    self._grant_active(self.spec_k if self.spec_k
-                                       else self.chunk)
+                dispatched = True
+            if self._active:
+                # one CHUNK of decode steps (or one speculative round)
+                # for every slot (inactive rows compute too — static
+                # shapes are the TPU contract; their outputs are
+                # discarded when processed against the snapshot)
+                width = self.spec_k if self.spec_k else self.chunk
+                with profiling.annotate("serving.engine.dispatch",
+                                        rows=self.slots * width,
+                                        live=len(self._active)):
+                    self._grant_active(width)
                     extra = ((jnp.asarray(self._tables),)
                              if self.paged else ())
                     if self.spec_k:
@@ -1999,35 +2072,36 @@ class ContinuousBatcher:
                             pass
                         events.append(("chunk", toks, dict(self._active),
                                        time.perf_counter()))
-                    dispatched = True
-                # keep the dispatch frontier at most ``pipeline`` chunks
-                # ahead of the processed state; when nothing new could be
-                # dispatched, drain one event so the pipeline empties
-                while chunk_depth() > self.pipeline:
-                    self._process_event(events.popleft())
-                if not dispatched and events:
-                    self._process_event(events.popleft())
-                if (self._draining and not self._active and not events
-                        and self._chunked is None):
-                    # drain complete: every in-flight slot ran to its
-                    # budget/EOS; park the unserved pendings (futures still
-                    # open) for the caller and zero this replica's gauges.
-                    # Unadmitted KV imports park too — their ``kv_blob`` is
-                    # set, so the fleet re-imports them on a surviving
-                    # decode replica instead of re-running prefill.
-                    self._handoff.extend(self._pending)
-                    self._pending.clear()
-                    self._handoff.extend(imp.req for imp in self._imports
-                                         if not imp.req.done.is_set())
-                    self._imports.clear()
-                    self._set_queue_gauge()
-                    self._set_occupancy()
-                    return
-            except Exception as e:
-                # a device/RPC failure must not wedge the engine silently:
-                # fail everything in flight, pending, and queued; refuse
-                # new work
-                with self._lock:
-                    self._closed = True
-                self._shutdown(f"engine step failed: {e}")
-                return
+                dispatched = True
+            # keep the dispatch frontier at most ``pipeline`` chunks
+            # ahead of the processed state; when nothing new could be
+            # dispatched, drain one event so the pipeline empties
+            while chunk_depth() > self.pipeline:
+                self._process_event(events.popleft())
+            if not dispatched and events:
+                self._process_event(events.popleft())
+            if (self._draining and not self._active and not events
+                    and self._chunked is None):
+                # drain complete: every in-flight slot ran to its
+                # budget/EOS; park the unserved pendings (futures still
+                # open) for the caller and zero this replica's gauges.
+                # Unadmitted KV imports park too — their ``kv_blob`` is
+                # set, so the fleet re-imports them on a surviving
+                # decode replica instead of re-running prefill.
+                self._handoff.extend(self._pending)
+                self._pending.clear()
+                self._handoff.extend(imp.req for imp in self._imports
+                                     if not imp.req.done.is_set())
+                self._imports.clear()
+                self._set_queue_gauge()
+                self._set_occupancy()
+                return False
+        except Exception as e:
+            # a device/RPC failure must not wedge the engine silently:
+            # fail everything in flight, pending, and queued; refuse
+            # new work
+            with self._lock:
+                self._closed = True
+            self._shutdown(f"engine step failed: {e}")
+            return False
+        return True

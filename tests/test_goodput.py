@@ -240,7 +240,9 @@ class TestElasticTrainerGoodput:
         assert snap["badputSeconds"]["preemption_replay"] > 0.0
         assert snap["badputSeconds"]["checkpoint_restore"] > 0.0
         assert snap["badputSeconds"]["checkpoint_save"] > 0.0
-        assert sum(snap["fractions"].values()) == 1.0
+        # wallclock-derived floats: the ledger's residual closes the sum to
+        # the last bit or two, which of the two is rounding's business
+        assert sum(snap["fractions"].values()) == pytest.approx(1.0, abs=1e-9)
         assert METRICS.histogram("checkpoint_restore_seconds").total == 1
 
     def test_graceful_drain_has_zero_replay(self, tmp_path):

@@ -313,7 +313,9 @@ class TestServingTelemetry:
         assert span.parent_span_id == "cd" * 8
         assert span.status == "OK" and span.attributes["generated_tokens"] == 6
         names = [e["name"] for e in span.events]
-        assert names[:3] == ["enqueued", "admitted", "prefill_done"]
+        assert names[:4] == ["enqueued", "dequeued", "admitted", "prefill_done"]
+        stamps = [e["timeUnixNano"] for e in span.events]
+        assert stamps == sorted(stamps)
         assert "first_token" in names and names[-1] == "retired"
         # SLO histograms observed, exemplars carry the request's trace id
         text = METRICS.render()
