@@ -268,10 +268,12 @@ class GptAttention(nn.Module):
     def _masked_attention(self, q: jax.Array, keys: jax.Array,
                           values: jax.Array, mask: jax.Array) -> jax.Array:
         """Scores, mask, softmax and context in float32 over the whole
-        [b, max_seq] view, for both decode paths; returns the context in
-        the compute type. The float32 conversion of the view belongs to
-        ``kv_gather`` and the rest to ``attn_scores``. Each operation is
-        traced where it always was, so the names move no instruction."""
+        view it is given ([b, max_seq] from the contiguous cache, the
+        table's columns from the paged arena), for both decode paths;
+        returns the context in the compute type. The float32 conversion of
+        the view belongs to ``kv_gather`` and the rest to ``attn_scores``.
+        Each operation is traced where it always was, so the names move no
+        instruction."""
         f32 = jnp.float32
         scale = self.cfg.head_dim**-0.5
         with jax.named_scope("attn_scores"):
@@ -302,6 +304,14 @@ class GptAttention(nn.Module):
         bit-identical to the contiguous path — the parity suite's contract.
         Rows whose table entries point at the trash block read garbage
         there, but only at positions the ``<= cursor`` mask already hides.
+
+        The view is as wide as the table: a caller that passes only the
+        first ``c`` columns (the engine does, ``c`` covering the longest
+        granted row of the dispatch) gets gather, conversion, scores,
+        softmax and context over ``c * block_t`` positions. Every live
+        row's cursor lies inside its granted blocks, so the positions left
+        out are ones the mask zeroes anyway; a row that writes beyond the
+        columns passed (dead, or past its budget) writes to trash.
         """
         cfg = self.cfg
         b, seg_len = x.shape[0], x.shape[1]
@@ -371,7 +381,7 @@ class GptAttention(nn.Module):
         mb = block_tables.shape[1]
         view = (b, mb * bt, cfg.n_heads, cfg.head_dim)
         with jax.named_scope("kv_gather"):
-            # every slot's whole view out of the arena
+            # every slot's view out of the arena, as wide as the table
             if quant:
                 # load-dequantized read: gather values + scales through
                 # the same table, dequantize to f32 (the einsums are f32

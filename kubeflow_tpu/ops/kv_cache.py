@@ -112,6 +112,17 @@ def kv_row_update(cache: jax.Array, new: jax.Array, cursors: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def _arena_block_map(block_t: int, max_seq: int, mb: int, trash: int):
+    """Index map of the paged kernels: grid step ``s`` -> the arena tile
+    that holds row ``s``'s cursor, chased through the prefetched table;
+    the trash tile for a cursor beyond the table's ``mb`` columns."""
+    def arena_block(s, cur, tbl):
+        col = jnp.minimum(cur[s], max_seq - 1) // block_t
+        return (jnp.where(col < mb, tbl[s, jnp.minimum(col, mb - 1)], trash),
+                0, 0, 0)
+    return arena_block
+
+
 def _paged_kernel(cur_ref, tbl_ref, arena_ref, new_ref, out_ref,
                   *, block_t: int, max_seq: int):
     s = pl.program_id(0)
@@ -140,7 +151,10 @@ def kv_block_update(arena: jax.Array, new: jax.Array, cursors: jax.Array,
     stays (S,) and each step touches exactly one [1, block_t, H, D] tile.
     Cursors at or beyond ``max_seq`` are a no-op for the data (the tile
     selection clamps, the in-kernel predicate skips the write); positions
-    whose table entry is the trash block land in the trash row.
+    whose table entry is the trash block land in the trash row, and so do
+    positions beyond the table where the caller passes only its first
+    columns (a decode dispatch bounded to the granted blocks: a row that
+    steps past the table is one whose output nobody reads).
     """
     N, block_t, H, D = arena.shape
     S = new.shape[0]
@@ -150,9 +164,7 @@ def kv_block_update(arena: jax.Array, new: jax.Array, cursors: jax.Array,
     if interpret is None:
         interpret = _interpret_default()
 
-    def arena_block(s, cur, tbl):
-        pos = jnp.minimum(cur[s], max_seq - 1)
-        return (tbl[s, jnp.minimum(pos // block_t, mb - 1)], 0, 0, 0)
+    arena_block = _arena_block_map(block_t, max_seq, mb, trash=N - 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -245,7 +257,7 @@ def kv_block_update_quant(arena: jax.Array, scales: jax.Array, new: jax.Array,
     [S, H, D] (or [S, 1, H, D]) bf16/f32. Quantizes ``new`` INSIDE the
     kernel (same math as :func:`quantize_kv`) and writes value + scale
     through the block table in one pass — both arenas alias in place. Same
-    out-of-range no-op contract as the bf16 kernel.
+    out-of-range and beyond-the-table contract as the bf16 kernel.
     """
     N, block_t, H, D = arena.shape
     S = new.shape[0]
@@ -255,9 +267,7 @@ def kv_block_update_quant(arena: jax.Array, scales: jax.Array, new: jax.Array,
     if interpret is None:
         interpret = _interpret_default()
 
-    def arena_block(s, cur, tbl):
-        pos = jnp.minimum(cur[s], max_seq - 1)
-        return (tbl[s, jnp.minimum(pos // block_t, mb - 1)], 0, 0, 0)
+    arena_block = _arena_block_map(block_t, max_seq, mb, trash=N - 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -292,9 +302,10 @@ def kv_block_update_ref(arena: jax.Array, seg: jax.Array, cursors: jax.Array,
     per row in one call).
 
     arena: [N, block_t, H, D]; seg: [S, L, H, D]; cursors: [S] (position of
-    ``seg[:, 0]``); tables: [S, MB]. Out-of-range positions are redirected
-    to the trash row (N-1) instead of being skipped so the whole update
-    stays one scatter per token.
+    ``seg[:, 0]``); tables: [S, MB]. Out-of-range positions (at or beyond
+    ``max_seq``, or beyond a table of which only the first columns were
+    passed) are redirected to the trash row (N-1) instead of being skipped
+    so the whole update stays one scatter per token.
     """
     N, block_t, _, _ = arena.shape
     S, L = seg.shape[:2]
@@ -303,7 +314,8 @@ def kv_block_update_ref(arena: jax.Array, seg: jax.Array, cursors: jax.Array,
     cursors = cursors.astype(jnp.int32)
     for j in range(L):
         pos = cursors + j
-        bi = jnp.clip(pos // block_t, 0, mb - 1)
-        blk = jnp.where(pos < max_seq, tables[rows, bi], N - 1)
+        bi = pos // block_t
+        blk = jnp.where((pos < max_seq) & (bi < mb),
+                        tables[rows, jnp.clip(bi, 0, mb - 1)], N - 1)
         arena = arena.at[blk, pos % block_t].set(seg[:, j].astype(arena.dtype))
     return arena

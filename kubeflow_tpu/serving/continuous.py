@@ -50,7 +50,8 @@ from ..runtime.tracing import TRACER, Span
 from ..tpu import profiling
 from .errors import (DeadlineExceeded, EngineClosed, FleetSaturated,
                      RequestCancelled)
-from .paged import KVBlockAllocator, KVReservation
+from .paged import (KVBlockAllocator, KVReservation, view_blocks,
+                    view_widths)
 
 #: admission priority classes; batch is shed first under saturation
 PRIORITIES = ("interactive", "batch")
@@ -306,7 +307,12 @@ class ContinuousBatcher:
         ``paged``: shared block-arena KV layout with a per-slot block table
         (default). ``paged=False`` keeps the contiguous per-slot cache as
         the parity ground truth — the same pattern as
-        ``ChipLedger(indexed=True)``.
+        ``ChipLedger(indexed=True)``. A paged decode dispatch reads the
+        arena through the block table's first columns only, up to the
+        longest granted row (``paged.view_blocks``; the stat
+        ``view_blocks`` of ``serving.engine.dispatch``, the gauge
+        ``serving_decode_view_blocks``): a step costs what the longest live
+        sequence needs, not ``max_seq`` a slot.
 
         ``kv_blocks``: allocatable arena blocks (None = full capacity
         parity, ``slots * ceil(max_seq / block_t)`` — no admission
@@ -403,6 +409,10 @@ class ContinuousBatcher:
             # trash block so unallocated positions can never hit real data
             self._tables = np.full((slots, self._max_blocks),
                                    self._alloc.trash, np.int32)
+            # a decode dispatch hands the model only the table's first
+            # columns, up to the longest granted row (view_blocks): jit
+            # specialises the decode program on each of these widths
+            self._view_widths = view_widths(self._max_blocks)
             self._slot_res: Dict[int, KVReservation] = {}
             # upper bound on each slot's device cursor at the dispatch
             # frontier — spec rounds advance the real cursor by a
@@ -411,6 +421,12 @@ class ContinuousBatcher:
         else:
             self.kv_block_t = 0
             self._alloc = None
+        # every view width's decode program is compiled once, at the first
+        # prewarm: "no" -> "asked" (prewarm) -> "done" (the engine thread,
+        # at a turn that finds no slot active). A prefill specialist ships
+        # its requests before they decode, a contiguous cache has one width.
+        self._view_warmup = ("no" if self.paged and self.role != "prefill"
+                             else "done")
         # -- chunked prefill (ISSUE 12) ------------------------------------
         self.prefill_chunk = effective_prefill_chunk(
             prefill_chunk, cfg.max_seq, self.kv_block_t or 1)
@@ -980,11 +996,18 @@ class ContinuousBatcher:
         pushed as ONE queue item so the worker admits them together —
         exercising the (prompt-bucket, group-bucket) prefill, the exact-n
         adopt, and (for the largest wave) the chunked decode step, all
-        through the production path. Compilations land in the persistent
-        JAX cache when one is configured. ``timeout`` becomes each dummy
+        through the production path. A paged engine's first prewarm also
+        runs the decode program once at every view width (``_warm_views``):
+        which width a dispatch takes depends on the longest live sequence,
+        so no wave of dummies would meet them all. Compilations land in the
+        persistent JAX cache when one is configured. ``timeout`` becomes each dummy
         request's deadline, so a wedged compile surfaces as
         :class:`DeadlineExceeded` instead of an 1800 s magic wait."""
         deadline = time.monotonic() + timeout
+        if self._view_warmup == "no":
+            # before the first wave is enqueued: the engine thread compiles
+            # the widths at the turn that takes the wave, ahead of admitting it
+            self._view_warmup = "asked"
         # default: EVERY group size 1.._group_pad — the adopt program is
         # traced per exact group size (admission chunks larger waves to
         # _group_pad), so a size first seen mid-run would compile inside
@@ -1646,6 +1669,32 @@ class ContinuousBatcher:
                     self._alloc.grant(res, self._alloc.blocks_for(ub))):
                 self._tables[slot, base + off] = blk
 
+    def _run_decode(self, tables: Tuple[Any, ...]) -> Tuple[str, Any]:
+        """One decode chunk, or one speculative round, of every slot on the
+        engine's state, reading the arena through ``tables`` (the block
+        table's first columns; empty for the contiguous cache). Returns the
+        event's kind and what the host fetches for it."""
+        if self.spec_k:
+            (self.cache, self.draft_cache, self.last_tok, self.rngs, toks,
+             acc) = self._spec_fn(
+                self.params, self._draft_params, self.cache, self.draft_cache,
+                self.last_tok, self.temps, self.rngs, *tables)
+            return "spec", (toks, acc)
+        self.cache, self.last_tok, self.rngs, toks = self._step_fn(
+            self.params, self.cache, self.last_tok, self.temps, self.rngs,
+            *tables)
+        return "chunk", toks
+
+    def _warm_views(self) -> None:
+        """Compile the decode program at every view width by running it
+        once on an all-trash table of that width: every row is dead there
+        (its writes go to the trash block, its tokens to nobody), and the
+        cursors and sampling state it moves are set anew by the adopt that
+        admits a request. Not for a turn with an active slot."""
+        for width in self._view_widths:
+            self._run_decode((jnp.full((self.slots, width), self._alloc.trash,
+                                       jnp.int32),))
+
     def _set_occupancy(self) -> None:
         active = len(self._active)
         METRICS.gauge("serving_continuous_active_slots",
@@ -2014,6 +2063,9 @@ class ContinuousBatcher:
             with profiling.annotate("serving.engine.reap"):
                 self._reap_pending()
                 self._reap_active()
+            if self._view_warmup == "asked" and not self._active:
+                self._warm_views()
+                self._view_warmup = "done"
             dispatched = False
             if self._imports and self._free and not self._draining:
                 # wire imports admit before fresh prompts: their
@@ -2043,35 +2095,25 @@ class ContinuousBatcher:
                 width = self.spec_k if self.spec_k else self.chunk
                 with profiling.annotate("serving.engine.dispatch",
                                         rows=self.slots * width,
-                                        live=len(self._active)):
+                                        live=len(self._active)) as span:
                     self._grant_active(width)
-                    extra = ((jnp.asarray(self._tables),)
-                             if self.paged else ())
-                    if self.spec_k:
-                        (self.cache, self.draft_cache, self.last_tok,
-                         self.rngs, toks, acc) = self._spec_fn(
-                            self.params, self._draft_params, self.cache,
-                            self.draft_cache, self.last_tok, self.temps,
-                            self.rngs, *extra)
-                        try:
-                            toks.copy_to_host_async()
-                            acc.copy_to_host_async()
-                        except Exception:
-                            pass
-                        events.append(("spec", (toks, acc),
-                                       dict(self._active),
-                                       time.perf_counter()))
-                    else:
-                        self.cache, self.last_tok, self.rngs, toks = \
-                            self._step_fn(self.params, self.cache,
-                                          self.last_tok, self.temps,
-                                          self.rngs, *extra)
-                        try:
-                            toks.copy_to_host_async()
-                        except Exception:
-                            pass
-                        events.append(("chunk", toks, dict(self._active),
-                                       time.perf_counter()))
+                    tables = ()
+                    if self.paged:
+                        view = view_blocks(self._tables, self._alloc.trash,
+                                           self._view_widths)
+                        tables = (jnp.asarray(self._tables[:, :view]),)
+                        span.set_metadata(view_blocks=view,
+                                          max_blocks=self._max_blocks)
+                        METRICS.gauge("serving_decode_view_blocks",
+                                      replica=self.engine_id).set(view)
+                    kind, out = self._run_decode(tables)
+                    try:
+                        for arr in jax.tree.leaves(out):
+                            arr.copy_to_host_async()
+                    except Exception:
+                        pass
+                    events.append((kind, out, dict(self._active),
+                                   time.perf_counter()))
                 dispatched = True
             # keep the dispatch frontier at most ``pipeline`` chunks
             # ahead of the processed state; when nothing new could be

@@ -35,10 +35,29 @@ trash, never into a re-granted block).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import List
+from typing import List, Tuple
+
+import numpy as np
 
 from ..runtime.metrics import METRICS
 from .errors import FleetSaturated
+
+
+def view_widths(max_blocks: int) -> Tuple[int, ...]:
+    """The widths, in block-table columns, at which a decode dispatch may
+    read the arena: the quarters of a row, ascending, the whole row last.
+    Few on purpose — the decode program is compiled once per width."""
+    return tuple(sorted({-(-max_blocks * q // 4) for q in (1, 2, 3, 4)}))
+
+
+def view_blocks(tables: np.ndarray, trash: int, widths: Tuple[int, ...]) -> int:
+    """The narrowest of ``widths`` that covers every granted column of the
+    host block table ``[slots, max_blocks]``: a column is live when any
+    row's entry in it is not the trash block, and granted blocks are a
+    prefix of their row, so the last live column is the longest row's."""
+    live = np.flatnonzero((tables != trash).any(axis=0))
+    last = int(live[-1]) + 1 if live.size else 0
+    return next(w for w in widths if w >= last)
 
 
 class KVBlocksExhausted(FleetSaturated):

@@ -296,6 +296,78 @@ def test_paged_engine_bit_identical_to_contiguous(params):
     assert base == paged
 
 
+class _Region:
+    """Stands in for ``profiling.annotate``'s region: keeps the stats."""
+
+    def __init__(self, log, name, stats):
+        self.log, self.name, self.stats = log, name, stats
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append((self.name, self.stats))
+
+    def set_metadata(self, **stats):
+        self.stats.update(stats)
+
+
+def test_decode_view_follows_the_longest_granted_row(params, monkeypatch):
+    """The paged dispatch hands the decode program the block table's first
+    ``view_blocks`` columns only (max_seq 128, blocks of 16: widths 2, 4, 6,
+    8). A 27-token prompt crosses position 32 in the middle of a 4-step
+    chunk (the view widens at the dispatch that grants the block, before
+    the crossing); it retires and a 60-token prompt takes its slot and
+    crosses 64; when that retires the width falls back to the short rows'.
+    The tokens stay the contiguous engine's, ``serving.engine.dispatch``
+    says how wide each dispatch read, and the gauge follows it."""
+    from kubeflow_tpu.runtime.metrics import METRICS
+    from kubeflow_tpu.tpu import profiling
+
+    jobs = [(prompt(1, 27), 14), (prompt(2, 5), 22), (prompt(3, 60), 10),
+            (prompt(4, 6), 24)]
+    kw = dict(slots=2, chunk=4, pipeline=1)
+    base = _run_jobs(CFG, params, jobs, paged=False, **kw)
+    log = []
+    monkeypatch.setattr(profiling, "annotate",
+                        lambda name, **stats: _Region(log, name, stats))
+    paged = _run_jobs(CFG, params, jobs, paged=True, engine_id="view", **kw)
+    assert base == paged
+    dispatch = [stats for name, stats in log if name == "serving.engine.dispatch"]
+    views = [d["view_blocks"] for d in dispatch]
+    assert all(d["max_blocks"] == 8 and d["view_blocks"] <= 8 for d in dispatch)
+    assert {2, 4, 6} <= set(views) <= {2, 4, 6, 8}
+    assert views[0] == 2 and views[-1] == 2          # widens, and falls again
+    assert METRICS.gauge("serving_decode_view_blocks", replica="view").value == views[-1]
+    # the contiguous engine has no table: its dispatches carry neither stat
+    log.clear()
+    _run_jobs(CFG, params, jobs[:1], paged=False, **kw)
+    assert all("view_blocks" not in stats for name, stats in log
+               if name == "serving.engine.dispatch")
+
+
+def test_prewarm_compiles_every_view_width_and_leaves_the_engine_sound(params):
+    """Which width a dispatch takes depends on the traffic, so the first
+    prewarm of a paged engine runs the decode program at every width (on
+    an all-trash table, with no slot active). Afterwards no width is new
+    to jit, and requests decode as on an engine never prewarmed."""
+    jobs = [(prompt(s, n), b) for s, n, b in MIXED_JOBS[:4]]
+    base = _run_jobs(CFG, params, jobs, slots=2, paged=False)
+    eng = ContinuousBatcher(CFG, params, slots=2, paged=True)
+    try:
+        assert eng._view_widths == (2, 4, 6, 8) and eng._view_warmup == "no"
+        eng.prewarm(8)
+        assert eng._view_warmup == "done"
+        compiled = eng._step_fn._cache_size()
+        assert compiled == len(eng._view_widths)
+        futs = [eng.submit(pr, b) for pr, b in jobs]
+        assert [f.result(timeout=180) for f in futs] == base
+        eng.prewarm(8)                                # once is enough
+        assert eng._step_fn._cache_size() == compiled
+    finally:
+        eng.close()
+
+
 def test_tiny_arena_backpressure_completes_all_and_stays_bit_identical(params):
     """An arena far smaller than slots*max_blocks forces admission
     back-pressure (requests wait for retirements to free blocks). Every

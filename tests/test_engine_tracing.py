@@ -110,6 +110,10 @@ def test_region_counts_ride_as_event_stats(capture):
         by.setdefault(s.name, []).append(s.stats)
     dispatch = by[ENGINE + "dispatch"]
     assert all(d["rows"] == 2 * 4 and 1 <= d["live"] <= 2 for d in dispatch)
+    # the table's columns each dispatch read: 2 of 8 (blocks of 16) for the
+    # short rows, 4 once the 40-token prompt decodes beside them
+    assert all(d["max_blocks"] == 8 for d in dispatch)
+    assert {4} <= {d["view_blocks"] for d in dispatch} <= {2, 4}
     # a wave's largest batched prompt bucket: 16, or 0 where its one
     # request went the chunked way; 5 requests reached an admission wave
     admit = by[ENGINE + "admit"]
@@ -279,7 +283,8 @@ WHILE = "%while.1 = (s32[]) while((s32[]) %t), condition=%c, body=%b"
 def serve_obs():
     spans = [
         sp("turn", 0, 1000), sp("idle", 0, 100), sp("drain", 100, 10, arrivals=1),
-        sp("dispatch", 200, 100, rows=32, live=3), sp("fetch", 400, 500, kind="chunk"),
+        sp("dispatch", 200, 100, rows=32, live=3, view_blocks=2, max_blocks=8),
+        sp("fetch", 400, 500, kind="chunk"),
         sp("deliver", 900, 50, kind="chunk", rows=32, tokens=20, retired=1),
         sp("turn", 1000, 500), sp("fetch", 1100, 100, kind="first"),
         sp("deliver", 1200, 10, kind="first", rows=2, tokens=2, retired=0),
@@ -331,6 +336,7 @@ SERVE_READERS = {
     "decode_kv_gather_share.serve": 100.0 * 200 / 800,         # inside jit_step only
     "decode_kv_view_share.serve": 100.0 * (200 + 500) / 800,   # kv_gather or attn_scores
     "decode_unscoped_share.serve": 100.0 * 100 / 800,          # named after the loop alone
+    "decode_view_block_share.serve": 100.0 * 2 / 8,            # columns read of the table's
 }
 TRAIN_READERS = {
     "remat_forward_share.train": 100.0 * 2 * 200 / 2000,       # both chips, over both busy times

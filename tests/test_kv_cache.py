@@ -339,3 +339,103 @@ def test_block_update_quant_matches_quantize_then_scatter(interpret):
         want_s[blk, pos % block_t] = np.asarray(s[row])
     np.testing.assert_array_equal(np.asarray(got_q), want_q)
     np.testing.assert_array_equal(np.asarray(got_s), want_s)
+
+
+# -- the decode view bounded to the granted columns — ISSUE 27 ----------------
+
+def test_block_update_beyond_a_bounded_table_writes_only_trash():
+    """A caller may pass only the table's first columns (the decode view
+    bounded to the longest granted row). A row whose cursor lies beyond
+    them — dead, or stepping past its budget — must write to trash, never
+    through the clamped last column into a live block; rows inside the
+    columns write as with the whole table."""
+    from kubeflow_tpu.ops.kv_cache import kv_block_update_quant
+
+    S, MB, bt, H, D = 3, 4, 4, 2, 4
+    max_seq = MB * bt
+    n_blocks = S * MB
+    whole = jnp.arange(n_blocks, dtype=jnp.int32).reshape(S, MB)
+    bounded = whole[:, :2]                      # positions 0..7
+    cursors = jnp.asarray([8, 13, 5], jnp.int32)    # two beyond, one inside
+    new = jnp.ones((S, H, D), jnp.float32)
+    arena = jnp.zeros((n_blocks + 1, bt, H, D), jnp.float32)
+    for out in (
+        kv_block_update(arena, new, cursors, bounded, max_seq=max_seq,
+                        interpret=True),
+        kv_block_update_ref(arena, new[:, None], cursors, bounded,
+                            max_seq=max_seq),
+    ):
+        out = np.asarray(out)
+        assert out[whole[2, 1], 1].all()            # the row inside wrote
+        assert out[:n_blocks].sum() == H * D        # ...and no other real block
+        assert out[n_blocks].sum() > 0              # the others went to trash
+    q, s = kv_block_update_quant(
+        jnp.zeros(arena.shape, jnp.int8), jnp.zeros(arena.shape[:3] + (1,)),
+        new, cursors, bounded, max_seq=max_seq, interpret=True)
+    assert np.abs(np.asarray(q)[:n_blocks]).sum() == 127 * H * D
+    assert np.asarray(s)[:n_blocks].sum() == pytest.approx(H / 127.0)
+
+
+@pytest.mark.parametrize("dead_row", [False, True])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("seg_len", [1, 4])
+def test_paged_decode_bounded_table_matches_whole_table(seg_len, kv_dtype, dead_row):
+    """``GptLM.apply`` with the block table cut to its live columns gives
+    the whole table's logits for every live row (to float32 rounding: the
+    positions left out carry exactly zero weight, only the sums' order
+    changes), and the same arena and cursors. The dead row's cursor lies
+    beyond the bounded table: its write must land in trash."""
+    from kubeflow_tpu.models.gpt import GptConfig, GptLM
+
+    cfg = GptConfig(d_model=32, n_layers=2, n_heads=2, d_ff=64,
+                    max_seq=64, vocab_size=128)
+    S, bt, mb = 3, 8, 8
+    n_blocks = S * mb
+    quant = kv_dtype == "int8"
+    params = GptLM(cfg).init(jax.random.PRNGKey(0),
+                             jnp.zeros((1, 4), jnp.int32))["params"]
+    model = GptLM(cfg, decode=True, per_slot=True, kv_kernel=False, paged=True,
+                  kv_blocks=n_blocks + 1, kv_block_t=bt, kv_dtype=kv_dtype)
+    rng = np.random.default_rng(27)
+    granted = [2, 3, 0 if dead_row else 1]       # blocks per row: a prefix
+    cursors = [9, 17, 40 if dead_row else 2]     # the segment stays inside them
+    tables = np.full((S, mb), n_blocks, np.int32)
+    free = list(rng.permutation(n_blocks))
+    for row, n in enumerate(granted):
+        tables[row, :n] = [free.pop() for _ in range(n)]
+    arena = (n_blocks + 1, bt, cfg.n_heads, cfg.head_dim)
+
+    def layer():
+        if quant:
+            att = {name: jnp.asarray(rng.integers(-127, 128, arena), jnp.int8)
+                   for name in ("k_arena", "v_arena")}
+            att.update({name: jnp.asarray(rng.uniform(0.001, 0.02, arena[:3] + (1,)),
+                                          jnp.float32)
+                        for name in ("k_scale", "v_scale")})
+        else:
+            att = {name: jnp.asarray(rng.normal(size=arena), cfg.dtype)
+                   for name in ("k_arena", "v_arena")}
+        att["cursors"] = jnp.asarray(cursors, jnp.int32)
+        return {"attention": att}
+
+    cache = {f"block_{i}": layer() for i in range(cfg.n_layers)}
+    ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (S, seg_len)), jnp.int32)
+
+    def run(table):
+        return jax.jit(lambda t: model.apply(
+            {"params": params, "cache": cache}, ids, mutable=["cache"],
+            block_tables=t))(jnp.asarray(table))
+
+    whole_logits, whole = run(tables)
+    view = max(granted) + 1                       # a width above the longest row
+    cut_logits, cut = run(tables[:, :view])
+    live = [row for row, n in enumerate(granted) if n]
+    np.testing.assert_allclose(np.asarray(cut_logits)[live],
+                               np.asarray(whole_logits)[live], rtol=2e-5, atol=2e-5)
+    for name, att in whole["cache"].items():
+        for key, value in att["attention"].items():
+            got = np.asarray(cut["cache"][name]["attention"][key])
+            want = np.asarray(value)
+            if key != "cursors":                  # dead rows share the trash block
+                got, want = got[:n_blocks], want[:n_blocks]
+            np.testing.assert_array_equal(got, want, err_msg=f"{name}/{key}")
