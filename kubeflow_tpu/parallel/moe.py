@@ -124,3 +124,78 @@ class MoEMlp(nn.Module):
         y = jnp.einsum("ecd,tec->td", out, combine.astype(self.dtype))
         y = _constrain(y.reshape(orig_shape), self.mesh, P(BATCH_AXES, *([None] * (len(orig_shape) - 1))))
         return y.astype(x.dtype)
+
+
+# -- dropless expert layer that is told which experts it holds ----------------
+#
+# The serving path's expert layer (models/mimo.py). Unlike ``MoEMlp`` above it
+# has no capacity and drops nothing: the router scores ALL experts, the
+# assignments that land on the experts held here are sorted by expert and go
+# through grouped matrix products, and the layer returns the held experts'
+# part of the result. What the experts held elsewhere would add is left out
+# (an exchange across chips would bring it; on one chip there is none, and
+# nothing stands in for it).
+
+def sigmoid_top_k(h: jax.Array, router: jax.Array, bias: jax.Array, k: int
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """Router in float32 over every expert: ``s = sigmoid(h @ router)``, the
+    ``k`` experts with the largest ``s + bias`` (the bias enters the choice
+    only), weights ``s`` normalised over the k chosen. h: [T, d]. Returns
+    (expert ids [T, k] int32, weights [T, k] float32)."""
+    f32 = jnp.float32
+    scores = jax.nn.sigmoid(jnp.dot(h.astype(f32), router.astype(f32),
+                                    precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + bias.astype(f32), k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+
+
+def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
+                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                     first_held: int = 0, live: Optional[jax.Array] = None,
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of a SwiGLU expert layer, dropless.
+
+    h [T, d]; idx / weights [T, k] from the router over all experts; the
+    experts ``first_held .. first_held + n_held - 1`` live here, stacked in
+    ``w_gate`` / ``w_up`` [n_held, d, f] and ``w_down`` [n_held, f, d].
+    ``live`` [T] bool leaves rows out (padding, dead slots). Returns
+    (``sum over held chosen experts of weight * expert(h)`` [T, d] in h's
+    type, stats int32 [3] = assignments on held experts, the busiest held
+    expert's, held experts touched).
+
+    Every assignment has a row of its own in a [T * k] buffer sorted by
+    expert (assignments elsewhere sort past the last group), so no skew
+    drops a token; ``jax.lax.ragged_dot`` multiplies each group by its
+    expert and leaves the rows past the groups alone.
+    """
+    T, k = idx.shape
+    n_held = w_gate.shape[0]
+    local = idx.reshape(-1) - first_held
+    held = (local >= 0) & (local < n_held)
+    if live is not None:
+        held = held & jnp.repeat(live, k)
+    with jax.named_scope("moe_dispatch"):
+        key = jnp.where(held, local, n_held)
+        order = jnp.argsort(key, stable=True)                    # [T*k]
+        sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
+                        axis=0, dtype=jnp.int32)                 # [n_held]
+        rows = h[order // k]                                     # [T*k, d]
+        in_group = jnp.arange(T * k) < jnp.sum(sizes)
+    with jax.named_scope("moe_experts"):
+        gate = jax.lax.ragged_dot(rows, w_gate, sizes,
+                                  preferred_element_type=jnp.float32)
+        up = jax.lax.ragged_dot(rows, w_up, sizes,
+                                preferred_element_type=jnp.float32)
+        mid = (jax.nn.silu(gate) * up).astype(h.dtype)
+        out = jax.lax.ragged_dot(mid, w_down, sizes,
+                                 preferred_element_type=jnp.float32)
+    with jax.named_scope("moe_combine"):
+        out = jnp.where(in_group[:, None], out, 0.0)
+        back = jnp.argsort(order)                                # inverse
+        per_choice = out[back].reshape(T, k, -1)
+        w = jnp.where(held.reshape(T, k), weights, 0.0)
+        y = jnp.einsum("tkd,tk->td", per_choice, w.astype(jnp.float32))
+    stats = jnp.stack([jnp.sum(sizes), jnp.max(sizes),
+                       jnp.sum(sizes > 0, dtype=jnp.int32)]).astype(jnp.int32)
+    return y.astype(h.dtype), stats

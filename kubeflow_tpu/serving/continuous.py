@@ -44,14 +44,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.gpt import GptConfig, GptLM
 from ..runtime.metrics import METRICS
 from ..runtime.tracing import TRACER, Span
 from ..tpu import profiling
 from .errors import (DeadlineExceeded, EngineClosed, FleetSaturated,
                      RequestCancelled)
-from .paged import (KVBlockAllocator, KVReservation, view_blocks,
-                    view_widths)
+from .family import family_for
+from .paged import (KVBlockAllocator, KVReservation, WindowRings,
+                    view_blocks, view_widths)
 
 #: admission priority classes; batch is shed first under saturation
 PRIORITIES = ("interactive", "batch")
@@ -241,6 +241,12 @@ class _ChunkedPrefill:
     key: Any
     pos: int = 0                       # prompt tokens prefilled so far
     res: Optional[KVReservation] = None
+    #: a family that prefills straight into the arenas: the row's block
+    #: table while it fills (the engine's own row stays on trash until the
+    #: request is activated, so decode dispatches in between write nothing
+    #: of this dead row into its blocks) and its expert counters so far
+    table: Optional[np.ndarray] = None
+    stats: Any = None
 
 
 @dataclass(eq=False)
@@ -286,7 +292,7 @@ class ContinuousBatcher:
     cache chain in dispatch order.
     """
 
-    def __init__(self, cfg: GptConfig, params: Any, slots: int = 8,
+    def __init__(self, cfg: Any, params: Any, slots: int = 8,
                  chunk: int = 16, pipeline: int = 3,
                  kv_kernel: Optional[bool] = None,
                  engine_id: str = "0",
@@ -296,13 +302,20 @@ class ContinuousBatcher:
                  kv_blocks: Optional[int] = None,
                  kv_block_t: int = 16,
                  prefill_chunk: Optional[int] = None,
-                 spec_draft: Optional[Tuple[GptConfig, Any]] = None,
+                 spec_draft: Optional[Tuple[Any, Any]] = None,
                  spec_k: int = 4,
                  kv_dtype: str = "bf16",
                  role: str = "unified",
                  model_id: str = "",
                  handoff_sink: Optional[Callable[["_Request", bytes], None]] = None):
-        """New ISSUE-12 knobs (defaults keep every pre-existing behavior):
+        """``cfg`` names the model family by its type (``serving/family.py``):
+        the engine builds no model itself. A family with window-attention
+        layers keeps a second kind of paged cache beside the block table
+        (``paged.WindowRings``: a ring of blocks a slot, given back as the
+        cursor leaves them behind), sized from ``slots``, the window,
+        ``kv_block_t`` and ``chunk``: two kinds of cache add no knob.
+
+        New ISSUE-12 knobs (defaults keep every pre-existing behavior):
 
         ``paged``: shared block-arena KV layout with a per-slot block table
         (default). ``paged=False`` keeps the contiguous per-slot cache as
@@ -397,13 +410,24 @@ class ContinuousBatcher:
         self._group_pad = min(slots, MAX_GROUP)
         # -- paged KV layout (ISSUE 12) ------------------------------------
         self.paged = bool(paged)
+        n_blocks = 0
         if self.paged:
             self.kv_block_t = _block_tile(cfg.max_seq, kv_block_t)
             self._max_blocks = cfg.max_seq // self.kv_block_t
             n_blocks = (int(kv_blocks) if kv_blocks
                         else slots * self._max_blocks)
+        else:
+            self.kv_block_t = 0
+        # kv_kernel: per-slot KV-write strategy (None = the
+        # KUBEFLOW_TPU_KV_KERNEL env default; see models.gpt)
+        self.family = family_for(
+            cfg, slots=slots, paged=self.paged, kv_blocks=n_blocks,
+            kv_block_t=self.kv_block_t, kv_kernel=kv_kernel,
+            kv_dtype=self.kv_dtype)
+        if self.paged:
             self._alloc: Optional[KVBlockAllocator] = KVBlockAllocator(
-                n_blocks, self.kv_block_t, engine_id=self.engine_id)
+                n_blocks, self.kv_block_t, engine_id=self.engine_id,
+                kind="full" if self.family.window else "")
             # ONE host-side block table shared by every layer (each
             # dispatch snapshots it to device); entries default to the
             # trash block so unallocated positions can never hit real data
@@ -419,8 +443,15 @@ class ContinuousBatcher:
             # data-dependent amount, so granting tracks the bound
             self._ub_cursor = np.zeros((slots,), np.int64)
         else:
-            self.kv_block_t = 0
             self._alloc = None
+        # the window kind of cache, for a family that has window layers: a
+        # dispatch moves a cursor by up to ``chunk`` positions. Where it is
+        # there, every prompt is prefilled in chunks straight into the
+        # arenas (no private cache, no adopt): the engine's ring paths and
+        # that prefill lane are one capability, asked of ``self._rings``.
+        self._rings: Optional[WindowRings] = (
+            self.family.rings(self.chunk, self.engine_id)
+            if self.family.window else None)
         # every view width's decode program is compiled once, at the first
         # prewarm: "no" -> "asked" (prewarm) -> "done" (the engine thread,
         # at a turn that finds no slot active). A prefill specialist ships
@@ -444,24 +475,24 @@ class ContinuousBatcher:
             self.spec_k = max(2, int(spec_k))
             self._draft_cfg = draft_cfg
             self._draft_params = draft_params
-            self._draft_model = GptLM(draft_cfg, decode=True, per_slot=True,
-                                      kv_kernel=False)
-            self._draft_prefill_model = GptLM(draft_cfg, decode=True)
-        # kv_kernel: per-slot KV-write strategy (None = the
-        # KUBEFLOW_TPU_KV_KERNEL env default; see models.gpt)
-        if self.paged:
-            self.model = GptLM(cfg, decode=True, per_slot=True,
-                               kv_kernel=kv_kernel, paged=True,
-                               kv_blocks=self._alloc.n_blocks + 1,
-                               kv_block_t=self.kv_block_t,
-                               kv_dtype=self.kv_dtype)
-        else:
-            self.model = GptLM(cfg, decode=True, per_slot=True,
-                               kv_kernel=kv_kernel)
-        self._prefill_model = GptLM(cfg, decode=True)  # [1, P], scalar cursor
-        self.cache = self._fresh_cache()
+            # the draft stays contiguous: it is small by construction, so
+            # the paged arena's memory win does not apply to it
+            self._draft_family = family_for(draft_cfg, slots=slots,
+                                            kv_kernel=False)
+        if self._rings is not None:
+            # a family that prefills into the arenas has no private cache
+            # to adopt, to ship or to verify drafts against
+            if self.spec_k or self.role != "unified":
+                raise ValueError(
+                    f"{type(cfg).__name__}: speculation and the prefill / "
+                    "decode roles are not built for this model family")
+            if not self.prefill_chunk:
+                raise ValueError(
+                    f"{type(cfg).__name__} prefills in chunks: prefill_chunk "
+                    "must not be 0")
+        self.cache = self.family.fresh_cache()
         if self.spec_k:
-            self.draft_cache = self._fresh_draft_cache()
+            self.draft_cache = self._draft_family.fresh_cache()
         self.last_tok = jnp.zeros((slots,), jnp.int32)
         # per-slot sampling state: temperature 0 = greedy; each admission
         # folds a fresh counter into the base key so sampled requests draw
@@ -486,11 +517,15 @@ class ContinuousBatcher:
         self._handoff: List[_Request] = []
         #: wire-format KV imports awaiting a slot (decode role, ISSUE 18)
         self._imports: "collections.deque[_Import]" = collections.deque()
-        self._step_fn = self._build_step()
-        self._adopt_fn = self._build_adopt()
-        self._import_fn = self._build_import() if self.paged else None
+        self._step_fn = self.family.build_step(self.chunk)
+        batched = self._rings is None
+        self._adopt_fn = self.family.build_adopt() if batched else None
+        self._import_fn = (self.family.build_import()
+                           if batched and self.paged else None)
         self._spec_fn = self._build_spec_step() if self.spec_k else None
-        self._draft_adopt_fn = self._build_draft_adopt() if self.spec_k else None
+        self._draft_adopt_fn = (self._draft_family.build_draft_adopt()
+                                if self.spec_k else None)
+        self._activate_fn = None if batched else self.family.build_activate()
         self._prefill_fns: Dict[Tuple[int, int, bool], Any] = {}
         # reusable zero prefill-cache per group bucket: prefill does NOT
         # donate its cache input, so one template serves every admission —
@@ -502,85 +537,8 @@ class ContinuousBatcher:
         self._worker.start()
 
     # -- compiled pieces -----------------------------------------------------
-    def _fresh_cache(self) -> Dict[str, Any]:
-        cfg, S = self.cfg, self.slots
-        if self.paged:
-            arena = (self._alloc.n_blocks + 1, self.kv_block_t,
-                     cfg.n_heads, cfg.head_dim)
-            quant = self.kv_dtype == "int8"
-            arena_dtype = jnp.int8 if quant else cfg.dtype
-
-            def layer() -> Dict[str, Any]:
-                att = {
-                    "k_arena": jnp.zeros(arena, arena_dtype),
-                    "v_arena": jnp.zeros(arena, arena_dtype),
-                    "cursors": jnp.zeros((S,), jnp.int32),
-                }
-                if quant:
-                    scale = arena[:3] + (1,)
-                    att["k_scale"] = jnp.zeros(scale, jnp.float32)
-                    att["v_scale"] = jnp.zeros(scale, jnp.float32)
-                return {"attention": att}
-
-            return {f"block_{i}": layer() for i in range(cfg.n_layers)}
-        kv = (S, cfg.max_seq, cfg.n_heads, cfg.head_dim)
-        return {
-            f"block_{i}": {"attention": {
-                "k": jnp.zeros(kv, cfg.dtype),
-                "v": jnp.zeros(kv, cfg.dtype),
-                "cursors": jnp.zeros((S,), jnp.int32),
-            }}
-            for i in range(cfg.n_layers)
-        }
-
-    def _fresh_draft_cache(self) -> Dict[str, Any]:
-        # the draft stays contiguous: it is small by construction, so the
-        # paged arena's memory win does not apply to it
-        dcfg, S = self._draft_cfg, self.slots
-        kv = (S, dcfg.max_seq, dcfg.n_heads, dcfg.head_dim)
-        return {
-            f"block_{i}": {"attention": {
-                "k": jnp.zeros(kv, dcfg.dtype),
-                "v": jnp.zeros(kv, dcfg.dtype),
-                "cursors": jnp.zeros((S,), jnp.int32),
-            }}
-            for i in range(dcfg.n_layers)
-        }
-
-    def _build_step(self):
-        model = self.model
-        chunk = self.chunk
-        paged = self.paged
-
-        # donate cache+tok+rngs: without donation every dispatch COPIES the
-        # full multi-GB KV cache into fresh output buffers (measured: the
-        # copy, not the math, dominated chunked stepping)
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 4))
-        def step(params, cache, tok, temps, rngs, *tables):
-            def one(carry, _):
-                cache, tok, rngs = carry
-                kwargs = {"block_tables": tables[0]} if paged else {}
-                logits, updated = model.apply(
-                    {"params": params, "cache": cache}, tok[:, None],
-                    mutable=["cache"], **kwargs
-                )
-                with jax.named_scope("sample"):
-                    lg = logits[:, -1]                           # [slots, vocab]
-                    greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-                    pairs = jax.vmap(jax.random.split)(rngs)   # [slots, 2, 2]
-                    rngs, keys = pairs[:, 0], pairs[:, 1]
-                    sampled = jax.vmap(
-                        lambda k, l, t: jax.random.categorical(k, l / jnp.maximum(t, 1e-6))
-                    )(keys, lg, temps).astype(jnp.int32)
-                    nxt = jnp.where(temps > 0.0, sampled, greedy)
-                return (updated["cache"], nxt, rngs), nxt
-
-            (cache, tok, rngs), toks = jax.lax.scan(
-                one, (cache, tok, rngs), None, length=chunk)
-            return cache, tok, rngs, jnp.moveaxis(toks, 0, 1)  # [slots, chunk]
-
-        return step
-
+    # (the family's: serving/family.py builds every program that knows what
+    # a cache leaf is; the engine keeps the ones that only sequence models)
     def _build_spec_step(self):
         """One speculative round: the draft model greedily proposes
         ``spec_k`` tokens (``spec_k - 1`` of them verifiable), the target
@@ -598,17 +556,10 @@ class ContinuousBatcher:
         unmasked. Sampled slots accept exactly one token per round, drawn
         from the verify logits at position 0 (one key split per round).
         """
-        model, draft_model = self.model, self._draft_model
+        model, draft_model = self.family.model, self._draft_family.model
         k = self.spec_k
         paged = self.paged
-
-        def _rollback(cache, delta):
-            out = {}
-            for name, layer in cache.items():
-                att = dict(layer["attention"])
-                att["cursors"] = att["cursors"] - delta
-                out[name] = {"attention": att}
-            return out
+        _rollback = self.family.rollback
 
         @functools.partial(jax.jit, donate_argnums=(2, 3, 4, 6))
         def spec(params, dparams, cache, dcache, tok, temps, rngs, *tables):
@@ -654,145 +605,6 @@ class ContinuousBatcher:
 
         return spec
 
-    def _build_adopt(self):
-        if self.paged:
-            bt = self.kv_block_t
-            quant = self.kv_dtype == "int8"
-
-            @functools.partial(jax.jit, donate_argnums=(0, 5, 6, 7))
-            def paged_adopt(cache, small, block_ids, slots, true_lens,
-                            last_tok, temps, rngs, first_toks, temperatures,
-                            slot_rngs):
-                """Paged adoption: scatter each prefill row's first ``L``
-                positions (``L = block_ids.shape[1] * block_t`` — the
-                prompt bucket or the chunked-prefill span, both whole
-                blocks by construction) into the arena rows named by
-                ``block_ids``. Rows' trailing entries are the trash block,
-                so bucket padding past the granted blocks lands in trash;
-                padding inside the last granted block sits above the
-                cursor, which the mask hides until decode overwrites it.
-                int8 arenas quantize here with the SAME quantize_kv the KV
-                wire exporter uses — a moved and a never-moved request land
-                byte-identical int8 blocks."""
-                from ..ops.kv_cache import quantize_kv
-
-                n = slots.shape[0]
-                nb = block_ids.shape[1]
-                ids = block_ids.reshape(-1)
-                out = {}
-                for name, layer in cache.items():
-                    att, small_att = layer["attention"], small[name]["attention"]
-                    shape = small_att["k"].shape                 # [n_pad, max_seq, h, d]
-                    seg_k = small_att["k"][:n, :nb * bt].reshape(
-                        n * nb, bt, shape[2], shape[3])
-                    seg_v = small_att["v"][:n, :nb * bt].reshape(
-                        n * nb, bt, shape[2], shape[3])
-                    upd = {"cursors": att["cursors"].at[slots].set(true_lens)}
-                    if quant:
-                        kq, ks = quantize_kv(seg_k)
-                        vq, vs = quantize_kv(seg_v)
-                        upd["k_arena"] = att["k_arena"].at[ids].set(kq)
-                        upd["v_arena"] = att["v_arena"].at[ids].set(vq)
-                        upd["k_scale"] = att["k_scale"].at[ids].set(ks)
-                        upd["v_scale"] = att["v_scale"].at[ids].set(vs)
-                    else:
-                        upd["k_arena"] = att["k_arena"].at[ids].set(
-                            seg_k.astype(att["k_arena"].dtype))
-                        upd["v_arena"] = att["v_arena"].at[ids].set(
-                            seg_v.astype(att["v_arena"].dtype))
-                    out[name] = {"attention": upd}
-                return (out, last_tok.at[slots].set(first_toks),
-                        temps.at[slots].set(temperatures),
-                        rngs.at[slots].set(slot_rngs))
-
-            return paged_adopt
-
-        @functools.partial(jax.jit, donate_argnums=(0, 4, 5, 6))
-        def adopt(cache, small, slots, true_lens, last_tok, temps, rngs,
-                  first_toks, temperatures, slot_rngs):
-            """Splice prefill-cache rows ``0..n-1`` of ``small`` (padded to
-            a group bucket — padding rows beyond n are ignored) into cache
-            rows ``slots[0..n-1]`` and reset those cursors to the TRUE
-            prompt lengths (bucket padding beyond them stays invisible and
-            is overwritten by the next decode steps). Also installs each
-            slot's sampling state. The group size n rides the arg shapes
-            (jit retraces per size); the per-row dynamic_update_slice chain
-            stays in place under donation — no full-cache pass."""
-            n = slots.shape[0]
-            out = {}
-            for name, layer in cache.items():
-                att, small_att = layer["attention"], small[name]["attention"]
-                k, v = att["k"], att["v"]
-                for i in range(n):
-                    k = jax.lax.dynamic_update_slice(
-                        k, small_att["k"][i:i + 1], (slots[i], 0, 0, 0))
-                    v = jax.lax.dynamic_update_slice(
-                        v, small_att["v"][i:i + 1], (slots[i], 0, 0, 0))
-                cursors = att["cursors"].at[slots].set(true_lens)
-                out[name] = {"attention": {"k": k, "v": v, "cursors": cursors}}
-            return (out, last_tok.at[slots].set(first_toks),
-                    temps.at[slots].set(temperatures),
-                    rngs.at[slots].set(slot_rngs))
-
-        return adopt
-
-    def _build_draft_adopt(self):
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def draft_adopt(dcache, small, slots, true_lens):
-            """Splice draft-prefill rows into the (contiguous) draft cache
-            — the sampling state lives with the target adopt; the draft
-            only needs KV + cursors."""
-            n = slots.shape[0]
-            out = {}
-            for name, layer in dcache.items():
-                att, small_att = layer["attention"], small[name]["attention"]
-                k, v = att["k"], att["v"]
-                for i in range(n):
-                    k = jax.lax.dynamic_update_slice(
-                        k, small_att["k"][i:i + 1], (slots[i], 0, 0, 0))
-                    v = jax.lax.dynamic_update_slice(
-                        v, small_att["v"][i:i + 1], (slots[i], 0, 0, 0))
-                cursors = att["cursors"].at[slots].set(true_lens)
-                out[name] = {"attention": {"k": k, "v": v, "cursors": cursors}}
-            return out
-
-        return draft_adopt
-
-    def _build_import(self):
-        """Jitted KV-wire import (decode role): scatter one request's
-        pre-filled blocks — [nb, block_t, h, d] per layer, plus the f32
-        scale blocks when int8 — into the arena rows just granted to it,
-        and install cursor/sampling state exactly as adoption would. One
-        retrace per distinct block count (shape-keyed under jit), same as
-        the prompt-bucketed adopt."""
-        quant = self.kv_dtype == "int8"
-
-        @functools.partial(jax.jit, donate_argnums=(0, 3, 4, 5))
-        def import_kv(cache, wire, block_ids, last_tok, temps, rngs,
-                      slot, true_len, first_tok, temperature, key):
-            out = {}
-            for name, layer in cache.items():
-                att = layer["attention"]
-                w = wire[name]
-                upd = {
-                    "k_arena": att["k_arena"].at[block_ids].set(
-                        w["k"].astype(att["k_arena"].dtype)),
-                    "v_arena": att["v_arena"].at[block_ids].set(
-                        w["v"].astype(att["v_arena"].dtype)),
-                    "cursors": att["cursors"].at[slot].set(true_len),
-                }
-                if quant:
-                    upd["k_scale"] = att["k_scale"].at[block_ids].set(
-                        w["k_scale"])
-                    upd["v_scale"] = att["v_scale"].at[block_ids].set(
-                        w["v_scale"])
-                out[name] = {"attention": upd}
-            return (out, last_tok.at[slot].set(first_tok),
-                    temps.at[slot].set(temperature),
-                    rngs.at[slot].set(key))
-
-        return import_kv
-
     def _prefill_group(self, prompts: Sequence[np.ndarray],
                        temperatures: Sequence[float], keys,
                        draft: bool = False) -> Tuple[Any, Any]:
@@ -810,7 +622,7 @@ class ContinuousBatcher:
         if n > n_pad:
             raise ValueError(f"admission group of {n} exceeds pad {n_pad}")
         if (bucket, n_pad, draft) not in self._prefill_fns:
-            model = self._draft_prefill_model if draft else self._prefill_model
+            model = (self._draft_family if draft else self.family).prefill_model
 
             @jax.jit
             def prefill(params, cache, ids, true_lens, temperatures, keys):
@@ -830,17 +642,9 @@ class ContinuousBatcher:
                 return updated["cache"], first
 
             self._prefill_fns[(bucket, n_pad, draft)] = prefill
-        cfg = self._draft_cfg if draft else self.cfg
         if (n_pad, draft) not in self._zero_small:
-            kv = (n_pad, cfg.max_seq, cfg.n_heads, cfg.head_dim)
-            self._zero_small[(n_pad, draft)] = {
-                f"block_{i}": {"attention": {
-                    "k": jnp.zeros(kv, cfg.dtype),
-                    "v": jnp.zeros(kv, cfg.dtype),
-                    "cursor": jnp.zeros((), jnp.int32),
-                }}
-                for i in range(cfg.n_layers)
-            }
+            self._zero_small[(n_pad, draft)] = (
+                self._draft_family if draft else self.family).prefill_cache(n_pad)
         small = self._zero_small[(n_pad, draft)]
         ids = np.zeros((n_pad, bucket), np.int32)
         true_lens = np.ones((n_pad,), np.int32)
@@ -1082,8 +886,10 @@ class ContinuousBatcher:
             # fresh sampling key per admission (distinct stream per request)
             self._rng_counter += 1
             key = jax.random.fold_in(self._base_rng, self._rng_counter)
-            if self.prefill_chunk and len(req.prompt) > self.prefill_chunk:
-                # long prompt → chunked prefill. One in flight at a time:
+            if self.prefill_chunk and (len(req.prompt) > self.prefill_chunk
+                                       or self._rings is not None):
+                # long prompt (or a family that prefills every prompt in
+                # chunks) → chunked prefill. One in flight at a time:
                 # it holds a slot from its first chunk, and serializing
                 # keeps prefill compute from flooding the decode stream.
                 if self._chunked is not None or not self._free:
@@ -1169,9 +975,7 @@ class ContinuousBatcher:
                 [len(r.prompt) for r, _ in group], dtype=jnp.int32)
             try:
                 # drop the scalar cursor — adopt() resets the row cursors itself
-                small = {nm: {"attention": {"k": l["attention"]["k"],
-                                            "v": l["attention"]["v"]}}
-                         for nm, l in small.items()}
+                small = self.family.kv_of(small)
                 first_n = first[:n]
                 adopt_args = (self.last_tok, self.temps, self.rngs, first_n,
                               jnp.asarray([r.temperature for r, _ in group],
@@ -1211,11 +1015,9 @@ class ContinuousBatcher:
                     dsmall, _ = self._prefill_group(
                         [r.prompt for r, _ in group],
                         [r.temperature for r, _ in group], keys, draft=True)
-                    dsmall = {nm: {"attention": {"k": l["attention"]["k"],
-                                                 "v": l["attention"]["v"]}}
-                              for nm, l in dsmall.items()}
                     self.draft_cache = self._draft_adopt_fn(
-                        self.draft_cache, dsmall, slots_arr, true_lens_arr)
+                        self.draft_cache, self.family.kv_of(dsmall),
+                        slots_arr, true_lens_arr)
             except Exception as e:
                 # Adopt failed AFTER the slots were popped: these requests
                 # are in neither _active nor the pending queue, so _shutdown
@@ -1313,7 +1115,7 @@ class ContinuousBatcher:
         _ev(req, "kv_handoff", bytes=len(blob))
 
     def _build_draft_full_prefill(self):
-        dmodel = self._draft_prefill_model
+        dmodel = self._draft_family.prefill_model
 
         @jax.jit
         def draft_full(params, cache, ids):
@@ -1326,7 +1128,7 @@ class ContinuousBatcher:
 
     # -- chunked prefill (ISSUE 12) ------------------------------------------
     def _build_chunk_prefill(self):
-        model = self._prefill_model
+        model = self.family.prefill_model
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def chunk_prefill(params, cache, ids, first_idx, temperature, key):
@@ -1363,19 +1165,22 @@ class ContinuousBatcher:
             except Exception as e:
                 _fail(req, e)
                 return True
-        cfg = self.cfg
-        kv = (1, cfg.max_seq, cfg.n_heads, cfg.head_dim)
-        cache = {
-            f"block_{i}": {"attention": {
-                "k": jnp.zeros(kv, cfg.dtype),
-                "v": jnp.zeros(kv, cfg.dtype),
-                "cursor": jnp.zeros((), jnp.int32),
-            }}
-            for i in range(cfg.n_layers)
-        }
         slot = self._free.pop()
-        self._chunked = _ChunkedPrefill(req=req, slot=slot, cache=cache,
-                                        key=key, res=res)
+        if self._rings is None:
+            cp = _ChunkedPrefill(req=req, slot=slot, key=key, res=res,
+                                 cache=self.family.prefill_cache(1))
+        else:
+            # no private cache: the chunks go into the arenas through a
+            # table of the row's own, handed to the engine's at activation
+            cp = _ChunkedPrefill(
+                req=req, slot=slot, key=key, res=res, cache=None,
+                table=np.full((self._max_blocks,), self._alloc.trash, np.int32),
+                stats=jnp.zeros((3,), jnp.int32))
+            # reservation at admission counts BOTH kinds: the full kind's
+            # ceil((prompt + budget) / block_t) above, and one ring of the
+            # window kind, which a request that has a slot always gets
+            self._rings.attach(slot, self._rings.reserve())
+        self._chunked = cp
         _ev(req, "chunked_prefill_start", slot=slot,
             chunks=-(-len(req.prompt) // self.prefill_chunk))
         return True
@@ -1390,6 +1195,8 @@ class ContinuousBatcher:
             self._ub_cursor[cp.slot] = 0
             if cp.res is not None:
                 self._alloc.release(cp.res)
+            if self._rings is not None:
+                self._rings.release(cp.slot)
         self._free.append(cp.slot)
         self._chunked = None
 
@@ -1418,6 +1225,8 @@ class ContinuousBatcher:
             _fail(req, DeadlineExceeded(
                 "deadline expired during chunked prefill"))
             return []
+        if self._rings is not None:
+            return self._advance_in_arena(cp)
         if self._chunk_prefill_fn is None:
             self._chunk_prefill_fn = self._build_chunk_prefill()
         n = len(req.prompt)
@@ -1453,9 +1262,7 @@ class ContinuousBatcher:
         # -- last chunk: adopt + activate -----------------------------------
         slot = cp.slot
         first_arr = first[None]
-        small = {nm: {"attention": {"k": l["attention"]["k"],
-                                    "v": l["attention"]["v"]}}
-                 for nm, l in cp.cache.items()}
+        small = self.family.kv_of(cp.cache)
         slots_arr = jnp.asarray([slot], jnp.int32)
         true_lens_arr = jnp.asarray([n], jnp.int32)
         adopt_args = (self.last_tok, self.temps, self.rngs, first_arr,
@@ -1479,29 +1286,26 @@ class ContinuousBatcher:
             # the draft adopts the full prompt in one forward (its whole
             # point is being small; chunking IT would serialize more
             # dispatches for no decode-lane benefit)
-            dcfg = self._draft_cfg
-            kv = (1, dcfg.max_seq, dcfg.n_heads, dcfg.head_dim)
-            dzero = {
-                f"block_{i}": {"attention": {
-                    "k": jnp.zeros(kv, dcfg.dtype),
-                    "v": jnp.zeros(kv, dcfg.dtype),
-                    "cursor": jnp.zeros((), jnp.int32),
-                }}
-                for i in range(dcfg.n_layers)
-            }
+            dzero = self._draft_family.prefill_cache(1)
             if self._draft_full_prefill_fn is None:
                 self._draft_full_prefill_fn = self._build_draft_full_prefill()
             dids = np.zeros((1, cp.pos), np.int32)
             dids[0, :n] = req.prompt
             dsmall = self._draft_full_prefill_fn(
                 self._draft_params, dzero, jnp.asarray(dids))
-            dsmall = {nm: {"attention": {"k": l["attention"]["k"],
-                                         "v": l["attention"]["v"]}}
-                      for nm, l in dsmall.items()}
             self.draft_cache = self._draft_adopt_fn(
-                self.draft_cache, dsmall, slots_arr, true_lens_arr)
+                self.draft_cache, self.family.kv_of(dsmall), slots_arr,
+                true_lens_arr)
+        return self._activate_chunked(req, slot, first_arr)
+
+    def _activate_chunked(self, req: _Request, slot: int, first: Any
+                          ) -> List[Tuple[str, Any, Any, float]]:
+        """A chunk-prefilled request joins the decode batch: the lane is
+        free again, and its first token (``first``: what the host fetches
+        for it) becomes the pipelined 'first' event."""
         try:
-            first_arr.copy_to_host_async()
+            for arr in jax.tree.leaves(first):
+                arr.copy_to_host_async()
         except Exception:
             pass
         now = time.perf_counter()
@@ -1514,7 +1318,64 @@ class ContinuousBatcher:
         _ev(req, "prefill_done")
         self._chunked = None
         self._set_occupancy()
-        return [("first", first_arr, [(req, slot)], now)]
+        return [("first", first, [(req, slot)], now)]
+
+    def _advance_in_arena(self, cp: _ChunkedPrefill
+                          ) -> List[Tuple[str, Any, Any, float]]:
+        """One chunk of a prompt whose family prefills straight into the
+        arenas (no private cache, no adopt): grant the chunk its blocks of
+        both kinds, dispatch the family's chunk program — ONE program a
+        chunk shape and view width, whatever the prompt's length — and,
+        after the last chunk, hand the row's table to the engine's and
+        activate the slot. The chunk reads the window ring as the previous
+        chunk left it and writes only the blocks the next reader (the next
+        chunk, or decode at the prompt's end) can still see; the ring's
+        older blocks go back to the free list before the new ones are
+        granted (table entry to trash first, as at a retire)."""
+        req, slot = cp.req, cp.slot
+        if self._chunk_prefill_fn is None:
+            self._chunk_prefill_fn = self.family.build_chunk_prefill()
+        n, c, bt = len(req.prompt), self.prefill_chunk, self.kv_block_t
+        start = cp.pos
+        end = min(start + c, n)
+        ids = np.zeros((c,), np.int32)
+        ids[:end - start] = req.prompt[start:end]
+        base = len(cp.res.granted)
+        for off, blk in enumerate(
+                self._alloc.grant(cp.res, self._alloc.blocks_for(end))):
+            cp.table[base + off] = blk
+        first_block = start // bt
+        held = self._alloc.blocks_for(end)
+        write_full = np.full((c // bt,), self._alloc.trash, np.int32)
+        write_full[:held - first_block] = cp.table[first_block:held]
+        view = next(w for w in self._view_widths if w >= held)
+        rings = self._rings
+        read_window = rings.row(slot).copy()
+        rings.advance(slot, end, end)
+        write_window = np.asarray(
+            [rings.block_of(slot, first_block + j) for j in range(c // bt)],
+            np.int32)
+        self.cache, first, stats = self._chunk_prefill_fn(
+            self.params, self.cache, jnp.asarray(ids),
+            jnp.asarray(start, jnp.int32), jnp.asarray(end - start, jnp.int32),
+            jnp.asarray(req.temperature, jnp.float32), cp.key,
+            jnp.asarray(cp.table[:view]), jnp.asarray(write_full),
+            jnp.asarray(read_window), jnp.asarray(write_window))
+        cp.stats = cp.stats + stats
+        cp.pos = start + c
+        METRICS.counter("serving_prefill_chunks_total").inc()
+        _ev(req, "prefill_chunk", start=start)
+        if end < n:
+            return []
+        self._tables[slot, :] = cp.table
+        self._slot_res[slot] = cp.res
+        self._ub_cursor[slot] = n
+        self.cache, self.last_tok, self.temps, self.rngs = self._activate_fn(
+            self.cache, self.last_tok, self.temps, self.rngs,
+            jnp.asarray(slot, jnp.int32), jnp.asarray(n, jnp.int32), first,
+            jnp.asarray(req.temperature, jnp.float32),
+            jax.random.fold_in(cp.key, 1))
+        return self._activate_chunked(req, slot, (first[None], cp.stats))
 
     # -- KV handoff: decode-role import (ISSUE 18) ---------------------------
     def _admit_imports(self) -> List[Tuple[str, Any, Any, float]]:
@@ -1599,16 +1460,7 @@ class ContinuousBatcher:
                     # the wire carries no draft KV: the draft re-prefills
                     # the prompt locally in one forward (it is small by
                     # construction — that is the draft's whole point)
-                    dcfg = self._draft_cfg
-                    kv = (1, dcfg.max_seq, dcfg.n_heads, dcfg.head_dim)
-                    dzero = {
-                        f"block_{i}": {"attention": {
-                            "k": jnp.zeros(kv, dcfg.dtype),
-                            "v": jnp.zeros(kv, dcfg.dtype),
-                            "cursor": jnp.zeros((), jnp.int32),
-                        }}
-                        for i in range(dcfg.n_layers)
-                    }
+                    dzero = self._draft_family.prefill_cache(1)
                     if self._draft_full_prefill_fn is None:
                         self._draft_full_prefill_fn = \
                             self._build_draft_full_prefill()
@@ -1617,12 +1469,8 @@ class ContinuousBatcher:
                     dids[0, :n] = req.prompt
                     dsmall = self._draft_full_prefill_fn(
                         self._draft_params, dzero, jnp.asarray(dids))
-                    dsmall = {nm: {"attention": {
-                        "k": l["attention"]["k"],
-                        "v": l["attention"]["v"]}}
-                        for nm, l in dsmall.items()}
                     self.draft_cache = self._draft_adopt_fn(
-                        self.draft_cache, dsmall,
+                        self.draft_cache, self.family.kv_of(dsmall),
                         jnp.asarray([slot], jnp.int32),
                         jnp.asarray([n], jnp.int32))
             except Exception as e:
@@ -1662,8 +1510,11 @@ class ContinuousBatcher:
             res = self._slot_res.get(slot)
             if res is None:
                 continue
-            ub = min(int(self._ub_cursor[slot]) + tokens, max_seq)
+            cursor = int(self._ub_cursor[slot])
+            ub = min(cursor + tokens, max_seq)
             self._ub_cursor[slot] = ub
+            if self._rings is not None:
+                self._rings.advance(slot, cursor, ub)
             base = len(res.granted)
             for off, blk in enumerate(
                     self._alloc.grant(res, self._alloc.blocks_for(ub))):
@@ -1680,10 +1531,25 @@ class ContinuousBatcher:
                 self.params, self._draft_params, self.cache, self.draft_cache,
                 self.last_tok, self.temps, self.rngs, *tables)
             return "spec", (toks, acc)
-        self.cache, self.last_tok, self.rngs, toks = self._step_fn(
+        self.cache, self.last_tok, self.rngs, toks, *stats = self._step_fn(
             self.params, self.cache, self.last_tok, self.temps, self.rngs,
             *tables)
-        return "chunk", toks
+        return "chunk", (toks, *stats) if stats else toks
+
+    def _ring_tables(self, span) -> Tuple[Any, Any]:
+        """The window kind's share of a decode dispatch: every LIVE row's
+        ring (a row still prefilling keeps its ring to itself: a decode
+        step writes every row's token somewhere, and a dead row's must land
+        in trash) and which rows are live; the blocks in use by kind ride
+        on the dispatch's region."""
+        rings = self._rings
+        live = np.zeros((self.slots,), bool)
+        live[list(self._active)] = True
+        span.set_metadata(full_blocks=self._alloc.used(),
+                          window_blocks=rings.used(),
+                          window_blocks_unreleased=rings.unreleased())
+        return (jnp.asarray(np.where(live[:, None], rings.tables, rings.trash)),
+                jnp.asarray(live))
 
     def _warm_views(self) -> None:
         """Compile the decode program at every view width by running it
@@ -1691,9 +1557,13 @@ class ContinuousBatcher:
         (its writes go to the trash block, its tokens to nobody), and the
         cursors and sampling state it moves are set anew by the adopt that
         admits a request. Not for a turn with an active slot."""
+        rings = ()
+        if self._rings is not None:
+            rings = (jnp.full((self.slots, self._rings.cols), self._rings.trash,
+                              jnp.int32), jnp.zeros((self.slots,), bool))
         for width in self._view_widths:
             self._run_decode((jnp.full((self.slots, width), self._alloc.trash,
-                                       jnp.int32),))
+                                       jnp.int32),) + rings)
 
     def _set_occupancy(self) -> None:
         active = len(self._active)
@@ -1717,6 +1587,8 @@ class ContinuousBatcher:
             res = self._slot_res.pop(slot, None)
             if res is not None:
                 self._alloc.release(res)
+            if self._rings is not None:
+                self._rings.release(slot)
             self._ub_cursor[slot] = 0
         req.done_at = time.perf_counter()
         if req.finish_reason is None:
@@ -1865,7 +1737,10 @@ class ContinuousBatcher:
         a row whose request finished in an earlier event is a discarded
         tail; a row adopted after the dispatch is not in the snapshot."""
         kind, dev, meta, dispatched_at = event
-        widths = None
+        widths = stats = None
+        if self.family.has_stats:
+            # a family with expert layers: their counters ride with the tokens
+            dev, stats = dev
         with profiling.annotate("serving.engine.fetch", kind=kind):
             if kind == "spec":
                 # one speculative round: [slots, spec_k] candidate tokens
@@ -1878,6 +1753,8 @@ class ContinuousBatcher:
             else:
                 # host fetch (async copy started at dispatch)
                 block = np.asarray(dev)
+                if stats is not None:
+                    stats = np.asarray(stats)
         now = time.perf_counter()
         with profiling.annotate("serving.engine.deliver", kind=kind,
                                 rows=int(block.size)) as span:
@@ -1893,6 +1770,22 @@ class ContinuousBatcher:
                 tokens, retired = self._deliver_block(
                     meta, block, widths, now)
             span.set_metadata(tokens=tokens, retired=retired)
+            if stats is not None:
+                # assignments of the event's live tokens (a decode chunk's
+                # rows, or the prompt a first token closes) that landed on
+                # the experts held here, the busiest held expert's, and how
+                # many held experts saw a token (summed over layers, steps)
+                on_held, busiest, touched = (int(v) for v in stats)
+                routed = self.family.routed(
+                    sum(len(r.prompt) for r, _ in meta) if kind == "first"
+                    else len(meta) * block.shape[1])
+                span.set_metadata(expert_tokens=on_held,
+                                  expert_tokens_max=busiest,
+                                  experts_touched=touched)
+                METRICS.counter("serving_moe_assignments_total",
+                                held="true").inc(on_held)
+                METRICS.counter("serving_moe_assignments_total",
+                                held="false").inc(max(routed - on_held, 0))
 
     def _deliver_first(self, pairs, block, now: float) -> Tuple[int, int]:
         """An admission group's first tokens to their requests. Returns
@@ -2074,8 +1967,14 @@ class ContinuousBatcher:
                 with profiling.annotate("serving.engine.import"):
                     events.extend(self._admit_imports())
                 dispatched = True
-            if self._free and self._pending and not self._draining:
-                wave = self._next_wave(len(self._free))
+            batched = self._rings is None
+            if (self._free and self._pending and not self._draining
+                    and (batched or self._chunked is None)):
+                # a family that prefills every prompt in the one chunked
+                # lane admits one request, and only when the lane is free:
+                # a wave of more would be keyed, turned back and queued
+                # again every turn
+                wave = self._next_wave(len(self._free) if batched else 1)
                 self._set_queue_gauge()
                 events.extend(self._admit_wave(wave))
                 dispatched = True
@@ -2106,6 +2005,8 @@ class ContinuousBatcher:
                                           max_blocks=self._max_blocks)
                         METRICS.gauge("serving_decode_view_blocks",
                                       replica=self.engine_id).set(view)
+                        if self._rings is not None:
+                            tables += self._ring_tables(span)
                     kind, out = self._run_decode(tables)
                     try:
                         for arr in jax.tree.leaves(out):

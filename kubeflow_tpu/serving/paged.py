@@ -35,7 +35,7 @@ trash, never into a re-granted block).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import List, Tuple
+from typing import Dict, List, Tuple
 
 import numpy as np
 
@@ -88,13 +88,18 @@ class KVBlockAllocator:
     :attr:`trash` exposes its id for table initialization.
     """
 
-    def __init__(self, n_blocks: int, block_t: int, *, engine_id: str = "0"):
+    def __init__(self, n_blocks: int, block_t: int, *, engine_id: str = "0",
+                 kind: str = ""):
+        """``kind`` ("full" / "window") labels the gauges of an engine that
+        keeps two kinds of cache side by side; an engine with one kind
+        leaves it empty and publishes the gauges it always did."""
         if n_blocks <= 0:
             raise ValueError(f"need at least one KV block, got {n_blocks}")
         self.n_blocks = int(n_blocks)
         self.block_t = int(block_t)
         self.trash = self.n_blocks
         self.engine_id = engine_id
+        self._labels = {"replica": engine_id, **({"kind": kind} if kind else {})}
         self._free: List[int] = list(range(self.n_blocks))
         self._promised = 0  # reserved but not yet granted
         self._publish()
@@ -156,8 +161,101 @@ class KVBlockAllocator:
         res.total = 0
         self._publish()
 
+    def give_back(self, res: KVReservation, block: int) -> None:
+        """Return ONE granted block to the free list while its reservation
+        lives on (a window layer's block that the cursor has left behind):
+        the block stays promised to the reservation, which may be granted
+        another. The same ordering holds as for :meth:`release`: the table
+        entry goes to trash first."""
+        res.granted.remove(block)
+        self._free.append(block)
+        self._promised += 1
+        self._publish()
+
     def _publish(self) -> None:
-        METRICS.gauge("serving_kv_blocks_free",
-                      replica=self.engine_id).set(len(self._free))
-        METRICS.gauge("serving_kv_blocks_used",
-                      replica=self.engine_id).set(self.used())
+        METRICS.gauge("serving_kv_blocks_free", **self._labels).set(len(self._free))
+        METRICS.gauge("serving_kv_blocks_used", **self._labels).set(self.used())
+
+
+class WindowRings:
+    """The window kind of cache: every slot keeps a RING of ``cols`` blocks
+    in each window layer, logical block ``b`` (positions ``[b * block_t,
+    (b + 1) * block_t)``) in column ``b % cols`` of its row of
+    :attr:`tables`, and holds only the blocks a dispatch can still read or
+    write: those from ``(cursor - window + 1) // block_t`` up to the
+    dispatch's frontier. A block the cursor has left behind by a window
+    goes back to the free list (its table entry to trash first), so a long
+    row costs ``cols`` blocks where the full kind costs a block per
+    ``block_t`` positions.
+
+    ``cols = ceil((window + lookahead - 1) / block_t) + 1``: a dispatch
+    that advances a cursor by up to ``lookahead`` positions reads back to
+    ``cursor - window + 1`` and writes up to ``cursor + lookahead - 1``.
+    With one step a dispatch that is ``ceil(window / block_t) + 1``.
+    """
+
+    def __init__(self, slots: int, window: int, block_t: int, lookahead: int,
+                 *, engine_id: str = "0"):
+        self.window, self.block_t = int(window), int(block_t)
+        self.cols = -(-(self.window + max(int(lookahead), 1) - 1) // self.block_t) + 1
+        # a whole ring for every slot: a slot can never hold more
+        self.alloc = KVBlockAllocator(slots * self.cols, block_t,
+                                      engine_id=engine_id, kind="window")
+        self.trash = self.alloc.trash
+        self.tables = np.full((slots, self.cols), self.trash, np.int32)
+        self._res: Dict[int, KVReservation] = {}
+        self._held: Dict[int, Dict[int, int]] = {}     # slot -> logical block -> id
+        self._frontier = np.zeros((slots,), np.int64)  # positions a full kind would hold
+
+    def reserve(self) -> KVReservation:
+        """A slot's ring, promised at admission. The arena holds one for
+        every slot, so a request that has a slot has its ring."""
+        return self.alloc.reserve(self.cols)
+
+    def attach(self, slot: int, res: KVReservation) -> None:
+        self._res[slot] = res
+        self._held[slot] = {}
+        self._frontier[slot] = 0
+
+    def row(self, slot: int) -> np.ndarray:
+        return self.tables[slot]
+
+    def advance(self, slot: int, cursor: int, frontier: int) -> List[int]:
+        """Before a dispatch that starts with the slot's cursor at
+        ``cursor`` and may write positions below ``frontier``: give back
+        the blocks wholly behind ``cursor - window + 1`` and grant those up
+        to the frontier. Returns the ids of the logical blocks
+        ``[first kept .. last]`` now held, oldest first."""
+        res, held = self._res[slot], self._held[slot]
+        first = max(0, cursor - self.window + 1) // self.block_t
+        last = (frontier - 1) // self.block_t
+        for b in [b for b in held if b < first]:
+            self.tables[slot, b % self.cols] = self.trash       # table first
+            self.alloc.give_back(res, held.pop(b))
+        for b in range(max(first, last - self.cols + 1), last + 1):
+            if b not in held:
+                (blk,) = self.alloc.grant(res, len(res.granted) + 1)
+                held[b] = blk
+                self.tables[slot, b % self.cols] = blk
+        self._frontier[slot] = max(self._frontier[slot], frontier)
+        return [held[b] for b in sorted(held)]
+
+    def block_of(self, slot: int, logical: int) -> int:
+        return self._held[slot].get(logical, self.trash)
+
+    def release(self, slot: int) -> None:
+        """Retire: the row goes to trash, then the blocks return."""
+        self.tables[slot, :] = self.trash
+        res = self._res.pop(slot, None)
+        self._held.pop(slot, None)
+        self._frontier[slot] = 0
+        if res is not None:
+            self.alloc.release(res)
+
+    def used(self) -> int:
+        return self.alloc.used()
+
+    def unreleased(self) -> int:
+        """Blocks the window kind would hold had nothing been given back:
+        one per ``block_t`` positions of every attached row's frontier."""
+        return int(sum(-(-int(self._frontier[s]) // self.block_t) for s in self._res))

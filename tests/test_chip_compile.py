@@ -16,6 +16,7 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from kubeflow_tpu.ops.chunk_attention import chunk_attention
 from kubeflow_tpu.ops.flash_attention import flash_attention
 from kubeflow_tpu.ops.fused_bottleneck import fused_bottleneck, fused_transition
 from kubeflow_tpu.ops.kv_cache import (
@@ -109,3 +110,21 @@ def test_fused_transition_resnet50_heads(chip, hw, cin, cmid, cout, stride):
         chip,
         lambda *a: fused_transition(*a, stride=stride, interpret=False),
         *shapes) == 1
+
+
+# A MiMo-V2 prefill chunk of 1,024 positions at the published widths (qk
+# 192, v 128, 64 query heads): a full layer's 4 KV heads x 16 query heads as
+# rows against an 8,192-position view, and a window layer's 8 x 8 against a
+# ring of 160 positions and the chunk itself, with its sinks.
+@pytest.mark.parametrize("kv,group,keys,window", [(4, 16, 8192, None), (8, 8, 160 + 1024, 128)],
+                         ids=["full", "window"])
+def test_chunk_attention_mimo_prefill_shape(chip, kv, group, keys, window):
+    rows = 1024 * group
+
+    def attend(q, k, v, q_pos, k_pos, sink):
+        return chunk_attention(q, k, v, q_pos, k_pos, scale=192 ** -0.5, window=window,
+                               sink=sink if window else None, interpret=False)
+
+    shapes = [((kv, rows, 192), BF16), ((kv, keys, 192), BF16), ((kv, keys, 128), BF16),
+              ((rows,), I32), ((keys,), I32), ((kv, rows), F32)]
+    assert _compile(chip, attend, *shapes) == 1

@@ -173,6 +173,11 @@ F32_MATMUL_ALLOWLIST = {
     ("gpt.py", "GptLM.__call__"),                  # f32 logits head
     ("gpt.py", "causal_lm_loss"),
     ("gpt.py", "blockwise_causal_lm_loss"),
+    # bf16 operands, float32 ACCUMULATION (preferred_element_type): the
+    # decode softmax island and the float32 logits head of the MiMo family
+    ("mimo.py", "_decode_attention"),
+    ("mimo.py", "decode_step"),
+    ("mimo.py", "prefill_chunk"),
 }
 
 _MATMUL_CALLEES = {"einsum", "matmul", "dot", "tensordot", "dot_general"}
