@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.chunk_attention import NOWHERE, chunk_attention
+from ..ops.paged_attention import paged_decode_attention
 from ..parallel.moe import held_experts_ffn, sigmoid_top_k
 
 FULL, WINDOW = 0, 1
@@ -131,9 +132,10 @@ def fresh_cache(cfg: MimoConfig, slots: int, blocks: Dict[int, int], block_t: in
     trash block) and one cursor a slot, shared by every layer. An arena is
     ``[blocks + 1, block_t, kv_heads * width]``: a position's KV heads lie
     side by side in ONE row (768 wide for 4 heads of 192: whole lanes, no
-    padding of a 192-wide head), which is how a token is written and how a
-    gathered view is read; the heads are told apart in the matmul
-    (:func:`_heads_apart`), not by moving the view."""
+    padding of a 192-wide head), which is how a token is written, how the
+    decode kernel fetches a page and how a gathered view is read; the heads
+    are told apart in the matmul (:func:`_heads_apart`), not by moving
+    what was read."""
     cache: Dict[str, Any] = {"cursors": jnp.zeros((slots,), jnp.int32)}
     for i, kind in enumerate(cfg.layer_kinds):
         rows, kv = (blocks[kind] + 1, block_t), cfg.kv_heads(kind)
@@ -176,18 +178,14 @@ def _qkv(cfg: MimoConfig, layer: Dict[str, Any], kind: int, h: jax.Array,
             partial_rope(k, positions, theta, cfg.rotary_dim), v)
 
 
-def _softmax(scores: jax.Array, mask: jax.Array, sink) -> jax.Array:
-    """Float32 softmax over the last axis of the masked scores; with a
-    ``sink`` (broadcastable to the scores without their last axis) its
-    exponential joins the denominator and takes no value."""
+def _softmax(scores: jax.Array, mask: jax.Array, sink: jax.Array) -> jax.Array:
+    """Float32 softmax over the last axis of the masked scores; the
+    exponential of ``sink`` (broadcastable to the scores without their last
+    axis) joins the denominator and takes no value."""
     scores = jnp.where(mask, scores, -1e30)
-    top = jnp.max(scores, axis=-1, keepdims=True)
-    if sink is not None:
-        top = jnp.maximum(top, sink[..., None])
+    top = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), sink[..., None])
     e = jnp.where(mask, jnp.exp(scores - top), 0.0)
-    den = jnp.sum(e, axis=-1, keepdims=True)
-    if sink is not None:
-        den = den + jnp.exp(sink[..., None] - top)
+    den = jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink[..., None] - top)
     return e / jnp.maximum(den, 1e-30)
 
 
@@ -241,9 +239,12 @@ def _own_head(x: jax.Array, kv: int) -> jax.Array:
 
 
 def _decode_attention(cfg: MimoConfig, layer, kind: int, arena, h, cursors,
-                      table, trash: int):
+                      table, live, trash: int):
     """h [S, d] at positions ``cursors`` [S]. Writes this token's key and
-    value through ``table`` and attends over what the table shows."""
+    value through ``table`` and attends over what the row holds: a window
+    layer over its ring's view, a full layer over its own pages
+    (``ops.paged_attention``: a row that is not ``live`` reads nothing and
+    gets zeros)."""
     S = h.shape[0]
     kv = cfg.kv_heads(kind)
     bt = arena["k"].shape[1]
@@ -263,22 +264,22 @@ def _decode_attention(cfg: MimoConfig, layer, kind: int, arena, h, cursors,
         keys_arena = arena["k"].at[ids, off].set(k.reshape(S, -1))
         vals_arena = arena["v"].at[ids, off].set(v.reshape(S, -1))
     with jax.named_scope("attn_window" if kind == WINDOW else "attn_full"):
-        keys = keys_arena[table].reshape(S, width * bt, kv * cfg.qk_dim)
-        vals = vals_arena[table].reshape(S, width * bt, kv * cfg.v_dim)
+        qb = _heads_apart(q.reshape(S, kv, cfg.n_heads // kv, cfg.qk_dim), kv)
         if kind == WINDOW:
+            keys = keys_arena[table].reshape(S, width * bt, kv * cfg.qk_dim)
+            vals = vals_arena[table].reshape(S, width * bt, kv * cfg.v_dim)
             pos = ring_positions(block, width, bt)                # [S, T]
             mask = ((pos >= 0) & (pos <= cursors[:, None])
                     & (cursors[:, None] - pos < cfg.window))
-            sink = layer["sink"][None, :]
+            scores = jnp.einsum("shc,stc->sht", qb, keys,
+                                preferred_element_type=jnp.float32) * cfg.qk_dim ** -0.5
+            probs = _softmax(scores, mask[:, None, :], layer["sink"][None, :])
+            ctx = _own_head(jnp.einsum("sht,stc->shc", probs.astype(cfg.dtype), vals,
+                                       preferred_element_type=jnp.float32), kv)
         else:
-            mask = jnp.arange(width * bt)[None, :] <= cursors[:, None]
-            sink = None
-        qb = _heads_apart(q.reshape(S, kv, cfg.n_heads // kv, cfg.qk_dim), kv)
-        scores = jnp.einsum("shc,stc->sht", qb, keys,
-                            preferred_element_type=jnp.float32) * cfg.qk_dim ** -0.5
-        probs = _softmax(scores, mask[:, None, :], sink)
-        ctx = _own_head(jnp.einsum("sht,stc->shc", probs.astype(cfg.dtype), vals,
-                                   preferred_element_type=jnp.float32), kv)
+            ctx = paged_decode_attention(
+                qb, keys_arena, vals_arena, table, jnp.where(live, cursors + 1, 0),
+                scale=cfg.qk_dim ** -0.5, kv_heads=kv)
         ctx = (ctx * cfg.value_scale).astype(cfg.dtype)             # [S, heads, v]
     out = jnp.einsum("shd,hdm->sm", ctx, layer["wo"])
     return out, {"k": keys_arena, "v": vals_arena}
@@ -299,7 +300,8 @@ def decode_step(cfg: MimoConfig, params, cache, tok: jax.Array,
         table = window_table if kind == WINDOW else full_table
         a, out_cache[f"layer_{i}"] = _decode_attention(
             cfg, layer, kind, cache[f"layer_{i}"],
-            rms_norm(x, layer["norm_attn"], cfg.norm_eps), cursors, table, trash[kind])
+            rms_norm(x, layer["norm_attn"], cfg.norm_eps), cursors, table, live,
+            trash[kind])
         x = x + a
         f, st = _ffn(cfg, layer, rms_norm(x, layer["norm_ffn"], cfg.norm_eps), live)
         x = x + f

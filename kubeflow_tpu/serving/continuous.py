@@ -1536,18 +1536,23 @@ class ContinuousBatcher:
             *tables)
         return "chunk", (toks, *stats) if stats else toks
 
-    def _ring_tables(self, span) -> Tuple[Any, Any]:
+    def _ring_tables(self, span, view: int) -> Tuple[Any, Any]:
         """The window kind's share of a decode dispatch: every LIVE row's
         ring (a row still prefilling keeps its ring to itself: a decode
         step writes every row's token somewhere, and a dead row's must land
         in trash) and which rows are live; the blocks in use by kind ride
-        on the dispatch's region."""
+        on the dispatch's region, and the full-kind pages its last step's
+        attention fetches (``ops/paged_attention``: each live row its own
+        pages up to its cursor, within the ``view`` columns handed over)."""
         rings = self._rings
         live = np.zeros((self.slots,), bool)
         live[list(self._active)] = True
         span.set_metadata(full_blocks=self._alloc.used(),
                           window_blocks=rings.used(),
-                          window_blocks_unreleased=rings.unreleased())
+                          window_blocks_unreleased=rings.unreleased(),
+                          full_blocks_read=sum(
+                              min(self._alloc.blocks_for(self._ub_cursor[slot]), view)
+                              for slot in self._active))
         return (jnp.asarray(np.where(live[:, None], rings.tables, rings.trash)),
                 jnp.asarray(live))
 
@@ -2006,7 +2011,7 @@ class ContinuousBatcher:
                         METRICS.gauge("serving_decode_view_blocks",
                                       replica=self.engine_id).set(view)
                         if self._rings is not None:
-                            tables += self._ring_tables(span)
+                            tables += self._ring_tables(span, view)
                     kind, out = self._run_decode(tables)
                     try:
                         for arr in jax.tree.leaves(out):

@@ -109,6 +109,34 @@ def manager():
     mgr.stop()
 
 
+class _Region:
+    """Stands in for ``profiling.annotate``'s region: keeps the stats."""
+
+    def __init__(self, log, name, stats):
+        self.log, self.name, self.stats = log, name, stats
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append((self.name, self.stats))
+
+    def set_metadata(self, **stats):
+        self.stats.update(stats)
+
+
+@pytest.fixture()
+def engine_regions(monkeypatch):
+    """The ``tpu.profiling.annotate`` regions the code under test closes,
+    with no profiler open: a list of ``(name, stats)`` in closing order."""
+    from kubeflow_tpu.tpu import profiling
+
+    log = []
+    monkeypatch.setattr(profiling, "annotate",
+                        lambda name, **stats: _Region(log, name, stats))
+    return log
+
+
 @pytest.fixture(autouse=True)
 def _reset_metrics():
     METRICS.reset()

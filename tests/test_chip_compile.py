@@ -21,6 +21,7 @@ from kubeflow_tpu.ops.flash_attention import flash_attention
 from kubeflow_tpu.ops.fused_bottleneck import fused_bottleneck, fused_transition
 from kubeflow_tpu.ops.kv_cache import (
     kv_block_update, kv_block_update_quant, kv_row_update)
+from kubeflow_tpu.ops.paged_attention import paged_decode_attention
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -128,3 +129,60 @@ def test_chunk_attention_mimo_prefill_shape(chip, kv, group, keys, window):
     shapes = [((kv, rows, 192), BF16), ((kv, keys, 192), BF16), ((kv, keys, 128), BF16),
               ((rows,), I32), ((keys,), I32), ((kv, rows), F32)]
     assert _compile(chip, attend, *shapes) == 1
+
+
+# The MiMo cell's decode step: 32 slots, 64 query heads handed over heads
+# apart (4 KV heads x 192), the full kind's arenas of 16,384 blocks of 16
+# and the trash block, the block table at each of the four view widths. A
+# pass says the kernel lowers and its buffers fit VMEM, its table SMEM.
+MIMO_ARENAS = [((16385, 16, 4 * 192), BF16), ((16385, 16, 4 * 128), BF16)]
+
+
+@pytest.mark.parametrize("columns", [128, 256, 384, 512])
+def test_paged_decode_attention_mimo_decode_shape(chip, columns):
+    def attend(q, keys, vals, table, lengths):
+        return paged_decode_attention(q, keys, vals, table, lengths, scale=192 ** -0.5,
+                                      kv_heads=4, interpret=False)
+
+    shapes = [((32, 64, 4 * 192), BF16)] + MIMO_ARENAS + [((32, columns), I32), ((32,), I32)]
+    assert _compile(chip, attend, *shapes) == 1
+
+
+def test_mimo_decode_program_copies_no_arena(chip, monkeypatch):
+    """The whole scanned ``step`` program of the cell (7 layers at the
+    published widths, 16 steps a dispatch, the widest view): both
+    full-attention layers hold the kernel, and nothing between the token's
+    scatter and the kernel moves an arena into another layout (an
+    arena-shaped ``copy`` cost 2.2 ms a step once: PERF.md section 6, PR 28)."""
+    import re
+
+    from kubeflow_tpu.models import mimo
+    from kubeflow_tpu.ops import paged_attention
+    from kubeflow_tpu.serving.family import MimoFamily
+
+    # the backend here is the CPU, where the kernel would run interpreted
+    monkeypatch.setattr(paged_attention, "_interpret_default", lambda: False)
+    cfg = mimo.MimoConfig()
+    family = MimoFamily(cfg, slots=32, kv_blocks=16384, kv_block_t=16)
+    rings = family.rings(16)
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+
+    params = described(jax.eval_shape(lambda: mimo.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = described(jax.eval_shape(family.fresh_cache))
+    rows = [((32,), I32), ((32,), F32), ((32, 2), jnp.uint32), ((32, 512), I32),
+            ((32, rings.cols), I32), ((32,), jnp.bool_)]
+    text = family.build_step(16).lower(
+        params, cache, *[jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+                         for shape, dtype in rows]).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and "paged_decode_attention" in line]
+    assert len(kernels) == 2
+    arena = r"bf16\[\d+,16,(768|512|1536|1024)\]"
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf"= {arena}\S* copy(-start)?\(", line)]
+    assert not copies, copies
+

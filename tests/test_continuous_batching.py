@@ -296,23 +296,7 @@ def test_paged_engine_bit_identical_to_contiguous(params):
     assert base == paged
 
 
-class _Region:
-    """Stands in for ``profiling.annotate``'s region: keeps the stats."""
-
-    def __init__(self, log, name, stats):
-        self.log, self.name, self.stats = log, name, stats
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        self.log.append((self.name, self.stats))
-
-    def set_metadata(self, **stats):
-        self.stats.update(stats)
-
-
-def test_decode_view_follows_the_longest_granted_row(params, monkeypatch):
+def test_decode_view_follows_the_longest_granted_row(params, engine_regions):
     """The paged dispatch hands the decode program the block table's first
     ``view_blocks`` columns only (max_seq 128, blocks of 16: widths 2, 4, 6,
     8). A 27-token prompt crosses position 32 in the middle of a 4-step
@@ -322,15 +306,13 @@ def test_decode_view_follows_the_longest_granted_row(params, monkeypatch):
     The tokens stay the contiguous engine's, ``serving.engine.dispatch``
     says how wide each dispatch read, and the gauge follows it."""
     from kubeflow_tpu.runtime.metrics import METRICS
-    from kubeflow_tpu.tpu import profiling
 
     jobs = [(prompt(1, 27), 14), (prompt(2, 5), 22), (prompt(3, 60), 10),
             (prompt(4, 6), 24)]
     kw = dict(slots=2, chunk=4, pipeline=1)
     base = _run_jobs(CFG, params, jobs, paged=False, **kw)
-    log = []
-    monkeypatch.setattr(profiling, "annotate",
-                        lambda name, **stats: _Region(log, name, stats))
+    log = engine_regions
+    log.clear()                    # the contiguous run's regions
     paged = _run_jobs(CFG, params, jobs, paged=True, engine_id="view", **kw)
     assert base == paged
     dispatch = [stats for name, stats in log if name == "serving.engine.dispatch"]

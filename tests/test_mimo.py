@@ -174,6 +174,26 @@ def test_decode_logits_agree_with_the_reference(params):
     assert np.abs(got - want).max() / want.std(-1).min() < 0.05, np.abs(got - want).max() / want.std(-1).min()
 
 
+@pytest.fixture(scope="module")
+def ragged_batch(params):
+    """Rows of 5, 37 and 70 positions and a dead slot, 16 steps a dispatch."""
+    eng = engine(params, chunk=16)
+    try:
+        futs = {n: eng.submit(prompt(n, n), 20) for n in (5, 37, 70)}
+        return {n: f.result(timeout=600) for n, f in futs.items()}
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("n", [5, 37, 70])
+def test_a_dispatch_of_16_steps_over_ragged_rows_agrees_with_the_reference(ragged_batch, n):
+    """The engine's decode program as the cell runs it: a scan of 16 steps
+    over rows of different lengths in one batch, each full-attention row
+    reading its own pages (``ops/paged_attention``)."""
+    out = ragged_batch[n]
+    assert len(out) == 20 and served_gaps(prompt(n, n), out).max() <= GAP_LIMIT_SD
+
+
 def test_staggered_arrivals_match_one_at_a_time_while_blocks_come_and_go(params):
     """Greedy tokens of requests that join a running batch at different
     times equal the tokens of the same requests served alone, while the
@@ -396,6 +416,27 @@ def test_the_engine_reports_blocks_by_kind(params):
         assert METRICS.value("serving_moe_assignments_total", held="true") > 0
     finally:
         eng.close()
+
+
+def test_a_dispatch_says_which_full_kind_pages_its_live_rows_read(params, engine_regions):
+    """``full_blocks_read`` on ``serving.engine.dispatch``: the pages the
+    live rows hold at the dispatch's last step, a row its own and a dead
+    slot none. One row of 30 positions, blocks of 4, 4 steps a dispatch:
+    34 positions are 9 pages, and every dispatch needs one more, of a
+    table of 3 slots x 32 columns."""
+    eng = engine(params, slots=3)
+    try:
+        eng.submit(prompt(1, 30), 12).result(timeout=600)
+    finally:
+        eng.close()
+    dispatch = [stats for name, stats in engine_regions
+                if name == "serving.engine.dispatch"]
+    read = [d["full_blocks_read"] for d in dispatch]
+    assert read[:3] == [9, 10, 11] and all(r <= d["view_blocks"] for r, d in zip(read, dispatch))
+    # one live row and nothing prefilling: all the full kind holds (a
+    # dispatch in flight past the row's budget reads on, into trash)
+    assert [d["full_blocks"] for d in dispatch[:3]] == read[:3]
+    assert all(d["max_blocks"] == 32 and d["rows"] == 3 * 4 for d in dispatch)
 
 
 # -- the other family's device programs did not move ---------------------------------
