@@ -72,17 +72,6 @@ class GptConfig:
         return cls(d_model=1024, n_layers=24, n_heads=16, d_ff=4096)  # ~GPT-2 medium
 
 
-def _kv_kernel_enabled() -> bool:
-    """``KUBEFLOW_TPU_KV_KERNEL=1`` routes per-slot KV writes through the
-    Pallas row-update kernel (ops/kv_cache.py); default is the whole-cache
-    where-select. The kernel touches 44x less cache per write; whether
-    that shows in a decode step on today's chip is not measured
-    (e2e/kv_update_probe.py is the probe, ROADMAP C3 the decision)."""
-    import os
-
-    return os.environ.get("KUBEFLOW_TPU_KV_KERNEL", "0") == "1"
-
-
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotary embedding. x: [b, L, heads, head_dim]; positions: [L] (shared
     across the batch) or [b, L] (per-row — continuous batching, where each
@@ -103,19 +92,7 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 def causal_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
-    import os
-
-    # Experiment knobs for full-step tiling sweeps (BASELINE methodology:
-    # only the full-step bench decides — isolated probes mispredicted three
-    # times in round 4). Unset = the kernel's measured auto-tiling.
-    bq = int(os.environ.get("GPT_FLASH_BLOCK_Q", "0")) or None
-    bk = int(os.environ.get("GPT_FLASH_BLOCK_K", "0")) or None
-    if os.environ.get("GPT_ATTN_BYPASS") == "1":
-        # Diagnostic only: attention out = v isolates the NON-attention
-        # step cost (all of which is per-token, so a bypassed step must
-        # time identically across seq lengths at equal token count).
-        return v
-    return flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+    return flash_attention(q, k, v, causal=True)
 
 
 class GptAttention(nn.Module):
@@ -123,11 +100,6 @@ class GptAttention(nn.Module):
     attention_fn: Callable = causal_flash_attention
     decode: bool = False
     per_slot: bool = False  # per-row cache cursors (continuous batching)
-    # kv_kernel: route per-slot decode KV writes through the Pallas
-    # row-update kernel (ops/kv_cache.py). None defers to the
-    # KUBEFLOW_TPU_KV_KERNEL env flag (deployment-wide default); True/False
-    # pin it per model instance so the fast path is testable in-process.
-    kv_kernel: Optional[bool] = None
     # paged: per-slot decode against a shared block arena + per-call block
     # tables instead of a contiguous [b, max_seq] cache (ISSUE 12). The
     # cache collection holds "k_arena"/"v_arena" [kv_blocks, kv_block_t,
@@ -207,31 +179,16 @@ class GptAttention(nn.Module):
             q = rope(dense(name="query")(x), seg_positions, cfg.rope_theta)
             k = rope(dense(name="key")(x), seg_positions, cfg.rope_theta)
             v = dense(name="value")(x)
-            use_kernel = (
-                _kv_kernel_enabled() if self.kv_kernel is None else self.kv_kernel
-            )
             with jax.named_scope("kv_write"):
                 if seg_len == 1:
-                    if use_kernel:
-                        # Pallas row-update kernel: touches ONE [1,8,h,d]
-                        # tile per row instead of a full-cache pass per
-                        # layer (ops/kv_cache.py; the where-select below
-                        # reads+writes the whole [b,max,h,d] cache every
-                        # layer — round-4's measured 8.2 vs 3.3 ms/step gap)
-                        from ..ops.kv_cache import kv_row_update
-
-                        keys = kv_row_update(cache_k.value, k[:, 0], start)
-                        values = kv_row_update(cache_v.value, v[:, 0], start)
-                    else:
-                        # broadcast-select instead of vmapped
-                        # dynamic_update_slice: the vmap form lowers to a
-                        # scatter (measured ~3x slower per decode step); a
-                        # where over the cache fuses into one elementwise
-                        # pass
-                        at = (jnp.arange(cfg.max_seq)[None, :, None, None]
-                              == start[:, None, None, None])        # [b,max,1,1]
-                        keys = jnp.where(at, k, cache_k.value)
-                        values = jnp.where(at, v, cache_v.value)
+                    # broadcast-select instead of vmapped
+                    # dynamic_update_slice: the vmap form lowers to a
+                    # scatter (measured ~3x slower per decode step); a
+                    # where over the cache fuses into one elementwise pass
+                    at = (jnp.arange(cfg.max_seq)[None, :, None, None]
+                          == start[:, None, None, None])        # [b,max,1,1]
+                    keys = jnp.where(at, k, cache_k.value)
+                    values = jnp.where(at, v, cache_v.value)
                 else:
                     upd = jax.vmap(
                         lambda cache_row, seg, s: jax.lax.dynamic_update_slice(
@@ -296,8 +253,8 @@ class GptAttention(nn.Module):
 
         Same math as the per-slot branch of :meth:`_decode_attention`, with
         the [b, max_seq] cache replaced by an indirect view: the write goes
-        through the block table (Pallas ``kv_block_update`` or the XLA
-        scatter reference), and the read gathers ``arena[tables]`` back
+        through the block table (``ops.kv_cache.kv_block_update``, one XLA
+        scatter a token), and the read gathers ``arena[tables]`` back
         into a [b, max_blocks*block_t, h, d] view. When ``block_t`` divides
         ``max_seq`` (the engine enforces it) that view has exactly the
         contiguous cache's shape, so the masked softmax/einsum below is
@@ -333,41 +290,24 @@ class GptAttention(nn.Module):
         q = rope(dense(name="query")(x), seg_positions, cfg.rope_theta)
         k = rope(dense(name="key")(x), seg_positions, cfg.rope_theta)
         v = dense(name="value")(x)
-        use_kernel = (
-            _kv_kernel_enabled() if self.kv_kernel is None else self.kv_kernel
-        )
-        from ..ops.kv_cache import (kv_block_update, kv_block_update_quant,
-                                    kv_block_update_ref, quantize_kv)
+        from ..ops.kv_cache import kv_block_update, quantize_kv
 
         with jax.named_scope("kv_write"):
             if quant:
-                if seg_len == 1 and use_kernel:
-                    keys_arena, k_scales = kv_block_update_quant(
-                        cache_k.value, scale_k.value, k[:, 0], start,
-                        block_tables, max_seq=cfg.max_seq)
-                    vals_arena, v_scales = kv_block_update_quant(
-                        cache_v.value, scale_v.value, v[:, 0], start,
-                        block_tables, max_seq=cfg.max_seq)
-                else:
-                    kq, ks = quantize_kv(k)
-                    vq, vs = quantize_kv(v)
-                    keys_arena = kv_block_update_ref(
-                        cache_k.value, kq, start, block_tables, max_seq=cfg.max_seq)
-                    vals_arena = kv_block_update_ref(
-                        cache_v.value, vq, start, block_tables, max_seq=cfg.max_seq)
-                    k_scales = kv_block_update_ref(
-                        scale_k.value, ks, start, block_tables, max_seq=cfg.max_seq)
-                    v_scales = kv_block_update_ref(
-                        scale_v.value, vs, start, block_tables, max_seq=cfg.max_seq)
-            elif seg_len == 1 and use_kernel:
+                kq, ks = quantize_kv(k)
+                vq, vs = quantize_kv(v)
                 keys_arena = kv_block_update(
-                    cache_k.value, k[:, 0], start, block_tables, max_seq=cfg.max_seq)
+                    cache_k.value, kq, start, block_tables, max_seq=cfg.max_seq)
                 vals_arena = kv_block_update(
-                    cache_v.value, v[:, 0], start, block_tables, max_seq=cfg.max_seq)
+                    cache_v.value, vq, start, block_tables, max_seq=cfg.max_seq)
+                k_scales = kv_block_update(
+                    scale_k.value, ks, start, block_tables, max_seq=cfg.max_seq)
+                v_scales = kv_block_update(
+                    scale_v.value, vs, start, block_tables, max_seq=cfg.max_seq)
             else:
-                keys_arena = kv_block_update_ref(
+                keys_arena = kv_block_update(
                     cache_k.value, k, start, block_tables, max_seq=cfg.max_seq)
-                vals_arena = kv_block_update_ref(
+                vals_arena = kv_block_update(
                     cache_v.value, v, start, block_tables, max_seq=cfg.max_seq)
         if not self.is_initializing():
             cache_k.value = keys_arena
@@ -418,7 +358,6 @@ class GptBlock(nn.Module):
     mesh: Optional[Any] = None
     decode: bool = False
     per_slot: bool = False
-    kv_kernel: Optional[bool] = None
     paged: bool = False
     kv_blocks: int = 0
     kv_block_t: int = 16
@@ -430,8 +369,8 @@ class GptBlock(nn.Module):
         cfg = self.cfg
         ln = functools.partial(nn.LayerNorm, dtype=jnp.float32, param_dtype=jnp.float32)
         x = x + GptAttention(cfg, self.attention_fn, self.decode, self.per_slot,
-                             self.kv_kernel, self.paged, self.kv_blocks,
-                             self.kv_block_t, self.kv_dtype, name="attention")(
+                             self.paged, self.kv_blocks, self.kv_block_t,
+                             self.kv_dtype, name="attention")(
             ln(name="ln_attn")(x).astype(cfg.dtype), positions, block_tables
         )
         normed = ln(name="ln_mlp")(x).astype(cfg.dtype)
@@ -467,7 +406,6 @@ class GptLM(nn.Module):
     mesh: Optional[Any] = None
     decode: bool = False
     per_slot: bool = False
-    kv_kernel: Optional[bool] = None
     paged: bool = False
     kv_blocks: int = 0
     kv_block_t: int = 16
@@ -517,8 +455,8 @@ class GptLM(nn.Module):
                 block = nn.remat(GptBlock, static_argnums=())
             for i in range(cfg.n_layers):
                 x = block(cfg, self.attention_fn, self.mesh, self.decode,
-                          self.per_slot, self.kv_kernel, self.paged,
-                          self.kv_blocks, self.kv_block_t, self.kv_dtype,
+                          self.per_slot, self.paged, self.kv_blocks,
+                          self.kv_block_t, self.kv_dtype,
                           name=f"block_{i}")(x, positions, block_tables)
         x = nn.LayerNorm(dtype=jnp.float32, param_dtype=jnp.float32, name="ln_final")(x)
         if return_hidden:

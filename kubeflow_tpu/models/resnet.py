@@ -213,8 +213,8 @@ class ResNet(nn.Module):
     4x4/1 conv on 12 channels instead of 7x7/2 on 3 — the same receptive
     field (the 7x7 kernel zero-padded to 8x8 and regrouped onto the
     half-res grid), but with 4x the channels feeding the MXU. In isolation
-    (e2e/conv_experiments.py) the s2d form has measured several times the
-    3-channel 7x7's rate; in the full train step the win was ~1% (XLA
+    (a standalone conv probe, BASELINE.md) the s2d form has measured several
+    times the 3-channel 7x7's rate; in the full train step the win was ~1% (XLA
     treats the in-model stem better than the standalone probe suggests —
     BASELINE.md, older findings). Default stays "conv7x7":
     the s2d stem renames/reshapes conv_init in the param tree, which would

@@ -451,7 +451,7 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
-    bf16_dots: Optional[bool] = None,
+    bf16_dots: bool = False,
 ) -> jax.Array:
     """Fused attention. q: [b, lq, h, d]; k/v: [b, lk, h, d] -> [b, lq, h, d].
 
@@ -478,10 +478,6 @@ def flash_attention(
             f"({q.shape[1]}, {k.shape[1]}) have no TPU-tileable divisor — "
             "pad the sequence or pass explicit block_q/block_k"
         )
-    if bf16_dots is None:
-        import os
-
-        bf16_dots = os.environ.get("FLASH_BF16_DOTS") == "1"
     return _flash(
         q, k, v, causal, scale, int(q_offset), int(k_offset),
         bq, bk, interpret, bool(bf16_dots),

@@ -19,8 +19,7 @@ slot-based admission — vLLM-style scheduling expressed the TPU way:
   the next queued request takes the row — no drain barrier, no padding to
   the longest request,
 - chunk dispatches overlap (bounded ``pipeline`` depth) so the
-  dispatch+fetch round trip hides behind decode compute
-  (e2e/kv_update_probe.py for the cost model).
+  dispatch+fetch round trip hides behind decode compute.
 
 Throughput model: mixed arrivals with budgets b_i on S slots cost
 ~max-ish(sum b_i / S) steps here vs sum-of-group-max for the static
@@ -50,8 +49,7 @@ from ..tpu import profiling
 from .errors import (DeadlineExceeded, EngineClosed, FleetSaturated,
                      RequestCancelled)
 from .family import family_for
-from .paged import (KVBlockAllocator, KVReservation, WindowRings,
-                    view_blocks, view_widths)
+from .paged import ContiguousKV, KVReservation, SlotKV
 
 #: admission priority classes; batch is shed first under saturation
 PRIORITIES = ("interactive", "batch")
@@ -230,22 +228,19 @@ def _fail(req: _Request, error: BaseException) -> None:
 @dataclass(eq=False)
 class _ChunkedPrefill:
     """One long prompt mid-chunked-prefill (ISSUE 12): it owns a slot and
-    (paged) a KV reservation from the first chunk, prefills into a private
+    a KV reservation from the first chunk, prefills into a private
     [1, max_seq] scalar-cursor cache one fixed-size chunk per engine
     iteration — decode chunks keep dispatching in between, which is the
     whole point — and adopts into the shared cache when the last chunk
-    lands."""
+    lands. A family that prefills straight into the arenas has no private
+    cache (``cache`` None; the row's table while it fills is the KV
+    owner's) and carries its expert counters so far in ``stats``."""
     req: _Request
     slot: int
     cache: Any
     key: Any
+    res: KVReservation
     pos: int = 0                       # prompt tokens prefilled so far
-    res: Optional[KVReservation] = None
-    #: a family that prefills straight into the arenas: the row's block
-    #: table while it fills (the engine's own row stays on trash until the
-    #: request is activated, so decode dispatches in between write nothing
-    #: of this dead row into its blocks) and its expert counters so far
-    table: Optional[np.ndarray] = None
     stats: Any = None
 
 
@@ -281,20 +276,18 @@ class ContinuousBatcher:
 
     ``pipeline`` = chunk dispatches kept in flight. A dispatch+fetch
     round trip has a fixed cost beside the marginal decode compute per
-    token, and a deep dispatch queue degrades (e2e/kv_update_probe.py
-    prices both). So the engine keeps a bounded event
-    pipeline: chunks are dispatched asynchronously (token blocks fetched
-    via ``copy_to_host_async``), and retirement/admission decisions lag
-    ``pipeline`` chunks behind the dispatch frontier, hiding the round
-    trip behind compute. Lagged decisions are safe because inactive rows cost nothing
-    (the batch shape is fixed; a retired row's tail tokens are discarded
+    token, and a deep dispatch queue degrades. So the engine keeps a
+    bounded event pipeline: chunks are dispatched asynchronously (token
+    blocks fetched via ``copy_to_host_async``), and retirement/admission
+    decisions lag ``pipeline`` chunks behind the dispatch frontier, hiding
+    the round trip behind compute. Lagged decisions are safe because
+    inactive rows cost nothing (the batch shape is fixed; a retired row's tail tokens are discarded
     against the dispatch-time snapshot) and adoptions join the donated
     cache chain in dispatch order.
     """
 
     def __init__(self, cfg: Any, params: Any, slots: int = 8,
                  chunk: int = 16, pipeline: int = 3,
-                 kv_kernel: Optional[bool] = None,
                  engine_id: str = "0",
                  max_pending: int = 0,
                  interactive_reserve: float = 0.25,
@@ -309,11 +302,14 @@ class ContinuousBatcher:
                  model_id: str = "",
                  handoff_sink: Optional[Callable[["_Request", bytes], None]] = None):
         """``cfg`` names the model family by its type (``serving/family.py``):
-        the engine builds no model itself. A family with window-attention
-        layers keeps a second kind of paged cache beside the block table
-        (``paged.WindowRings``: a ring of blocks a slot, given back as the
-        cursor leaves them behind), sized from ``slots``, the window,
-        ``kv_block_t`` and ``chunk``: two kinds of cache add no knob.
+        the engine builds no model itself, and it keeps no account of a
+        slot's KV: ``self.kv`` (``paged.SlotKV``) owns the block table, the
+        reservations, the cursor bounds and the retire order. A family with
+        window-attention layers keeps a second kind of paged cache beside
+        the block table (``paged.WindowRings``: a ring of blocks a slot,
+        given back as the cursor leaves them behind), sized from ``slots``,
+        the window, ``kv_block_t`` and ``chunk``: two kinds of cache add no
+        knob.
 
         New ISSUE-12 knobs (defaults keep every pre-existing behavior):
 
@@ -409,55 +405,31 @@ class ContinuousBatcher:
         # template per prompt bucket; waves larger than this are chunked
         self._group_pad = min(slots, MAX_GROUP)
         # -- paged KV layout (ISSUE 12) ------------------------------------
-        self.paged = bool(paged)
-        n_blocks = 0
-        if self.paged:
+        if paged:
             self.kv_block_t = _block_tile(cfg.max_seq, kv_block_t)
-            self._max_blocks = cfg.max_seq // self.kv_block_t
             n_blocks = (int(kv_blocks) if kv_blocks
-                        else slots * self._max_blocks)
+                        else slots * (cfg.max_seq // self.kv_block_t))
         else:
-            self.kv_block_t = 0
-        # kv_kernel: per-slot KV-write strategy (None = the
-        # KUBEFLOW_TPU_KV_KERNEL env default; see models.gpt)
+            self.kv_block_t = n_blocks = 0
         self.family = family_for(
-            cfg, slots=slots, paged=self.paged, kv_blocks=n_blocks,
-            kv_block_t=self.kv_block_t, kv_kernel=kv_kernel,
-            kv_dtype=self.kv_dtype)
-        if self.paged:
-            self._alloc: Optional[KVBlockAllocator] = KVBlockAllocator(
-                n_blocks, self.kv_block_t, engine_id=self.engine_id,
-                kind="full" if self.family.window else "")
-            # ONE host-side block table shared by every layer (each
-            # dispatch snapshots it to device); entries default to the
-            # trash block so unallocated positions can never hit real data
-            self._tables = np.full((slots, self._max_blocks),
-                                   self._alloc.trash, np.int32)
-            # a decode dispatch hands the model only the table's first
-            # columns, up to the longest granted row (view_blocks): jit
-            # specialises the decode program on each of these widths
-            self._view_widths = view_widths(self._max_blocks)
-            self._slot_res: Dict[int, KVReservation] = {}
-            # upper bound on each slot's device cursor at the dispatch
-            # frontier — spec rounds advance the real cursor by a
-            # data-dependent amount, so granting tracks the bound
-            self._ub_cursor = np.zeros((slots,), np.int64)
-        else:
-            self._alloc = None
-        # the window kind of cache, for a family that has window layers: a
-        # dispatch moves a cursor by up to ``chunk`` positions. Where it is
-        # there, every prompt is prefilled in chunks straight into the
-        # arenas (no private cache, no adopt): the engine's ring paths and
-        # that prefill lane are one capability, asked of ``self._rings``.
-        self._rings: Optional[WindowRings] = (
-            self.family.rings(self.chunk, self.engine_id)
-            if self.family.window else None)
+            cfg, slots=slots, paged=bool(paged), kv_blocks=n_blocks,
+            kv_block_t=self.kv_block_t, kv_dtype=self.kv_dtype)
+        # the one owner of every slot's KV on the host. A family with window
+        # layers keeps the window kind of cache beside the block table (a
+        # dispatch moves a cursor by up to ``chunk`` positions) and prefills
+        # every prompt in chunks straight into the arenas (no private cache,
+        # no adopt): both are asked of ``self.family.window``.
+        self.kv = (SlotKV(slots, cfg.max_seq, self.kv_block_t, n_blocks,
+                          engine_id=self.engine_id,
+                          rings=(self.family.rings(self.chunk, self.engine_id)
+                                 if self.family.window else None))
+                   if paged else ContiguousKV())
         # every view width's decode program is compiled once, at the first
         # prewarm: "no" -> "asked" (prewarm) -> "done" (the engine thread,
-        # at a turn that finds no slot active). A prefill specialist ships
-        # its requests before they decode, a contiguous cache has one width.
-        self._view_warmup = ("no" if self.paged and self.role != "prefill"
-                             else "done")
+        # at a turn that finds no slot active; a contiguous cache has no
+        # width to warm). A prefill specialist ships its requests before
+        # they decode.
+        self._view_warmup = "no" if self.role != "prefill" else "done"
         # -- chunked prefill (ISSUE 12) ------------------------------------
         self.prefill_chunk = effective_prefill_chunk(
             prefill_chunk, cfg.max_seq, self.kv_block_t or 1)
@@ -477,9 +449,8 @@ class ContinuousBatcher:
             self._draft_params = draft_params
             # the draft stays contiguous: it is small by construction, so
             # the paged arena's memory win does not apply to it
-            self._draft_family = family_for(draft_cfg, slots=slots,
-                                            kv_kernel=False)
-        if self._rings is not None:
+            self._draft_family = family_for(draft_cfg, slots=slots)
+        if self.family.window:
             # a family that prefills into the arenas has no private cache
             # to adopt, to ship or to verify drafts against
             if self.spec_k or self.role != "unified":
@@ -518,10 +489,10 @@ class ContinuousBatcher:
         #: wire-format KV imports awaiting a slot (decode role, ISSUE 18)
         self._imports: "collections.deque[_Import]" = collections.deque()
         self._step_fn = self.family.build_step(self.chunk)
-        batched = self._rings is None
+        batched = not self.family.window
         self._adopt_fn = self.family.build_adopt() if batched else None
         self._import_fn = (self.family.build_import()
-                           if batched and self.paged else None)
+                           if batched and paged else None)
         self._spec_fn = self._build_spec_step() if self.spec_k else None
         self._draft_adopt_fn = (self._draft_family.build_draft_adopt()
                                 if self.spec_k else None)
@@ -558,7 +529,6 @@ class ContinuousBatcher:
         """
         model, draft_model = self.family.model, self._draft_family.model
         k = self.spec_k
-        paged = self.paged
         _rollback = self.family.rollback
 
         @functools.partial(jax.jit, donate_argnums=(2, 3, 4, 6))
@@ -578,7 +548,7 @@ class ContinuousBatcher:
                 draft_one, (dcache, tok), None, length=k)
             drafts = jnp.moveaxis(drafts, 0, 1)                  # [S, k]
             seg = jnp.concatenate([tok[:, None], drafts[:, :k - 1]], axis=1)
-            kwargs = {"block_tables": tables[0]} if paged else {}
+            kwargs = {"block_tables": tables[0]} if tables else {}
             logits, updated = model.apply(
                 {"params": params, "cache": cache}, seg,
                 mutable=["cache"], **kwargs)
@@ -685,14 +655,7 @@ class ContinuousBatcher:
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
         if len(prompt) + max_new_tokens > self.cfg.max_seq:
             raise ValueError("prompt + budget exceeds max_seq")
-        if self.paged:
-            need = self._alloc.blocks_for(len(prompt) + max_new_tokens)
-            if need > self._alloc.n_blocks:
-                # waiting can never help — fail fast instead of pending
-                # forever behind an arena that is too small by construction
-                raise ValueError(
-                    f"prompt + budget needs {need} KV blocks; the arena has "
-                    f"{self._alloc.n_blocks} (raise kv_blocks)")
+        self.kv.check(len(prompt) + max_new_tokens)
         req = _Request(prompt, max_new_tokens, eos_id=eos_id,
                        temperature=float(temperature),
                        deadline=deadline, priority=priority, on_done=on_done,
@@ -739,8 +702,9 @@ class ContinuousBatcher:
         submit→first-token path across both replicas."""
         if self.role == "prefill":
             raise ValueError("prefill-role engines cannot import KV")
-        if not self.paged:
-            raise ValueError("KV import requires the paged arena layout")
+        if self._import_fn is None:
+            raise ValueError("KV import requires the paged arena layout "
+                             "of a family that adopts prefilled KV")
         from .kv_wire import unpack_kv
 
         manifest, arrays = unpack_kv(blob)
@@ -758,11 +722,7 @@ class ContinuousBatcher:
                 f"{self.model_id!r}")
         if int(manifest.get("prompt_len", -1)) != len(req.prompt):
             raise ValueError("wire prompt_len disagrees with the request")
-        need = self._alloc.blocks_for(len(req.prompt) + req.max_new_tokens)
-        if need > self._alloc.n_blocks:
-            raise ValueError(
-                f"prompt + budget needs {need} KV blocks; the arena has "
-                f"{self._alloc.n_blocks} (raise kv_blocks)")
+        self.kv.check(len(req.prompt) + req.max_new_tokens)
         req.kv_blob = blob
         imp = _Import(req=req, manifest=manifest, arrays=arrays)
         with self._lock:
@@ -801,7 +761,7 @@ class ContinuousBatcher:
         exercising the (prompt-bucket, group-bucket) prefill, the exact-n
         adopt, and (for the largest wave) the chunked decode step, all
         through the production path. A paged engine's first prewarm also
-        runs the decode program once at every view width (``_warm_views``):
+        runs the decode program once at every view width (``SlotKV.warm_tables``):
         which width a dispatch takes depends on the longest live sequence,
         so no wave of dummies would meet them all. Compilations land in the
         persistent JAX cache when one is configured. ``timeout`` becomes each dummy
@@ -887,7 +847,7 @@ class ContinuousBatcher:
             self._rng_counter += 1
             key = jax.random.fold_in(self._base_rng, self._rng_counter)
             if self.prefill_chunk and (len(req.prompt) > self.prefill_chunk
-                                       or self._rings is not None):
+                                       or self.family.window):
                 # long prompt (or a family that prefills every prompt in
                 # chunks) → chunked prefill. One in flight at a time:
                 # it holds a slot from its first chunk, and serializing
@@ -928,28 +888,25 @@ class ContinuousBatcher:
                           trace_id=_trace_id(group[0][0]))
                 self._export_group(group, small, first)
                 continue
+            # reserve the worst case BEFORE spending prefill compute;
+            # exhaustion is back-pressure (the request stays pending and
+            # retries as retirements free blocks), not an error
             reserved: List[KVReservation] = []
-            if self.paged:
-                # reserve worst-case blocks BEFORE spending prefill compute;
-                # exhaustion is back-pressure (the request stays pending and
-                # retries as retirements free blocks), not an error
-                admit: List[Tuple[_Request, Any]] = []
-                for req, key in group:
-                    blocks = self._alloc.blocks_for(
-                        len(req.prompt) + req.max_new_tokens)
-                    try:
-                        res = self._alloc.reserve(blocks)
-                    except FleetSaturated:
-                        back.append(req)
-                        continue
-                    except Exception as e:
-                        _fail(req, e)
-                        continue
-                    admit.append((req, key))
-                    reserved.append(res)
-                group = admit
-                if not group:
+            admit: List[Tuple[_Request, Any]] = []
+            for req, key in group:
+                try:
+                    res = self.kv.reserve(len(req.prompt) + req.max_new_tokens)
+                except FleetSaturated:
+                    back.append(req)
                     continue
+                except Exception as e:
+                    _fail(req, e)
+                    continue
+                admit.append((req, key))
+                reserved.append(res)
+            group = admit
+            if not group:
+                continue
             try:
                 keys = jnp.stack([k for _, k in group])
                 t0 = time.perf_counter()
@@ -958,7 +915,7 @@ class ContinuousBatcher:
                     [r.temperature for r, _ in group], keys)
             except Exception as e:  # whole-group failure takes no slots
                 for res in reserved:
-                    self._alloc.release(res)
+                    self.kv.release(res)
                 for req, _ in group:
                     _fail(req, e)
                 continue
@@ -970,42 +927,25 @@ class ContinuousBatcher:
                       trace_id=_trace_id(group[0][0]))
             n = len(group)
             slots = [self._free.pop() for _ in range(n)]
+            lens = [len(r.prompt) for r, _ in group]
             slots_arr = jnp.asarray(slots, dtype=jnp.int32)
-            true_lens_arr = jnp.asarray(
-                [len(r.prompt) for r, _ in group], dtype=jnp.int32)
+            true_lens_arr = jnp.asarray(lens, dtype=jnp.int32)
+            # as wide as the bucket the prefill padded the prompts to
+            block_ids = self.kv.bind(slots, reserved, lens,
+                                     _bucket_for(max(lens)))
             try:
                 # drop the scalar cursor — adopt() resets the row cursors itself
                 small = self.family.kv_of(small)
                 first_n = first[:n]
-                adopt_args = (self.last_tok, self.temps, self.rngs, first_n,
-                              jnp.asarray([r.temperature for r, _ in group],
-                                          dtype=jnp.float32),
-                              jnp.stack([jax.random.fold_in(k, 1)
-                                         for _, k in group]))
-                if self.paged:
-                    # grant each row the blocks its PROMPT needs (decode
-                    # grants the rest as cursors advance) and point its
-                    # table at them — BEFORE the adopt dispatch snapshots
-                    # the block ids
-                    bucket = _bucket_for(max(len(r.prompt) for r, _ in group))
-                    nb = bucket // self.kv_block_t
-                    block_ids = np.full((n, nb), self._alloc.trash, np.int32)
-                    for i, ((req, _), slot, res) in enumerate(
-                            zip(group, slots, reserved)):
-                        self._alloc.grant(
-                            res, self._alloc.blocks_for(len(req.prompt)))
-                        block_ids[i, :len(res.granted)] = res.granted
-                        self._tables[slot, :len(res.granted)] = res.granted
-                        self._slot_res[slot] = res
-                        self._ub_cursor[slot] = len(req.prompt)
-                    self.cache, self.last_tok, self.temps, self.rngs = \
-                        self._adopt_fn(self.cache, small,
-                                       jnp.asarray(block_ids), slots_arr,
-                                       true_lens_arr, *adopt_args)
-                else:
-                    self.cache, self.last_tok, self.temps, self.rngs = \
-                        self._adopt_fn(self.cache, small, slots_arr,
-                                       true_lens_arr, *adopt_args)
+                self.cache, self.last_tok, self.temps, self.rngs = \
+                    self._adopt_fn(
+                        self.cache, small, *map(jnp.asarray, block_ids),
+                        slots_arr, true_lens_arr,
+                        self.last_tok, self.temps, self.rngs, first_n,
+                        jnp.asarray([r.temperature for r, _ in group],
+                                    dtype=jnp.float32),
+                        jnp.stack([jax.random.fold_in(k, 1)
+                                   for _, k in group]))
                 if self.spec_k:
                     # the draft must adopt the same prompts before any spec
                     # round includes these rows; a failure here is engine
@@ -1024,12 +964,8 @@ class ContinuousBatcher:
                 # could never fail them — callers would block until their
                 # result() timeout. Restore the slots and fail the group now.
                 self._free.extend(slots)
-                if self.paged:
-                    for slot, res in zip(slots, reserved):
-                        self._tables[slot, :] = self._alloc.trash
-                        self._slot_res.pop(slot, None)
-                        self._ub_cursor[slot] = 0
-                        self._alloc.release(res)
+                for slot in slots:
+                    self.kv.release(slot)
                 for req, _ in group:
                     _fail(req, e)
                 continue
@@ -1041,14 +977,7 @@ class ContinuousBatcher:
             # next chunk dispatch must include these rows in its snapshot
             now = time.perf_counter()
             for (req, _), slot in zip(group, slots):
-                self._active[slot] = req
-                if req.submit_at is not None:
-                    METRICS.histogram(
-                        "serving_queue_wait_seconds",
-                        buckets=QUEUE_WAIT_BUCKETS,
-                    ).observe(now - req.submit_at, trace_id=_trace_id(req))
-                _ev(req, "admitted", slot=slot)
-                _ev(req, "prefill_done")
+                self._join(req, slot, now)
             events.append(("first", first_n,
                            [(req, slot) for (req, _), slot in zip(group, slots)],
                            now))
@@ -1147,56 +1076,38 @@ class ContinuousBatcher:
         return chunk_prefill
 
     def _start_chunked(self, req: _Request, key) -> bool:
-        """Claim a slot (and, paged, the worst-case block reservation) for
-        one long prompt and install it as THE in-flight chunked prefill —
-        the actual chunk dispatches happen one per engine iteration from
+        """Claim a slot and the worst-case KV reservation for one long
+        prompt and install it as THE in-flight chunked prefill — the actual
+        chunk dispatches happen one per engine iteration from
         :meth:`_advance_chunked` so decode keeps ticking in between.
         Returns False when the arena cannot reserve yet (caller requeues);
         a structurally impossible request fails and returns True."""
-        res = None
-        # a prefill specialist never decodes: no arena reservation — the
+        # a prefill specialist never decodes: it reserves nothing — the
         # decode replica that imports the wire blob reserves there
-        if self.paged and self.role != "prefill":
-            blocks = self._alloc.blocks_for(len(req.prompt) + req.max_new_tokens)
-            try:
-                res = self._alloc.reserve(blocks)
-            except FleetSaturated:
-                return False
-            except Exception as e:
-                _fail(req, e)
-                return True
+        tokens = (0 if self.role == "prefill"
+                  else len(req.prompt) + req.max_new_tokens)
+        try:
+            res = self.kv.reserve(tokens)
+        except FleetSaturated:
+            return False
+        except Exception as e:
+            _fail(req, e)
+            return True
         slot = self._free.pop()
-        if self._rings is None:
-            cp = _ChunkedPrefill(req=req, slot=slot, key=key, res=res,
-                                 cache=self.family.prefill_cache(1))
-        else:
-            # no private cache: the chunks go into the arenas through a
-            # table of the row's own, handed to the engine's at activation
-            cp = _ChunkedPrefill(
-                req=req, slot=slot, key=key, res=res, cache=None,
-                table=np.full((self._max_blocks,), self._alloc.trash, np.int32),
-                stats=jnp.zeros((3,), jnp.int32))
-            # reservation at admission counts BOTH kinds: the full kind's
-            # ceil((prompt + budget) / block_t) above, and one ring of the
-            # window kind, which a request that has a slot always gets
-            self._rings.attach(slot, self._rings.reserve())
-        self._chunked = cp
+        self.kv.hold(slot, res)
+        in_arena = bool(self.family.window)    # no private cache, then
+        self._chunked = _ChunkedPrefill(
+            req=req, slot=slot, key=key, res=res,
+            cache=None if in_arena else self.family.prefill_cache(1),
+            stats=jnp.zeros((3,), jnp.int32) if in_arena else None)
         _ev(req, "chunked_prefill_start", slot=slot,
             chunks=-(-len(req.prompt) // self.prefill_chunk))
         return True
 
     def _abort_chunked(self, cp: _ChunkedPrefill) -> None:
-        """Release a mid-prefill request's slot and (paged) blocks; the
-        caller completes/fails the request itself. Retire ordering applies
-        here too: the table row goes to trash before the blocks return."""
-        if self.paged:
-            self._tables[cp.slot, :] = self._alloc.trash
-            self._slot_res.pop(cp.slot, None)
-            self._ub_cursor[cp.slot] = 0
-            if cp.res is not None:
-                self._alloc.release(cp.res)
-            if self._rings is not None:
-                self._rings.release(cp.slot)
+        """Release a mid-prefill request's slot and KV; the caller
+        completes/fails the request itself."""
+        self.kv.release(cp.slot)
         self._free.append(cp.slot)
         self._chunked = None
 
@@ -1225,7 +1136,7 @@ class ContinuousBatcher:
             _fail(req, DeadlineExceeded(
                 "deadline expired during chunked prefill"))
             return []
-        if self._rings is not None:
+        if self.family.window:
             return self._advance_in_arena(cp)
         if self._chunk_prefill_fn is None:
             self._chunk_prefill_fn = self._build_chunk_prefill()
@@ -1265,23 +1176,13 @@ class ContinuousBatcher:
         small = self.family.kv_of(cp.cache)
         slots_arr = jnp.asarray([slot], jnp.int32)
         true_lens_arr = jnp.asarray([n], jnp.int32)
-        adopt_args = (self.last_tok, self.temps, self.rngs, first_arr,
-                      jnp.asarray([req.temperature], jnp.float32),
-                      jax.random.fold_in(cp.key, 1)[None])
-        if self.paged:
-            nb = cp.pos // self.kv_block_t  # whole blocks: bt | chunk
-            block_ids = np.full((1, nb), self._alloc.trash, np.int32)
-            self._alloc.grant(cp.res, self._alloc.blocks_for(n))
-            block_ids[0, :len(cp.res.granted)] = cp.res.granted
-            self._tables[slot, :len(cp.res.granted)] = cp.res.granted
-            self._slot_res[slot] = cp.res
-            self._ub_cursor[slot] = n
-            self.cache, self.last_tok, self.temps, self.rngs = self._adopt_fn(
-                self.cache, small, jnp.asarray(block_ids), slots_arr,
-                true_lens_arr, *adopt_args)
-        else:
-            self.cache, self.last_tok, self.temps, self.rngs = self._adopt_fn(
-                self.cache, small, slots_arr, true_lens_arr, *adopt_args)
+        # whole blocks of the padded prompt: block_t divides the chunk
+        block_ids = self.kv.bind([slot], [cp.res], [n], cp.pos)
+        self.cache, self.last_tok, self.temps, self.rngs = self._adopt_fn(
+            self.cache, small, *map(jnp.asarray, block_ids), slots_arr,
+            true_lens_arr, self.last_tok, self.temps, self.rngs, first_arr,
+            jnp.asarray([req.temperature], jnp.float32),
+            jax.random.fold_in(cp.key, 1)[None])
         if self.spec_k:
             # the draft adopts the full prompt in one forward (its whole
             # point is being small; chunking IT would serialize more
@@ -1309,13 +1210,7 @@ class ContinuousBatcher:
         except Exception:
             pass
         now = time.perf_counter()
-        self._active[slot] = req
-        if req.submit_at is not None:
-            METRICS.histogram(
-                "serving_queue_wait_seconds", buckets=QUEUE_WAIT_BUCKETS,
-            ).observe(now - req.submit_at, trace_id=_trace_id(req))
-        _ev(req, "admitted", slot=slot)
-        _ev(req, "prefill_done")
+        self._join(req, slot, now)
         self._chunked = None
         self._set_occupancy()
         return [("first", first, [(req, slot)], now)]
@@ -1323,53 +1218,33 @@ class ContinuousBatcher:
     def _advance_in_arena(self, cp: _ChunkedPrefill
                           ) -> List[Tuple[str, Any, Any, float]]:
         """One chunk of a prompt whose family prefills straight into the
-        arenas (no private cache, no adopt): grant the chunk its blocks of
-        both kinds, dispatch the family's chunk program — ONE program a
-        chunk shape and view width, whatever the prompt's length — and,
-        after the last chunk, hand the row's table to the engine's and
-        activate the slot. The chunk reads the window ring as the previous
-        chunk left it and writes only the blocks the next reader (the next
-        chunk, or decode at the prompt's end) can still see; the ring's
-        older blocks go back to the free list before the new ones are
-        granted (table entry to trash first, as at a retire)."""
+        arenas (no private cache, no adopt): the owner grants the chunk its
+        blocks of both kinds and says which tables it reads and writes
+        (``SlotKV.chunk_tables``), the family's chunk program runs — ONE
+        program a chunk shape and view width, whatever the prompt's length
+        — and, after the last chunk, the row's table becomes the one decode
+        dispatches see and the slot is activated."""
         req, slot = cp.req, cp.slot
         if self._chunk_prefill_fn is None:
             self._chunk_prefill_fn = self.family.build_chunk_prefill()
-        n, c, bt = len(req.prompt), self.prefill_chunk, self.kv_block_t
+        n, c = len(req.prompt), self.prefill_chunk
         start = cp.pos
         end = min(start + c, n)
         ids = np.zeros((c,), np.int32)
         ids[:end - start] = req.prompt[start:end]
-        base = len(cp.res.granted)
-        for off, blk in enumerate(
-                self._alloc.grant(cp.res, self._alloc.blocks_for(end))):
-            cp.table[base + off] = blk
-        first_block = start // bt
-        held = self._alloc.blocks_for(end)
-        write_full = np.full((c // bt,), self._alloc.trash, np.int32)
-        write_full[:held - first_block] = cp.table[first_block:held]
-        view = next(w for w in self._view_widths if w >= held)
-        rings = self._rings
-        read_window = rings.row(slot).copy()
-        rings.advance(slot, end, end)
-        write_window = np.asarray(
-            [rings.block_of(slot, first_block + j) for j in range(c // bt)],
-            np.int32)
+        tables = self.kv.chunk_tables(slot, start, end, c)
         self.cache, first, stats = self._chunk_prefill_fn(
             self.params, self.cache, jnp.asarray(ids),
             jnp.asarray(start, jnp.int32), jnp.asarray(end - start, jnp.int32),
             jnp.asarray(req.temperature, jnp.float32), cp.key,
-            jnp.asarray(cp.table[:view]), jnp.asarray(write_full),
-            jnp.asarray(read_window), jnp.asarray(write_window))
+            *map(jnp.asarray, tables))
         cp.stats = cp.stats + stats
         cp.pos = start + c
         METRICS.counter("serving_prefill_chunks_total").inc()
         _ev(req, "prefill_chunk", start=start)
         if end < n:
             return []
-        self._tables[slot, :] = cp.table
-        self._slot_res[slot] = cp.res
-        self._ub_cursor[slot] = n
+        self.kv.bind([slot], [cp.res], [n])
         self.cache, self.last_tok, self.temps, self.rngs = self._activate_fn(
             self.cache, self.last_tok, self.temps, self.rngs,
             jnp.asarray(slot, jnp.int32), jnp.asarray(n, jnp.int32), first,
@@ -1412,8 +1287,7 @@ class ContinuousBatcher:
                 continue
             n = len(req.prompt)
             try:
-                res = self._alloc.reserve(
-                    self._alloc.blocks_for(n + req.max_new_tokens))
+                res = self.kv.reserve(n + req.max_new_tokens)
             except FleetSaturated:
                 break  # no blocks yet; the import keeps its place in line
             except Exception as e:
@@ -1422,17 +1296,13 @@ class ContinuousBatcher:
                 continue
             self._imports.popleft()
             slot = self._free.pop()
+            block_ids = self.kv.bind([slot], [res], [n])[0][0]
+            nb = len(block_ids)
             try:
-                nb = self._alloc.blocks_for(n)
-                self._alloc.grant(res, nb)
-                block_ids = np.asarray(res.granted, np.int32)
                 if any(a.shape[0] != nb for a in imp.arrays.values()):
                     raise ValueError(
                         f"wire carries a block count != {nb} for "
                         f"prompt_len {n}")
-                self._tables[slot, :nb] = block_ids
-                self._slot_res[slot] = res
-                self._ub_cursor[slot] = n
                 wire = {}
                 for i in range(self.cfg.n_layers):
                     nm = f"block_{i}"
@@ -1475,50 +1345,17 @@ class ContinuousBatcher:
                         jnp.asarray([n], jnp.int32))
             except Exception as e:
                 self._free.append(slot)
-                self._tables[slot, :] = self._alloc.trash
-                self._slot_res.pop(slot, None)
-                self._ub_cursor[slot] = 0
-                self._alloc.release(res)
+                self.kv.release(slot)
                 _fail(req, e)
                 continue
             now = time.perf_counter()
-            self._active[slot] = req
-            if req.submit_at is not None:
-                METRICS.histogram(
-                    "serving_queue_wait_seconds", buckets=QUEUE_WAIT_BUCKETS,
-                ).observe(now - req.submit_at, trace_id=_trace_id(req))
             METRICS.counter("serving_kv_import_total").inc()
-            _ev(req, "admitted", slot=slot)
-            _ev(req, "kv_import", blocks=int(nb))
+            self._join(req, slot, now, "kv_import", blocks=int(nb))
             events.append(("first",
                            np.asarray([imp.manifest["first_token"]], np.int32),
                            [(req, slot)], now))
         self._set_occupancy()
         return events
-
-    def _grant_active(self, tokens: int) -> None:
-        """Advance every active slot's cursor upper bound by the tokens the
-        next dispatch may write and grant the blocks that frontier needs —
-        BEFORE the dispatch snapshots the table. The bound (not the exact
-        data-dependent cursor, which spec rounds make device-resident)
-        drives granting; positions past ``res.total`` stay on trash, which
-        only retired-but-undrained rows can reach."""
-        if not self.paged:
-            return
-        max_seq = self.cfg.max_seq
-        for slot in self._active:
-            res = self._slot_res.get(slot)
-            if res is None:
-                continue
-            cursor = int(self._ub_cursor[slot])
-            ub = min(cursor + tokens, max_seq)
-            self._ub_cursor[slot] = ub
-            if self._rings is not None:
-                self._rings.advance(slot, cursor, ub)
-            base = len(res.granted)
-            for off, blk in enumerate(
-                    self._alloc.grant(res, self._alloc.blocks_for(ub))):
-                self._tables[slot, base + off] = blk
 
     def _run_decode(self, tables: Tuple[Any, ...]) -> Tuple[str, Any]:
         """One decode chunk, or one speculative round, of every slot on the
@@ -1536,39 +1373,17 @@ class ContinuousBatcher:
             *tables)
         return "chunk", (toks, *stats) if stats else toks
 
-    def _ring_tables(self, span, view: int) -> Tuple[Any, Any]:
-        """The window kind's share of a decode dispatch: every LIVE row's
-        ring (a row still prefilling keeps its ring to itself: a decode
-        step writes every row's token somewhere, and a dead row's must land
-        in trash) and which rows are live; the blocks in use by kind ride
-        on the dispatch's region, and the full-kind pages its last step's
-        attention fetches (``ops/paged_attention``: each live row its own
-        pages up to its cursor, within the ``view`` columns handed over)."""
-        rings = self._rings
-        live = np.zeros((self.slots,), bool)
-        live[list(self._active)] = True
-        span.set_metadata(full_blocks=self._alloc.used(),
-                          window_blocks=rings.used(),
-                          window_blocks_unreleased=rings.unreleased(),
-                          full_blocks_read=sum(
-                              min(self._alloc.blocks_for(self._ub_cursor[slot]), view)
-                              for slot in self._active))
-        return (jnp.asarray(np.where(live[:, None], rings.tables, rings.trash)),
-                jnp.asarray(live))
-
-    def _warm_views(self) -> None:
-        """Compile the decode program at every view width by running it
-        once on an all-trash table of that width: every row is dead there
-        (its writes go to the trash block, its tokens to nobody), and the
-        cursors and sampling state it moves are set anew by the adopt that
-        admits a request. Not for a turn with an active slot."""
-        rings = ()
-        if self._rings is not None:
-            rings = (jnp.full((self.slots, self._rings.cols), self._rings.trash,
-                              jnp.int32), jnp.zeros((self.slots,), bool))
-        for width in self._view_widths:
-            self._run_decode((jnp.full((self.slots, width), self._alloc.trash,
-                                       jnp.int32),) + rings)
+    def _join(self, req: _Request, slot: int, now: float,
+              how: str = "prefill_done", **attrs: Any) -> None:
+        """A request whose KV is in place joins the decode batch in
+        ``slot``: the next dispatch has the row in its snapshot."""
+        self._active[slot] = req
+        if req.submit_at is not None:
+            METRICS.histogram(
+                "serving_queue_wait_seconds", buckets=QUEUE_WAIT_BUCKETS,
+            ).observe(now - req.submit_at, trace_id=_trace_id(req))
+        _ev(req, "admitted", slot=slot)
+        _ev(req, how, **attrs)
 
     def _set_occupancy(self) -> None:
         active = len(self._active)
@@ -1580,21 +1395,7 @@ class ContinuousBatcher:
     def _retire(self, slot: int) -> None:
         req = self._active.pop(slot)
         self._free.append(slot)
-        if self.paged:
-            # retire-ordering invariant: redirect the table row to TRASH
-            # before the blocks return to the free list. Later dispatches
-            # snapshot the trashed table, so a block re-granted to another
-            # slot can only be written by (a) dispatches issued before this
-            # retire — which execute before the new slot's adopt overwrites
-            # the block (device streams run in issue order) — or (b) the
-            # new slot itself. Never a corrupting interleave.
-            self._tables[slot, :] = self._alloc.trash
-            res = self._slot_res.pop(slot, None)
-            if res is not None:
-                self._alloc.release(res)
-            if self._rings is not None:
-                self._rings.release(slot)
-            self._ub_cursor[slot] = 0
+        self.kv.release(slot)
         req.done_at = time.perf_counter()
         if req.finish_reason is None:
             req.finish_reason = "ok"
@@ -1962,7 +1763,11 @@ class ContinuousBatcher:
                 self._reap_pending()
                 self._reap_active()
             if self._view_warmup == "asked" and not self._active:
-                self._warm_views()
+                # compile the decode program at every view width: the cursors
+                # and sampling state a run on all-trash tables moves are set
+                # anew by the adopt that admits a request
+                for tables in self.kv.warm_tables():
+                    self._run_decode(tables)
                 self._view_warmup = "done"
             dispatched = False
             if self._imports and self._free and not self._draining:
@@ -1972,7 +1777,7 @@ class ContinuousBatcher:
                 with profiling.annotate("serving.engine.import"):
                     events.extend(self._admit_imports())
                 dispatched = True
-            batched = self._rings is None
+            batched = not self.family.window
             if (self._free and self._pending and not self._draining
                     and (batched or self._chunked is None)):
                 # a family that prefills every prompt in the one chunked
@@ -2000,18 +1805,9 @@ class ContinuousBatcher:
                 with profiling.annotate("serving.engine.dispatch",
                                         rows=self.slots * width,
                                         live=len(self._active)) as span:
-                    self._grant_active(width)
-                    tables = ()
-                    if self.paged:
-                        view = view_blocks(self._tables, self._alloc.trash,
-                                           self._view_widths)
-                        tables = (jnp.asarray(self._tables[:, :view]),)
-                        span.set_metadata(view_blocks=view,
-                                          max_blocks=self._max_blocks)
-                        METRICS.gauge("serving_decode_view_blocks",
-                                      replica=self.engine_id).set(view)
-                        if self._rings is not None:
-                            tables += self._ring_tables(span, view)
+                    self.kv.advance(self._active, width)
+                    tables, stats = self.kv.dispatch_tables(self._active)
+                    span.set_metadata(**stats)
                     kind, out = self._run_decode(tables)
                     try:
                         for arr in jax.tree.leaves(out):

@@ -1,8 +1,8 @@
 """Model families of the serving engine.
 
 ``ContinuousBatcher`` (serving/continuous.py) owns the loop, the scheduler,
-the admission path, the slots and the block accounting. What it does NOT
-know is a model: which modules to build, what a fresh cache looks like,
+the admission path and the slots; ``paged.SlotKV`` owns the block
+accounting. What neither knows is a model: which modules to build, what a fresh cache looks like,
 which leaves of it an adopt, a rollback or an import touch, and which
 device programs run a decode step or a prefill. A family is that knowledge
 for one kind of configuration, found from the configuration's type
@@ -23,7 +23,7 @@ tokens. It has no private prefill cache, so nothing of it is adopted.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -74,18 +74,16 @@ class GptFamily:
 
     def __init__(self, cfg: GptConfig, *, slots: int, paged: bool = False,
                  kv_blocks: int = 0, kv_block_t: int = 16,
-                 kv_kernel: Optional[bool] = None, kv_dtype: str = "bf16"):
+                 kv_dtype: str = "bf16"):
         self.cfg, self.slots, self.paged = cfg, slots, paged
         self.kv_blocks, self.kv_block_t, self.kv_dtype = kv_blocks, kv_block_t, kv_dtype
         if paged:
-            self.model = GptLM(cfg, decode=True, per_slot=True,
-                               kv_kernel=kv_kernel, paged=True,
+            self.model = GptLM(cfg, decode=True, per_slot=True, paged=True,
                                kv_blocks=kv_blocks + 1,
                                kv_block_t=kv_block_t,
                                kv_dtype=kv_dtype)
         else:
-            self.model = GptLM(cfg, decode=True, per_slot=True,
-                               kv_kernel=kv_kernel)
+            self.model = GptLM(cfg, decode=True, per_slot=True)
         self.prefill_model = GptLM(cfg, decode=True)  # [1, P], scalar cursor
 
     # -- caches ----------------------------------------------------------------
@@ -301,7 +299,7 @@ class MimoFamily:
 
     def __init__(self, cfg: MimoConfig, *, slots: int, paged: bool = True,
                  kv_blocks: int = 0, kv_block_t: int = 16,
-                 kv_kernel: Optional[bool] = None, kv_dtype: str = "bf16"):
+                 kv_dtype: str = "bf16"):
         if not paged:
             raise ValueError("this model family keeps two kinds of cache: "
                              "it needs the paged layout (paged=True)")

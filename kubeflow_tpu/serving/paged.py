@@ -8,7 +8,10 @@ block) and a host-side per-slot block table mapping absolute positions to
 arena rows. This module owns the host-side half: the free-list allocator
 that reserves capacity at admission and grants physical blocks as cursors
 advance, published as ``serving_kv_blocks_{free,used}`` gauges so arena
-sizing is an observable capacity knob rather than a silent OOM.
+sizing is an observable capacity knob rather than a silent OOM; the window
+kind of cache (:class:`WindowRings`); and :class:`SlotKV`, the ONE owner of
+every slot's KV on the host, whose verbs the engine
+(``serving/continuous.py``) performs without knowing table, trash or rings.
 
 Two-phase accounting (reserve → grant) is deliberate:
 
@@ -25,18 +28,19 @@ Two-phase accounting (reserve → grant) is deliberate:
   stay on the free list (they count against :meth:`available`, not the
   gauges), and the slot's table entries point at the trash block.
 
-The device-side correctness contract lives in
-``kubeflow_tpu/ops/kv_cache.py`` (trash-block convention) and
-``serving/continuous.py`` (retire ordering: table row → trash BEFORE
-blocks return to the free list, so stale in-flight dispatches write to
-trash, never into a re-granted block).
+The device-side half of the contract is the trash-block convention
+(``kubeflow_tpu/ops/kv_cache.py``); the host-side half is the retire
+ordering (:func:`_trash_then_return`: table row → trash BEFORE blocks return
+to the free list, so stale in-flight dispatches write to trash, never into
+a re-granted block).
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
+import jax.numpy as jnp
 import numpy as np
 
 from ..runtime.metrics import METRICS
@@ -75,9 +79,12 @@ class KVReservation:
     """One slot's promised block budget: ``total`` blocks reserved, of
     which ``granted`` have been popped off the free list (in position
     order — ``granted[i]`` backs positions ``[i*block_t, (i+1)*block_t)``).
+    ``ring``: the same request's ring of the window kind, where the family
+    has window layers (:meth:`SlotKV.reserve` promises both together).
     """
     total: int
     granted: List[int] = field(default_factory=list)
+    ring: Optional["KVReservation"] = None
 
 
 class KVBlockAllocator:
@@ -153,8 +160,8 @@ class KVBlockAllocator:
 
     def release(self, res: KVReservation) -> None:
         """Return a reservation's blocks (granted and promised) to the
-        free list. The caller MUST have redirected the slot's table row to
-        trash before calling this (retire ordering invariant)."""
+        free list. A reservation that has a table row goes through
+        :func:`_trash_then_return`, never straight here."""
         self._free.extend(res.granted)
         self._promised -= res.total - len(res.granted)
         res.granted = []
@@ -175,6 +182,20 @@ class KVBlockAllocator:
     def _publish(self) -> None:
         METRICS.gauge("serving_kv_blocks_free", **self._labels).set(len(self._free))
         METRICS.gauge("serving_kv_blocks_used", **self._labels).set(self.used())
+
+
+def _trash_then_return(row: np.ndarray, alloc: KVBlockAllocator,
+                       res: Optional[KVReservation]) -> None:
+    """A slot gives one kind of cache back. Retire-ordering invariant:
+    redirect the table row to TRASH before the blocks return to the free
+    list. Later dispatches snapshot the trashed table, so a block
+    re-granted to another slot can only be written by (a) dispatches issued
+    before this retire — which execute before the new slot's adopt
+    overwrites the block (device streams run in issue order) — or (b) the
+    new slot itself. Never a corrupting interleave."""
+    row[:] = alloc.trash
+    if res is not None:
+        alloc.release(res)
 
 
 class WindowRings:
@@ -244,13 +265,10 @@ class WindowRings:
         return self._held[slot].get(logical, self.trash)
 
     def release(self, slot: int) -> None:
-        """Retire: the row goes to trash, then the blocks return."""
-        self.tables[slot, :] = self.trash
-        res = self._res.pop(slot, None)
         self._held.pop(slot, None)
         self._frontier[slot] = 0
-        if res is not None:
-            self.alloc.release(res)
+        _trash_then_return(self.tables[slot], self.alloc,
+                           self._res.pop(slot, None))
 
     def used(self) -> int:
         return self.alloc.used()
@@ -259,3 +277,234 @@ class WindowRings:
         """Blocks the window kind would hold had nothing been given back:
         one per ``block_t`` positions of every attached row's frontier."""
         return int(sum(-(-int(self._frontier[s]) // self.block_t) for s in self._res))
+
+
+class SlotKV:
+    """The one owner of every slot's KV on the host. Only this class knows
+    the trash id, the table's layout (``[slots, max_blocks]``, ONE table for
+    every layer of the full kind; entries default to trash, so unallocated
+    positions can never hit real data), the two kinds of blocks (the full
+    kind here, the window kind in ``rings`` for a family with window
+    layers), the widths a decode dispatch reads the table at, and the order
+    in which a slot gives everything back.
+
+    A slot's life, in the engine's verbs: :meth:`check` at submit;
+    :meth:`reserve` before any compute is spent; :meth:`hold` once the
+    request has a slot (its row stays on trash); :meth:`bind` when the
+    prompt's KV is written (the row shows its blocks from here on); every
+    decode dispatch :meth:`advance`, then :meth:`dispatch_tables`;
+    :meth:`release` at retirement or on any failure in between. A prompt
+    that prefills straight into the arenas asks :meth:`chunk_tables` a
+    chunk, between ``hold`` and ``bind``.
+    """
+
+    def __init__(self, slots: int, max_seq: int, block_t: int, n_blocks: int,
+                 *, engine_id: str = "0",
+                 rings: Optional[WindowRings] = None):
+        self.slots, self.max_seq, self.block_t = int(slots), int(max_seq), int(block_t)
+        self.engine_id = engine_id
+        self.rings = rings
+        self.alloc = KVBlockAllocator(n_blocks, block_t, engine_id=engine_id,
+                                      kind="full" if rings is not None else "")
+        self.max_blocks = self.max_seq // self.block_t
+        self.tables = np.full((self.slots, self.max_blocks), self.alloc.trash,
+                              np.int32)
+        # jit specialises the decode program on each of these widths
+        self.view_widths = view_widths(self.max_blocks)
+        self._res: Dict[int, KVReservation] = {}
+        # upper bound on each slot's device cursor at the dispatch frontier
+        # — spec rounds advance the real cursor by a data-dependent amount,
+        # so granting tracks the bound
+        self._cursor = np.zeros((self.slots,), np.int64)
+        # the table of a row that prefills straight into the arenas, while
+        # it fills: the shared row stays on trash until bind, so decode
+        # dispatches in between write nothing of this dead row into its blocks
+        self._filling: Dict[int, np.ndarray] = {}
+
+    def check(self, tokens: int) -> None:
+        """ValueError for a request of ``tokens`` positions that can NEVER
+        fit: waiting cannot help, so it must not pend forever behind an
+        arena that is too small by construction."""
+        need = self.alloc.blocks_for(tokens)
+        if need > self.alloc.n_blocks:
+            raise ValueError(
+                f"prompt + budget needs {need} KV blocks; the arena has "
+                f"{self.alloc.n_blocks} (raise kv_blocks)")
+
+    def reserve(self, tokens: int) -> KVReservation:
+        """Promise a request its worst case: ``ceil(tokens / block_t)``
+        blocks of the full kind and, with window layers, one ring (a request
+        that has a slot always gets one). :class:`KVBlocksExhausted` is
+        back-pressure and leaves nothing taken."""
+        res = self.alloc.reserve(self.alloc.blocks_for(tokens))
+        if self.rings is not None:
+            try:
+                res.ring = self.rings.reserve()
+            except Exception:
+                self.alloc.release(res)
+                raise
+        return res
+
+    def hold(self, slot: int, res: KVReservation) -> None:
+        """``res`` is ``slot``'s from here on (:meth:`release` of the slot
+        returns it); the slot's row stays on trash until :meth:`bind`."""
+        self._res[slot] = res
+        if res.ring is not None:
+            self.rings.attach(slot, res.ring)
+
+    def bind(self, slots: Sequence[int], reservations: Sequence[KVReservation],
+             prompt_lens: Sequence[int], padded: int = 0
+             ) -> Tuple[np.ndarray, ...]:
+        """Grant each row the blocks its PROMPT needs (decode grants the
+        rest as cursors advance), point its table row at them and set its
+        cursor bound — BEFORE the dispatch that writes the prompt's KV
+        snapshots the ids. Returns what that dispatch takes beside the
+        rows: the ids ``[n, ceil(padded / block_t)]``, trash behind each
+        row's own (``padded`` 0: as wide as the longest prompt needs)."""
+        cols = self.alloc.blocks_for(padded or max(prompt_lens))
+        ids = np.full((len(slots), cols), self.alloc.trash, np.int32)
+        for i, (slot, res, n) in enumerate(zip(slots, reservations, prompt_lens)):
+            if self._res.get(slot) is not res:
+                self.hold(slot, res)
+            self._filling.pop(slot, None)
+            self.alloc.grant(res, self.alloc.blocks_for(n))
+            ids[i, :len(res.granted)] = res.granted
+            self.tables[slot, :len(res.granted)] = res.granted
+            self._cursor[slot] = n
+        return (ids,)
+
+    def release(self, what: Union[int, KVReservation]) -> None:
+        """Give back a slot's KV of both kinds, or a reservation that never
+        met a slot."""
+        if isinstance(what, KVReservation):
+            self.alloc.release(what)
+            if what.ring is not None:
+                self.rings.alloc.release(what.ring)
+            return
+        self._filling.pop(what, None)
+        self._cursor[what] = 0
+        _trash_then_return(self.tables[what], self.alloc,
+                           self._res.pop(what, None))
+        if self.rings is not None:
+            self.rings.release(what)
+
+    def advance(self, active: Iterable[int], tokens: int) -> None:
+        """Advance every active slot's cursor upper bound by the tokens the
+        next dispatch may write and grant the blocks that frontier needs —
+        BEFORE the dispatch snapshots the table. The bound (not the exact
+        data-dependent cursor, which spec rounds make device-resident)
+        drives granting; positions past ``res.total`` stay on trash, which
+        only retired-but-undrained rows can reach."""
+        for slot in active:
+            res = self._res.get(slot)
+            if res is None:
+                continue
+            cursor = int(self._cursor[slot])
+            ub = min(cursor + tokens, self.max_seq)
+            self._cursor[slot] = ub
+            if self.rings is not None:
+                self.rings.advance(slot, cursor, ub)
+            self._grant_into(self.tables[slot], res, ub)
+
+    def _grant_into(self, row: np.ndarray, res: KVReservation, tokens: int) -> None:
+        """Grant ``res`` the blocks ``tokens`` positions need and put the new
+        ones behind those ``row`` already shows."""
+        base = len(res.granted)
+        new = self.alloc.grant(res, self.alloc.blocks_for(tokens))
+        row[base:base + len(new)] = new
+
+    def dispatch_tables(self, active: Iterable[int]
+                        ) -> Tuple[Tuple[Any, ...], Dict[str, int]]:
+        """What a decode dispatch of the ``active`` slots takes after the
+        cache, and the ``serving.engine.dispatch`` region's stats. The
+        table's first columns, up to the longest granted row
+        (``view_blocks``); with window layers also every LIVE row's ring (a
+        row still prefilling keeps its ring to itself: a decode step writes
+        every row's token somewhere, and a dead row's must land in trash),
+        which rows are live, the blocks in use by kind, and the full-kind
+        pages the last step's attention fetches (``ops/paged_attention``:
+        each live row its own pages up to its cursor, within the view)."""
+        view = view_blocks(self.tables, self.alloc.trash, self.view_widths)
+        METRICS.gauge("serving_decode_view_blocks",
+                      replica=self.engine_id).set(view)
+        tables = (jnp.asarray(self.tables[:, :view]),)
+        stats = {"view_blocks": view, "max_blocks": self.max_blocks}
+        rings = self.rings
+        if rings is not None:
+            live = np.zeros((self.slots,), bool)
+            live[list(active)] = True
+            stats.update(
+                full_blocks=self.alloc.used(), window_blocks=rings.used(),
+                window_blocks_unreleased=rings.unreleased(),
+                full_blocks_read=sum(
+                    min(self.alloc.blocks_for(self._cursor[slot]), view)
+                    for slot in active))
+            tables += (jnp.asarray(np.where(live[:, None], rings.tables,
+                                            rings.trash)),
+                       jnp.asarray(live))
+        return tables, stats
+
+    def warm_tables(self) -> List[Tuple[Any, ...]]:
+        """All-trash tables, one set a view width, to compile the decode
+        program on: every row is dead there (its writes go to the trash
+        block, its tokens to nobody)."""
+        ring = ()
+        if self.rings is not None:
+            ring = (jnp.full((self.slots, self.rings.cols), self.rings.trash,
+                             jnp.int32), jnp.zeros((self.slots,), bool))
+        return [(jnp.full((self.slots, width), self.alloc.trash, jnp.int32),)
+                + ring for width in self.view_widths]
+
+    def chunk_tables(self, slot: int, start: int, end: int, chunk: int
+                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+        """The four tables of ONE prefill chunk (positions ``[start, end)``
+        in a program of ``chunk``) of a held slot that prefills straight
+        into the arenas: the row's own table up to the narrowest view width
+        covering ``end``, the full-kind blocks the chunk writes, the ring as
+        the previous chunk left it, the ring blocks the chunk writes. Grants
+        the chunk its blocks of both kinds first; the ring's older blocks go
+        back before the new ones are granted, so the chunk writes only what
+        the next reader (the next chunk, or decode) can still see."""
+        res, bt, trash = self._res[slot], self.block_t, self.alloc.trash
+        table = self._filling.get(slot)
+        if table is None:
+            table = self._filling[slot] = np.full((self.max_blocks,), trash,
+                                                  np.int32)
+        self._grant_into(table, res, end)
+        held, first_block = self.alloc.blocks_for(end), start // bt
+        write_full = np.full((chunk // bt,), trash, np.int32)
+        write_full[:held - first_block] = table[first_block:held]
+        view = next(w for w in self.view_widths if w >= held)
+        rings = self.rings
+        read_window = rings.row(slot).copy()
+        rings.advance(slot, end, end)
+        write_window = np.asarray(
+            [rings.block_of(slot, first_block + j) for j in range(chunk // bt)],
+            np.int32)
+        return table[:view], write_full, read_window, write_window
+
+
+class ContiguousKV:
+    """:class:`SlotKV`'s verbs with nothing behind them, for the contiguous
+    cache (``paged=False``, the tests' parity reference): every slot's
+    ``max_seq`` rows are its own on the device for good, so nothing is
+    reserved, granted or given back and a dispatch takes no table."""
+
+    block_t = 0
+
+    def reserve(self, tokens: int) -> KVReservation:
+        return KVReservation(total=0)
+
+    def bind(self, slots, reservations, prompt_lens, padded=0) -> Tuple[()]:
+        return ()
+
+    def dispatch_tables(self, active) -> Tuple[Tuple[()], Dict[str, int]]:
+        return (), {}
+
+    def warm_tables(self) -> List[Tuple[Any, ...]]:
+        return []
+
+    def _nothing(self, *args: Any) -> None:
+        pass
+
+    check = hold = release = advance = _nothing

@@ -19,11 +19,9 @@ from jax.sharding import SingleDeviceSharding
 from kubeflow_tpu.ops.chunk_attention import chunk_attention
 from kubeflow_tpu.ops.flash_attention import flash_attention
 from kubeflow_tpu.ops.fused_bottleneck import fused_bottleneck, fused_transition
-from kubeflow_tpu.ops.kv_cache import (
-    kv_block_update, kv_block_update_quant, kv_row_update)
 from kubeflow_tpu.ops.paged_attention import paged_decode_attention
 
-BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
 @pytest.fixture(scope="module")
@@ -70,27 +68,6 @@ def test_flash_attention_fwd_bwd_gpt_train_shape(chip):
 
     qkv = [((8, 1024, 16, 64), BF16)] * 3   # the GPT row: b8 L1024 h16 d64
     assert _compile(chip, jax.grad(loss, argnums=(0, 1, 2)), *qkv) == 3
-
-
-# 8 slots x 1024 positions of 16 x 64 heads: the serving rows' cache
-KV = (8, 1024, 16, 64)
-ARENA = (8 * 64 + 1, 16, 16, 64)   # the same capacity in 16-token blocks
-KV_ROW = [((8, 16, 64), BF16), ((8,), I32)]
-KV_PAGED = KV_ROW + [((8, 64), I32)]
-
-
-@pytest.mark.parametrize("fn,shapes", [
-    (lambda c, n, cur: kv_row_update(c, n, cur, interpret=False),
-     [(KV, BF16)] + KV_ROW),
-    (lambda a, n, cur, t: kv_block_update(
-        a, n, cur, t, max_seq=1024, interpret=False),
-     [(ARENA, BF16)] + KV_PAGED),
-    (lambda a, s, n, cur, t: kv_block_update_quant(
-        a, s, n, cur, t, max_seq=1024, interpret=False),
-     [(ARENA, I8), (ARENA[:3] + (1,), F32)] + KV_PAGED),
-], ids=["kv_row_update", "kv_block_update", "kv_block_update_quant"])
-def test_kv_write_kernels_serving_shape(chip, fn, shapes):
-    assert _compile(chip, fn, *shapes) == 1
 
 
 def test_fused_bottleneck_identity_stage4(chip):

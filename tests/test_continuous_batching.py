@@ -337,11 +337,11 @@ def test_prewarm_compiles_every_view_width_and_leaves_the_engine_sound(params):
     base = _run_jobs(CFG, params, jobs, slots=2, paged=False)
     eng = ContinuousBatcher(CFG, params, slots=2, paged=True)
     try:
-        assert eng._view_widths == (2, 4, 6, 8) and eng._view_warmup == "no"
+        assert eng.kv.view_widths == (2, 4, 6, 8) and eng._view_warmup == "no"
         eng.prewarm(8)
         assert eng._view_warmup == "done"
         compiled = eng._step_fn._cache_size()
-        assert compiled == len(eng._view_widths)
+        assert compiled == len(eng.kv.view_widths)
         futs = [eng.submit(pr, b) for pr, b in jobs]
         assert [f.result(timeout=180) for f in futs] == base
         eng.prewarm(8)                                # once is enough

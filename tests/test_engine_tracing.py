@@ -180,7 +180,7 @@ def test_decode_step_carries_its_scopes_and_they_move_no_instruction(params, pag
     def lower():
         eng = ContinuousBatcher(CFG, params, slots=2, chunk=2, paged=paged)
         try:
-            extra = (jnp.asarray(eng._tables),) if paged else ()
+            extra = (jnp.asarray(eng.kv.tables),) if paged else ()
             return eng._step_fn.lower(eng.params, eng.cache, eng.last_tok,
                                       eng.temps, eng.rngs, *extra)
         finally:

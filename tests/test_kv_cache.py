@@ -1,157 +1,50 @@
-"""ops/kv_cache.py — the per-row KV write kernel behind continuous
-batching's per-slot decode (KUBEFLOW_TPU_KV_KERNEL=1 path)."""
+"""ops/kv_cache.py — the KV write of the paged serving path (one XLA
+scatter a token through the block table), int8 KV quantization, and the
+contiguous per-slot cache's where-select write in models/gpt.py."""
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from kubeflow_tpu.ops.kv_cache import kv_row_update
 
+def test_per_slot_decode_past_the_end_writes_nothing():
+    """Idle/retired rows keep stepping past their end in the engine (static
+    shapes: every row computes every chunk). The contiguous cache's
+    where-select write must leave such a row untouched — no position
+    compares equal to a cursor at or beyond ``max_seq`` — instead of
+    corrupting the last KV position (T-1 may hold a live token for a row at
+    exactly full length)."""
+    from kubeflow_tpu.models.gpt import GptConfig, GptLM
 
-def _reference(cache, new, cursors):
-    out = np.array(cache, copy=True)
-    T = out.shape[1]
-    for s in range(out.shape[0]):
-        if int(cursors[s]) < T:  # out-of-range rows are a no-op (retired slots)
-            out[s, int(cursors[s])] = new[s]
-    return out
-
-
-@pytest.mark.parametrize("shape,dtype", [
-    ((8, 352, 16, 64), jnp.float32),
-    ((4, 36, 4, 8), jnp.bfloat16),    # T not divisible by the default tile
-    ((1, 8, 2, 128), jnp.float32),    # single slot, tiny T
-])
-def test_row_update_matches_reference(shape, dtype):
-    S, T, H, D = shape
-    rng = np.random.default_rng(0)
-    cache_np = rng.normal(size=shape).astype(np.float32)
-    new_np = rng.normal(size=(S, H, D)).astype(np.float32)
-    cursors = rng.integers(0, T, S).astype(np.int32)
-    out = kv_row_update(jnp.asarray(cache_np, dtype), jnp.asarray(new_np, dtype),
-                        jnp.asarray(cursors))
-    want = _reference(np.asarray(jnp.asarray(cache_np, dtype), np.float32),
-                      np.asarray(jnp.asarray(new_np, dtype), np.float32), cursors)
-    np.testing.assert_allclose(np.asarray(out, np.float32), want, rtol=0, atol=0)
-
-
-def test_out_of_range_cursor_is_a_noop():
-    """Idle/retired rows keep stepping past their end in the engine; the
-    kernel must leave those rows untouched — the where-select path writes
-    nothing (no position compares equal to the cursor), and the kernel must
-    agree instead of corrupting the last KV position (T-1 may hold a live
-    token for a row at exactly full length)."""
-    S, T, H, D = 4, 16, 2, 8
-    cache = jnp.zeros((S, T, H, D), jnp.float32)
-    new = jnp.ones((S, H, D), jnp.float32)
+    cfg = GptConfig(d_model=32, n_layers=2, n_heads=2, d_ff=64,
+                    max_seq=24, vocab_size=128)
+    T = cfg.max_seq
+    params = GptLM(cfg).init(jax.random.PRNGKey(0),
+                             jnp.zeros((1, 4), jnp.int32))["params"]
+    model = GptLM(cfg, decode=True, per_slot=True)
+    S = 4
+    kv = (S, T, cfg.n_heads, cfg.head_dim)
     cursors = jnp.asarray([0, T, T + 5, 3], jnp.int32)
-    out = np.asarray(kv_row_update(cache, new, cursors))
-    assert out[0, 0].all() and out[3, 3].all()
-    assert out[1].sum() == 0 and out[2].sum() == 0  # untouched rows
-    # agreement with the reference (which skips out-of-range rows)
-    np.testing.assert_array_equal(
-        out, _reference(np.zeros((S, T, H, D), np.float32),
-                        np.ones((S, H, D), np.float32), np.asarray(cursors)))
-
-
-def test_per_slot_decode_same_tokens_with_and_without_kernel(monkeypatch):
-    """The kernel path and the where-select path must produce identical
-    decode tokens through the real per-slot model."""
-    import functools
-
-    from kubeflow_tpu.models.gpt import GptConfig, GptLM
-
-    cfg = GptConfig(d_model=32, n_layers=2, n_heads=2, d_ff=64,
-                    max_seq=24, vocab_size=128)
-    rng = jax.random.PRNGKey(0)
-    params = GptLM(cfg).init(rng, jnp.zeros((1, 4), jnp.int32))["params"]
-
-    def run(kernel: bool):
-        monkeypatch.setenv("KUBEFLOW_TPU_KV_KERNEL", "1" if kernel else "0")
-        model = GptLM(cfg, decode=True, per_slot=True)
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def step(params, cache, tok):
-            def one(carry, _):
-                cache, tok = carry
-                logits, upd = model.apply({"params": params, "cache": cache},
-                                          tok[:, None], mutable=["cache"])
-                nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-                return (upd["cache"], nxt), nxt
-            (cache, tok), toks = jax.lax.scan(one, (cache, tok), None, length=6)
-            return cache, tok, jnp.moveaxis(toks, 0, 1)
-
-        S = 3
-        kv = (S, cfg.max_seq, cfg.n_heads, cfg.head_dim)
-        cache = {f"block_{i}": {"attention": {
-            "k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
-            "cursors": jnp.asarray([1, 5, 9], jnp.int32)}}
-            for i in range(cfg.n_layers)}
-        tok = jnp.asarray([3, 7, 11], jnp.int32)
-        _, _, toks = step(params, cache, tok)
-        return np.asarray(toks)
-
-    np.testing.assert_array_equal(run(False), run(True))
-
-
-def test_kv_kernel_constructor_arg_decode_parity(monkeypatch):
-    """kv_kernel as a constructor arg must (a) produce identical decode
-    tokens either way and (b) OVERRIDE the env flag — serving configs pin
-    the strategy explicitly instead of inheriting process env."""
-    import functools
-
-    from kubeflow_tpu.models.gpt import GptConfig, GptLM
-
-    cfg = GptConfig(d_model=32, n_layers=2, n_heads=2, d_ff=64,
-                    max_seq=24, vocab_size=128)
-    rng = jax.random.PRNGKey(0)
-    params = GptLM(cfg).init(rng, jnp.zeros((1, 4), jnp.int32))["params"]
-
-    def run(kv_kernel):
-        # env set OPPOSITE to the arg: if the arg didn't take precedence,
-        # both runs would silently take the same path and the test would
-        # prove nothing
-        monkeypatch.setenv("KUBEFLOW_TPU_KV_KERNEL",
-                           "0" if kv_kernel else "1")
-        model = GptLM(cfg, decode=True, per_slot=True, kv_kernel=kv_kernel)
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def step(params, cache, tok):
-            def one(carry, _):
-                cache, tok = carry
-                logits, upd = model.apply({"params": params, "cache": cache},
-                                          tok[:, None], mutable=["cache"])
-                nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-                return (upd["cache"], nxt), nxt
-            (cache, tok), toks = jax.lax.scan(one, (cache, tok), None, length=6)
-            return cache, tok, jnp.moveaxis(toks, 0, 1)
-
-        S = 3
-        kv = (S, cfg.max_seq, cfg.n_heads, cfg.head_dim)
-        cache = {f"block_{i}": {"attention": {
-            "k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
-            "cursors": jnp.asarray([1, 5, 9], jnp.int32)}}
-            for i in range(cfg.n_layers)}
-        tok = jnp.asarray([3, 7, 11], jnp.int32)
-        _, _, toks = step(params, cache, tok)
-        return np.asarray(toks)
-
-    np.testing.assert_array_equal(run(False), run(True))
-
-
-def test_continuous_batcher_accepts_kv_kernel():
-    """The serving engine must expose the same pin-it-explicitly knob."""
-    import inspect
-
-    from kubeflow_tpu.serving.continuous import ContinuousBatcher
-
-    assert "kv_kernel" in inspect.signature(ContinuousBatcher.__init__).parameters
+    cache = {f"block_{i}": {"attention": {
+        "k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
+        "cursors": cursors}} for i in range(cfg.n_layers)}
+    _, upd = model.apply({"params": params, "cache": cache},
+                         jnp.asarray([[3], [7], [11], [5]], jnp.int32),
+                         mutable=["cache"])
+    for layer in upd["cache"].values():
+        for name in ("k", "v"):
+            out = np.asarray(layer["attention"][name], np.float32)
+            assert np.abs(out[0, 0]).sum() > 0 and np.abs(out[3, 3]).sum() > 0
+            assert np.abs(out[0, 1:]).sum() == 0 and np.abs(out[3, :3]).sum() == 0
+            assert np.abs(out[1]).sum() == 0 and np.abs(out[2]).sum() == 0
+        np.testing.assert_array_equal(np.asarray(layer["attention"]["cursors"]),
+                                      np.asarray(cursors) + 1)
 
 
 # -- paged (block-table) variants — ISSUE 12 ---------------------------------
 
-from kubeflow_tpu.ops.kv_cache import kv_block_update, kv_block_update_ref
+from kubeflow_tpu.ops.kv_cache import kv_block_update
 from kubeflow_tpu.serving.paged import KVBlockAllocator, KVBlocksExhausted
 
 
@@ -169,10 +62,9 @@ def _paged_reference(arena, seg, cursors, tables, max_seq):
     return out
 
 
-@pytest.mark.parametrize("interpret", [True])
-def test_block_update_matches_reference(interpret):
-    """Pallas block-update kernel == XLA scatter reference == numpy oracle,
-    over random cursors and a shuffled (non-identity) block table."""
+def test_block_update_matches_reference():
+    """The XLA scatter == the numpy oracle, over random cursors and a
+    shuffled (non-identity) block table."""
     S, MB, bt, H, D = 5, 4, 8, 2, 4
     max_seq = MB * bt
     n_blocks = S * MB
@@ -182,22 +74,16 @@ def test_block_update_matches_reference(interpret):
     cursors = rng.integers(0, max_seq, S).astype(np.int32)
     perm = rng.permutation(n_blocks)[: S * MB].reshape(S, MB).astype(np.int32)
     want = _paged_reference(arena_np, new_np[:, None], cursors, perm, max_seq)
-    out_k = kv_block_update(jnp.asarray(arena_np), jnp.asarray(new_np),
-                            jnp.asarray(cursors), jnp.asarray(perm),
-                            max_seq=max_seq, interpret=interpret)
-    np.testing.assert_array_equal(np.asarray(out_k), want)
-    out_r = kv_block_update_ref(jnp.asarray(arena_np),
-                                jnp.asarray(new_np)[:, None],
-                                jnp.asarray(cursors), jnp.asarray(perm),
-                                max_seq=max_seq)
-    np.testing.assert_array_equal(np.asarray(out_r), want)
+    out = kv_block_update(jnp.asarray(arena_np), jnp.asarray(new_np)[:, None],
+                          jnp.asarray(cursors), jnp.asarray(perm),
+                          max_seq=max_seq)
+    np.testing.assert_array_equal(np.asarray(out), want)
 
 
 def test_block_update_out_of_range_writes_only_trash():
-    """Cursors at/past max_seq: the kernel leaves EVERY real block
-    untouched (same no-op contract as kv_row_update); the scatter
-    reference redirects the write into the trash row — either way no real
-    data can be corrupted by a retired/idle row stepping past its end."""
+    """Cursors at/past max_seq: the scatter redirects the write into the
+    trash row, so no real data can be corrupted by a retired/idle row
+    stepping past its end."""
     S, MB, bt, H, D = 3, 2, 4, 2, 4
     max_seq = MB * bt
     n_blocks = S * MB
@@ -205,18 +91,13 @@ def test_block_update_out_of_range_writes_only_trash():
     new = jnp.ones((S, H, D), jnp.float32)
     tables = jnp.arange(S * MB, dtype=jnp.int32).reshape(S, MB)
     cursors = jnp.asarray([max_seq, max_seq + 3, 1], jnp.int32)
-    for out in (
-        kv_block_update(arena, new, cursors, tables, max_seq=max_seq,
-                        interpret=True),
-        kv_block_update_ref(arena, new[:, None], cursors, tables,
-                            max_seq=max_seq),
-    ):
-        out = np.asarray(out)
-        assert out[tables[2, 0], 1].all()          # in-range row wrote
-        assert out[: n_blocks].sum() == H * D      # ...and ONLY that row
+    out = np.asarray(kv_block_update(arena, new[:, None], cursors, tables,
+                                     max_seq=max_seq))
+    assert out[tables[2, 0], 1].all()          # in-range row wrote
+    assert out[: n_blocks].sum() == H * D      # ...and ONLY that row
     # multi-token segment straddling max_seq: the tail goes to trash
     seg = jnp.ones((1, 3, H, D), jnp.float32)
-    out = np.asarray(kv_block_update_ref(
+    out = np.asarray(kv_block_update(
         arena, seg, jnp.asarray([max_seq - 1], jnp.int32), tables[:1],
         max_seq=max_seq))
     assert out[: n_blocks].sum() == H * D          # one real write
@@ -312,9 +193,11 @@ def test_quantize_is_deterministic_across_jit_contexts():
     np.testing.assert_allclose(np.asarray(s0), np.asarray(s1), rtol=1e-6)
 
 
-@pytest.mark.parametrize("interpret", [True])
-def test_block_update_quant_matches_quantize_then_scatter(interpret):
-    from kubeflow_tpu.ops.kv_cache import kv_block_update_quant, quantize_kv
+def test_quantized_write_puts_code_and_scale_at_the_same_mapped_position():
+    """The int8 arena's write as ``GptAttention`` makes it: quantize, then
+    the same scatter through the same table for the codes and for the
+    scales; a row past ``max_seq`` touches no real block of either."""
+    from kubeflow_tpu.ops.kv_cache import quantize_kv
 
     S, MB, block_t, H, D = 3, 4, 4, 2, 8
     N = S * MB + 1  # one arena block per table entry + the trash row
@@ -325,20 +208,20 @@ def test_block_update_quant_matches_quantize_then_scatter(interpret):
     new = jnp.asarray(rng.normal(size=(S, H, D)).astype(np.float32))
     cursors = jnp.asarray([0, 5, max_seq], jnp.int32)  # last row out of range
     tables = jnp.asarray(np.arange(S * MB).reshape(S, MB), jnp.int32)
-    got_q, got_s = kv_block_update_quant(arena, scales, new, cursors, tables,
-                                         max_seq=max_seq, interpret=interpret)
+    q, s = quantize_kv(new)
+    got_q = kv_block_update(arena, q[:, None], cursors, tables, max_seq=max_seq)
+    got_s = kv_block_update(scales, s[:, None], cursors, tables, max_seq=max_seq)
     want_q = np.array(arena, copy=True)
     want_s = np.array(scales, copy=True)
-    q, s = quantize_kv(new)
     for row in range(S):
         pos = int(cursors[row])
         if pos >= max_seq:
-            continue  # out-of-range rows are a no-op (retired slots)
+            continue  # out-of-range rows go to the trash row (N - 1)
         blk = int(tables[row, pos // block_t])
         want_q[blk, pos % block_t] = np.asarray(q[row])
         want_s[blk, pos % block_t] = np.asarray(s[row])
-    np.testing.assert_array_equal(np.asarray(got_q), want_q)
-    np.testing.assert_array_equal(np.asarray(got_s), want_s)
+    np.testing.assert_array_equal(np.asarray(got_q)[:N - 1], want_q[:N - 1])
+    np.testing.assert_array_equal(np.asarray(got_s)[:N - 1], want_s[:N - 1])
 
 
 # -- the decode view bounded to the granted columns — ISSUE 27 ----------------
@@ -349,7 +232,7 @@ def test_block_update_beyond_a_bounded_table_writes_only_trash():
     them — dead, or stepping past its budget — must write to trash, never
     through the clamped last column into a live block; rows inside the
     columns write as with the whole table."""
-    from kubeflow_tpu.ops.kv_cache import kv_block_update_quant
+    from kubeflow_tpu.ops.kv_cache import quantize_kv
 
     S, MB, bt, H, D = 3, 4, 4, 2, 4
     max_seq = MB * bt
@@ -359,19 +242,16 @@ def test_block_update_beyond_a_bounded_table_writes_only_trash():
     cursors = jnp.asarray([8, 13, 5], jnp.int32)    # two beyond, one inside
     new = jnp.ones((S, H, D), jnp.float32)
     arena = jnp.zeros((n_blocks + 1, bt, H, D), jnp.float32)
-    for out in (
-        kv_block_update(arena, new, cursors, bounded, max_seq=max_seq,
-                        interpret=True),
-        kv_block_update_ref(arena, new[:, None], cursors, bounded,
-                            max_seq=max_seq),
-    ):
-        out = np.asarray(out)
-        assert out[whole[2, 1], 1].all()            # the row inside wrote
-        assert out[:n_blocks].sum() == H * D        # ...and no other real block
-        assert out[n_blocks].sum() > 0              # the others went to trash
-    q, s = kv_block_update_quant(
-        jnp.zeros(arena.shape, jnp.int8), jnp.zeros(arena.shape[:3] + (1,)),
-        new, cursors, bounded, max_seq=max_seq, interpret=True)
+    out = np.asarray(kv_block_update(arena, new[:, None], cursors, bounded,
+                                     max_seq=max_seq))
+    assert out[whole[2, 1], 1].all()            # the row inside wrote
+    assert out[:n_blocks].sum() == H * D        # ...and no other real block
+    assert out[n_blocks].sum() > 0              # the others went to trash
+    codes, scales = quantize_kv(new)
+    q = kv_block_update(jnp.zeros(arena.shape, jnp.int8), codes[:, None],
+                        cursors, bounded, max_seq=max_seq)
+    s = kv_block_update(jnp.zeros(arena.shape[:3] + (1,)), scales[:, None],
+                        cursors, bounded, max_seq=max_seq)
     assert np.abs(np.asarray(q)[:n_blocks]).sum() == 127 * H * D
     assert np.asarray(s)[:n_blocks].sum() == pytest.approx(H / 127.0)
 
@@ -394,7 +274,7 @@ def test_paged_decode_bounded_table_matches_whole_table(seg_len, kv_dtype, dead_
     quant = kv_dtype == "int8"
     params = GptLM(cfg).init(jax.random.PRNGKey(0),
                              jnp.zeros((1, 4), jnp.int32))["params"]
-    model = GptLM(cfg, decode=True, per_slot=True, kv_kernel=False, paged=True,
+    model = GptLM(cfg, decode=True, per_slot=True, paged=True,
                   kv_blocks=n_blocks + 1, kv_block_t=bt, kv_dtype=kv_dtype)
     rng = np.random.default_rng(27)
     granted = [2, 3, 0 if dead_row else 1]       # blocks per row: a prefix

@@ -207,15 +207,15 @@ def test_staggered_arrivals_match_one_at_a_time_while_blocks_come_and_go(params)
         eng.close()
     eng = engine(params, slots=3)
     given_back = []
-    orig = eng._rings.alloc.give_back
-    eng._rings.alloc.give_back = lambda res, blk: (given_back.append(blk), orig(res, blk))[1]
+    orig = eng.kv.rings.alloc.give_back
+    eng.kv.rings.alloc.give_back = lambda res, blk: (given_back.append(blk), orig(res, blk))[1]
     try:
         futs = []
         for p, b in zip(prompts, budgets):
             futs.append(eng.submit(p, b))
             time.sleep(0.05)
         together = [f.result(timeout=600) for f in futs]
-        assert eng._rings.used() == 0 and eng._alloc.used() == 0
+        assert eng.kv.rings.used() == 0 and eng.kv.alloc.used() == 0
     finally:
         eng.close()
     assert together == alone
@@ -368,17 +368,17 @@ def test_admission_reserves_both_kinds_and_waits_for_a_ring(params):
     (back-pressure, not an error) until a ring comes back with its slot;
     all finish, and both free lists are whole afterwards."""
     eng = engine(params, slots=2)
-    assert eng._rings.cols == 4 and eng._rings.alloc.n_blocks == 8
+    assert eng.kv.rings.cols == 4 and eng.kv.rings.alloc.n_blocks == 8
     room = []
-    reserve = eng._rings.reserve
-    eng._rings.reserve = lambda: (room.append(eng._rings.alloc.available()), reserve())[1]
+    reserve = eng.kv.rings.reserve
+    eng.kv.rings.reserve = lambda: (room.append(eng.kv.rings.alloc.available()), reserve())[1]
     try:
         futs = [eng.submit(prompt(1, 20), 16), eng.submit(prompt(2, 9), 8),
                 eng.submit(prompt(3, 13), 6)]
         assert [len(f.result(timeout=600)) for f in futs] == [16, 8, 6]
-        assert eng._rings.used() == 0 and eng._alloc.used() == 0
-        assert eng._rings.alloc.available() == 8
-        full = eng._alloc.blocks_for(20 + 16)
+        assert eng.kv.rings.used() == 0 and eng.kv.alloc.used() == 0
+        assert eng.kv.rings.alloc.available() == 8
+        full = eng.kv.alloc.blocks_for(20 + 16)
         assert full == 9                                         # ceil((prompt + budget) / 4)
     finally:
         eng.close()
@@ -396,10 +396,10 @@ def test_the_window_arena_is_a_whole_ring_for_every_slot(params, slots, chunk, b
     more in every window layer's arenas on the device."""
     eng = engine(params, slots=slots, chunk=chunk, kv_block_t=bt)
     try:
-        assert eng._rings.cols == cols == -(-(CFG.window + chunk - 1) // bt) + 1
-        assert eng._rings.alloc.n_blocks == slots * cols
+        assert eng.kv.rings.cols == cols == -(-(CFG.window + chunk - 1) // bt) + 1
+        assert eng.kv.rings.alloc.n_blocks == slots * cols
         shapes = {leaf.shape[0] for leaf in jax.tree.leaves(eng.cache) if leaf.ndim == 3}
-        assert shapes == {eng._alloc.n_blocks + 1, slots * cols + 1}
+        assert shapes == {eng.kv.alloc.n_blocks + 1, slots * cols + 1}
     finally:
         eng.close()
 
@@ -515,12 +515,12 @@ def test_gpt_device_programs_are_what_the_parent_engine_lowered(gpt_params, prog
     arguments: the refactor into families moved no instruction."""
     eng = ContinuousBatcher(GPT, gpt_params, slots=2, chunk=2, kv_block_t=16)
     try:
-        n_blocks, bt = eng._alloc.n_blocks, eng.kv_block_t
+        n_blocks, bt = eng.kv.alloc.n_blocks, eng.kv_block_t
         if program == "decode":
-            model = GptLM(GPT, decode=True, per_slot=True, kv_kernel=None, paged=True,
+            model = GptLM(GPT, decode=True, per_slot=True, paged=True,
                           kv_blocks=n_blocks + 1, kv_block_t=bt, kv_dtype="bf16")
             args = (eng.params, eng.cache, eng.last_tok, eng.temps, eng.rngs,
-                    jnp.asarray(eng._tables))
+                    jnp.asarray(eng.kv.tables))
             ours, theirs = eng._step_fn.lower(*args), _parent_step(model, 2).lower(*args)
         else:
             model = GptLM(GPT, decode=True)
@@ -542,3 +542,32 @@ def test_gpt_device_programs_are_what_the_parent_engine_lowered(gpt_params, prog
     finally:
         eng.close()
     assert ours.as_text() == theirs.as_text()
+
+
+@pytest.mark.parametrize("family", ["gpt", "mimo", "gpt_contiguous"])
+def test_the_engine_keeps_the_names_the_benchmark_reads(params, gpt_params, family):
+    """``benchmark/runners/{gpt,mimo}_serve.py`` read the engine by name:
+    ``params`` (and assign it), ``prefill_chunk``, ``prewarm``,
+    ``_group_pad``, ``kv_block_t``, ``chunk``, ``engine_id``. Their values
+    are the constructor's, whoever owns the slots' KV; and the constructor
+    takes sixteen arguments beside the configuration and its weights."""
+    import inspect
+
+    cfg, tree, kw, block_t, prefill_chunk = {
+        "gpt": (GPT, gpt_params, {"kv_block_t": 16}, 16, 128),
+        "mimo": (CFG, params, {"kv_block_t": 4, "prefill_chunk": 16}, 4, 16),
+        "gpt_contiguous": (GPT, gpt_params, {"paged": False}, 0, 128),
+    }[family]
+    eng = ContinuousBatcher(cfg, tree, slots=3, chunk=2, engine_id="names", **kw)
+    try:
+        assert eng.params is tree
+        assert (eng.prefill_chunk, eng._group_pad, eng.kv_block_t, eng.chunk,
+                eng.engine_id) == (prefill_chunk, 3, block_t, 2, "names")
+        assert eng.kv.block_t == eng.kv_block_t
+        eng.params = tree                      # the runners hand it fresh weights
+        assert list(inspect.signature(eng.prewarm).parameters)[:2] == [
+            "prompt_len", "group_sizes"]
+    finally:
+        eng.close()
+    arguments = list(inspect.signature(ContinuousBatcher.__init__).parameters)
+    assert arguments[:3] == ["self", "cfg", "params"] and len(arguments[3:]) == 16

@@ -228,8 +228,8 @@ class TestAutoTiling:
 
 class TestFusedBottleneck:
     """Parity of the fused bottleneck kernel (ops/fused_bottleneck.py)
-    against the XLA composite of the same math
-    (e2e/fused_bottleneck_probe.py is its on-chip probe)."""
+    against the XLA composite of the same math (on the chip the kernels
+    compile in tests/test_chip_compile.py and run in chip_smoke.py)."""
 
     def test_parity_vs_xla_composite(self):
         import numpy as np
