@@ -62,10 +62,11 @@ def sizes() -> Dict[str, Any]:
         # hpo: trials through the real StudyJob controller
         "hpo_trials": 2,
         # four chips: GPT-medium widths and depth. The one-device run it is
-        # compared with is what binds: f32, no remat, materialized scores
-        # compile to 14.05 of 15.75 GiB there at one microbatch of two
-        # sequences (4.8 GiB a device on the 2x2 mesh); two microbatches
-        # need 21 GiB at 8 layers already.
+        # compared with is what bound when these sizes were chosen: f32,
+        # materialized scores and every intermediate of a block kept
+        # compiled to 14.05 of 15.75 GiB there at one microbatch of two
+        # sequences (4.8 GiB a device on the 2x2 mesh). Since the block's
+        # checkpoint policy (composite._remat) both need far less.
         "composite": CompositeConfig(vocab_size=32000, d_model=1024,
                                      n_heads=16, d_ff=4096, n_layers=24,
                                      seq=1024),

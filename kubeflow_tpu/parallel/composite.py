@@ -42,6 +42,7 @@ DIFFERENT mesh factorization is exercised in ``__graft_entry__``
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict
 
@@ -49,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .mesh import AXIS_FSDP, AXIS_MODEL, AXIS_PIPE, BATCH_AXES
@@ -212,6 +214,24 @@ def _gather_layer(wqkv_l, wo_l, w1_l, w2_l):
     )
 
 
+#: What the backward pass keeps of a block besides its arguments (``h`` and
+#: the gathered weights: ``jax.checkpoint`` keeps what comes in): the matmul
+#: outputs and both ``psum`` results. Scores, mask, softmax, the GELU's and
+#: LayerNorm's pieces are recomputed from these in the backward, so no
+#: [heads, seq, seq] buffer is stacked over the layers, and no collective
+#: runs twice.
+SAVED_IN_BLOCK = ("qkv", "ctx", "attn_out", "pre", "mlp_out")
+
+
+def _remat(block):
+    """``block`` under :data:`SAVED_IN_BLOCK`'s policy. ``_stage_fn`` hands
+    it a fresh callable every trace: ``jax.checkpoint`` keeps a traced
+    function by identity, and a step built after this module's ``lax`` was
+    swapped (the benchmark's left-out-exchange fault) must trace anew."""
+    return jax.checkpoint(
+        block, policy=jax.checkpoint_policies.save_only_these_names(*SAVED_IN_BLOCK))
+
+
 def _block(cfg: CompositeConfig, h, ln1, ln2, wqkv, wo, w1, w2):
     """One transformer block, weights fully gathered over fsdp (still
     tp-local): Megatron column/row splits with one psum per sublayer."""
@@ -224,7 +244,8 @@ def _block(cfg: CompositeConfig, h, ln1, ln2, wqkv, wo, w1, w2):
     # attention: column-split QKV -> local heads; causal; row-split WO
     with jax.named_scope("attn"):
         x = ln(h, ln1)
-        qkv = jnp.einsum("bsd,drh->bsrh", x, wqkv)       # [mb, s, 3, d/tp]
+        qkv = checkpoint_name(
+            jnp.einsum("bsd,drh->bsrh", x, wqkv), "qkv")  # [mb, s, 3, d/tp]
         dl = qkv.shape[-1]                               # d/tp local width
         q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
         hd = cfg.d_model // cfg.n_heads
@@ -237,13 +258,14 @@ def _block(cfg: CompositeConfig, h, ln1, ln2, wqkv, wo, w1, w2):
         mask = jnp.tril(jnp.ones((s, s), bool))
         scores = jnp.where(mask, scores, -1e30)
         attn = jax.nn.softmax(scores, axis=-1) @ v       # [mb, nh, s, hd]
-        attn = attn.transpose(0, 2, 1, 3).reshape(mb, s, dl)
+        attn = checkpoint_name(attn.transpose(0, 2, 1, 3).reshape(mb, s, dl), "ctx")
         # row-split output proj: partial sums reduced over the model axis
-        h = h + lax.psum(attn @ wo, AXIS_MODEL)
+        h = h + checkpoint_name(lax.psum(attn @ wo, AXIS_MODEL), "attn_out")
     # mlp: column-split W1 (no comm), row-split W2 (+psum)
     with jax.named_scope("mlp"):
         x = ln(h, ln2)
-        h = h + lax.psum(jax.nn.gelu(x @ w1) @ w2, AXIS_MODEL)
+        pre = checkpoint_name(x @ w1, "pre")
+        h = h + checkpoint_name(lax.psum(jax.nn.gelu(pre) @ w2, AXIS_MODEL), "mlp_out")
     return h
 
 
@@ -265,6 +287,7 @@ def _stage_fn(
     """
     lns = (p["ln1_scale"], p["ln2_scale"])
     ws = (p["wqkv"], p["wo"], p["w1"], p["w2"])
+    run_block = _remat(functools.partial(_block, cfg))
 
     if gather_mode == "overlap":
         lpc = p["ln1_scale"].shape[0]
@@ -285,7 +308,7 @@ def _stage_fn(
             ln1, ln2 = (
                 lax.dynamic_index_in_dim(s, i, keepdims=False) for s in lns
             )
-            h = _block(cfg, h, ln1, ln2, *g)
+            h = run_block(h, ln1, ln2, *g)
             return (h, g_next), None
 
         (h, _), _ = lax.scan(body, (h, gather_at(0)), jnp.arange(lpc))
@@ -297,7 +320,7 @@ def _stage_fn(
             wqkv, wo, w1, w2 = wqkv_l, wo_l, w1_l, w2_l
         else:  # eager: gather the weight shard right before use (ZeRO-3)
             wqkv, wo, w1, w2 = _gather_layer(wqkv_l, wo_l, w1_l, w2_l)
-        return _block(cfg, h, ln1, ln2, wqkv, wo, w1, w2), None
+        return run_block(h, ln1, ln2, wqkv, wo, w1, w2), None
 
     h, _ = lax.scan(block, h, lns + ws)
     return h
