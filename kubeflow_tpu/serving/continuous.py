@@ -304,12 +304,12 @@ class ContinuousBatcher:
         """``cfg`` names the model family by its type (``serving/family.py``):
         the engine builds no model itself, and it keeps no account of a
         slot's KV: ``self.kv`` (``paged.SlotKV``) owns the block table, the
-        reservations, the cursor bounds and the retire order. A family with
-        window-attention layers keeps a second kind of paged cache beside
-        the block table (``paged.WindowRings``: a ring of blocks a slot,
-        given back as the cursor leaves them behind), sized from ``slots``,
-        the window, ``kv_block_t`` and ``chunk``: two kinds of cache add no
-        knob.
+        reservations, the cursor bounds and the retire order. A family may
+        keep a second kind of paged cache beside the block table, a ring of
+        blocks a slot (``paged.WindowRings``: given back as the cursor
+        leaves them behind; ``paged.AlignedWindows``: a whole window at its
+        end), sized from ``slots``, the window, ``kv_block_t`` and
+        ``chunk``: two kinds of cache add no knob.
 
         New ISSUE-12 knobs (defaults keep every pre-existing behavior):
 
@@ -407,22 +407,20 @@ class ContinuousBatcher:
         # -- paged KV layout (ISSUE 12) ------------------------------------
         if paged:
             self.kv_block_t = _block_tile(cfg.max_seq, kv_block_t)
-            n_blocks = (int(kv_blocks) if kv_blocks
-                        else slots * (cfg.max_seq // self.kv_block_t))
         else:
-            self.kv_block_t = n_blocks = 0
+            self.kv_block_t = 0
+        # kv_blocks 0: the family's default, a whole row of the table a slot
         self.family = family_for(
-            cfg, slots=slots, paged=bool(paged), kv_blocks=n_blocks,
+            cfg, slots=slots, paged=bool(paged), kv_blocks=int(kv_blocks or 0) if paged else 0,
             kv_block_t=self.kv_block_t, kv_dtype=self.kv_dtype)
-        # the one owner of every slot's KV on the host. A family with window
-        # layers keeps the window kind of cache beside the block table (a
-        # dispatch moves a cursor by up to ``chunk`` positions) and prefills
-        # every prompt in chunks straight into the arenas (no private cache,
-        # no adopt): both are asked of ``self.family.window``.
-        self.kv = (SlotKV(slots, cfg.max_seq, self.kv_block_t, n_blocks,
+        # the one owner of every slot's KV on the host. A family may keep a
+        # second kind of cache, a ring a slot beside the block table (a
+        # dispatch moves a cursor by up to ``chunk`` positions), and a row
+        # of its table may stand for more than one position.
+        self.kv = (SlotKV(slots, cfg.max_seq, self.kv_block_t, self.family.kv_blocks,
                           engine_id=self.engine_id,
-                          rings=(self.family.rings(self.chunk, self.engine_id)
-                                 if self.family.window else None))
+                          rings=self.family.rings(self.chunk, self.engine_id),
+                          stride=self.family.kv_stride)
                    if paged else ContiguousKV())
         # every view width's decode program is compiled once, at the first
         # prewarm: "no" -> "asked" (prewarm) -> "done" (the engine thread,
@@ -450,7 +448,7 @@ class ContinuousBatcher:
             # the draft stays contiguous: it is small by construction, so
             # the paged arena's memory win does not apply to it
             self._draft_family = family_for(draft_cfg, slots=slots)
-        if self.family.window:
+        if self.family.prefills_in_arena:
             # a family that prefills into the arenas has no private cache
             # to adopt, to ship or to verify drafts against
             if self.spec_k or self.role != "unified":
@@ -489,7 +487,7 @@ class ContinuousBatcher:
         #: wire-format KV imports awaiting a slot (decode role, ISSUE 18)
         self._imports: "collections.deque[_Import]" = collections.deque()
         self._step_fn = self.family.build_step(self.chunk)
-        batched = not self.family.window
+        batched = not self.family.prefills_in_arena
         self._adopt_fn = self.family.build_adopt() if batched else None
         self._import_fn = (self.family.build_import()
                            if batched and paged else None)
@@ -847,7 +845,7 @@ class ContinuousBatcher:
             self._rng_counter += 1
             key = jax.random.fold_in(self._base_rng, self._rng_counter)
             if self.prefill_chunk and (len(req.prompt) > self.prefill_chunk
-                                       or self.family.window):
+                                       or self.family.prefills_in_arena):
                 # long prompt (or a family that prefills every prompt in
                 # chunks) → chunked prefill. One in flight at a time:
                 # it holds a slot from its first chunk, and serializing
@@ -1095,7 +1093,7 @@ class ContinuousBatcher:
             return True
         slot = self._free.pop()
         self.kv.hold(slot, res)
-        in_arena = bool(self.family.window)    # no private cache, then
+        in_arena = self.family.prefills_in_arena    # no private cache, then
         self._chunked = _ChunkedPrefill(
             req=req, slot=slot, key=key, res=res,
             cache=None if in_arena else self.family.prefill_cache(1),
@@ -1136,7 +1134,7 @@ class ContinuousBatcher:
             _fail(req, DeadlineExceeded(
                 "deadline expired during chunked prefill"))
             return []
-        if self.family.window:
+        if self.family.prefills_in_arena:
             return self._advance_in_arena(cp)
         if self._chunk_prefill_fn is None:
             self._chunk_prefill_fn = self._build_chunk_prefill()
@@ -1545,7 +1543,7 @@ class ContinuousBatcher:
         kind, dev, meta, dispatched_at = event
         widths = stats = None
         if self.family.has_stats:
-            # a family with expert layers: their counters ride with the tokens
+            # the family's counters ride with the tokens
             dev, stats = dev
         with profiling.annotate("serving.engine.fetch", kind=kind):
             if kind == "spec":
@@ -1577,21 +1575,12 @@ class ContinuousBatcher:
                     meta, block, widths, now)
             span.set_metadata(tokens=tokens, retired=retired)
             if stats is not None:
-                # assignments of the event's live tokens (a decode chunk's
-                # rows, or the prompt a first token closes) that landed on
-                # the experts held here, the busiest held expert's, and how
-                # many held experts saw a token (summed over layers, steps)
-                on_held, busiest, touched = (int(v) for v in stats)
-                routed = self.family.routed(
+                # over the event's live tokens: a decode chunk's rows, or
+                # the prompt a first token closes
+                span.set_metadata(**self.family.account(
+                    [int(v) for v in stats],
                     sum(len(r.prompt) for r, _ in meta) if kind == "first"
-                    else len(meta) * block.shape[1])
-                span.set_metadata(expert_tokens=on_held,
-                                  expert_tokens_max=busiest,
-                                  experts_touched=touched)
-                METRICS.counter("serving_moe_assignments_total",
-                                held="true").inc(on_held)
-                METRICS.counter("serving_moe_assignments_total",
-                                held="false").inc(max(routed - on_held, 0))
+                    else len(meta) * block.shape[1]))
 
     def _deliver_first(self, pairs, block, now: float) -> Tuple[int, int]:
         """An admission group's first tokens to their requests. Returns
@@ -1777,7 +1766,7 @@ class ContinuousBatcher:
                 with profiling.annotate("serving.engine.import"):
                     events.extend(self._admit_imports())
                 dispatched = True
-            batched = not self.family.window
+            batched = not self.family.prefills_in_arena
             if (self._free and self._pending and not self._draining
                     and (batched or self._chunked is None)):
                 # a family that prefills every prompt in the one chunked

@@ -18,6 +18,20 @@ kinds of paged cache side by side (``paged.WindowRings`` beside the block
 table), every prompt prefilled in fixed-size chunks that write straight
 into the arenas, and an expert layer's counters riding home with the
 tokens. It has no private prefill cache, so nothing of it is adopted.
+
+``EvaFamily`` (``EvaConfig``): EVA attention, so again two kinds of paged
+cache but other ones: the LOCAL kind holds a slot's current aligned window
+only (``paged.AlignedWindows``), the SUMMARY kind a row a chunk of every
+window the slot has left (the block table at a stride). Prompts prefill in
+chunks straight into the arenas, as the MiMo family's.
+
+What the engine asks of a family, beside its programs: ``rings(lookahead,
+engine_id)`` (the kind of cache that keeps a ring a slot beside the block
+table, or None), ``kv_stride`` (positions a row of the block table stands
+for), ``prefills_in_arena`` (every prompt in chunks straight into the
+arenas: no private cache, no adopt, no speculation or hand-off roles),
+``has_stats`` (the step and the prefill return three counters beside the
+tokens, which ``account`` reads).
 """
 
 from __future__ import annotations
@@ -28,10 +42,12 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..models.evabyte import EvaConfig
 from ..models.gpt import GptConfig, GptLM
 from ..models.mimo import FULL, WINDOW, MimoConfig
-from ..models import mimo
-from .paged import WindowRings
+from ..models import evabyte, mimo
+from ..runtime.metrics import METRICS
+from .paged import AlignedWindows, WindowRings
 
 
 def sample_next(lg: jax.Array, temps: jax.Array, rngs: jax.Array):
@@ -64,18 +80,19 @@ class GptFamily:
     """``GptLM`` in the engine: the per-slot decode model, the scalar-cursor
     prefill model, their caches and the programs that move cache leaves."""
 
-    #: how far back window layers read. 0: none, so one kind of cache, and
-    #: prompts prefilled in batches by bucket on a private cache, then
-    #: adopted. Over 0: a ring of blocks a slot beside the block table
-    #: (``rings``), and every prompt in chunks straight into the arenas.
-    window = 0
-    #: the step and the prefill return expert counters beside the tokens
+    #: prompts prefill in batches by bucket on a private cache, then adopt
+    prefills_in_arena = False
+    #: the step and the prefill return counters beside the tokens
     has_stats = False
+    #: a row of the block table is a position
+    kv_stride = 1
 
     def __init__(self, cfg: GptConfig, *, slots: int, paged: bool = False,
                  kv_blocks: int = 0, kv_block_t: int = 16,
                  kv_dtype: str = "bf16"):
         self.cfg, self.slots, self.paged = cfg, slots, paged
+        if paged and not kv_blocks:
+            kv_blocks = slots * (cfg.max_seq // kv_block_t)      # a whole row a slot
         self.kv_blocks, self.kv_block_t, self.kv_dtype = kv_blocks, kv_block_t, kv_dtype
         if paged:
             self.model = GptLM(cfg, decode=True, per_slot=True, paged=True,
@@ -85,6 +102,10 @@ class GptFamily:
         else:
             self.model = GptLM(cfg, decode=True, per_slot=True)
         self.prefill_model = GptLM(cfg, decode=True)  # [1, P], scalar cursor
+
+    def rings(self, lookahead: int, engine_id: str = "0") -> None:
+        """One kind of cache: no ring beside the block table."""
+        return None
 
     # -- caches ----------------------------------------------------------------
     def fresh_cache(self) -> Dict[str, Any]:
@@ -296,17 +317,16 @@ class MimoFamily:
     prefilled chunk by chunk straight into the arenas, expert counters."""
 
     has_stats = True
+    prefills_in_arena = True
+    kv_stride = 1
 
     def __init__(self, cfg: MimoConfig, *, slots: int, paged: bool = True,
                  kv_blocks: int = 0, kv_block_t: int = 16,
                  kv_dtype: str = "bf16"):
-        if not paged:
-            raise ValueError("this model family keeps two kinds of cache: "
-                             "it needs the paged layout (paged=True)")
-        if kv_dtype != "bf16":
-            raise ValueError("this model family has no int8 arenas yet")
+        _two_kinds_only(paged, kv_dtype)
         self.cfg, self.slots, self.window = cfg, slots, int(cfg.window)
-        self.kv_blocks, self.kv_block_t = kv_blocks, kv_block_t
+        self.kv_blocks = kv_blocks or slots * (cfg.max_seq // kv_block_t)
+        self.kv_block_t = kv_block_t
 
     def rings(self, lookahead: int, engine_id: str = "0") -> WindowRings:
         """The window kind's accounting, a whole ring for every slot, for
@@ -323,31 +343,28 @@ class MimoFamily:
         """Token-to-expert assignments of ``tokens`` tokens, all layers."""
         return tokens * self.cfg.experts_per_token * sum(self.cfg.moe_layers)
 
+    def account(self, stats, tokens: int) -> Dict[str, int]:
+        """An event's three counters into the program's own and the
+        ``serving.engine.deliver`` region's stats: the assignments of the
+        event's ``tokens`` live tokens (a decode chunk's rows, or the prompt
+        a first token closes) that landed on the experts held here, the
+        busiest held expert's, and how many held experts saw a token
+        (summed over layers and steps)."""
+        on_held, busiest, touched = stats
+        METRICS.counter("serving_moe_assignments_total", held="true").inc(on_held)
+        METRICS.counter("serving_moe_assignments_total", held="false").inc(
+            max(self.routed(tokens) - on_held, 0))
+        return {"expert_tokens": on_held, "expert_tokens_max": busiest,
+                "experts_touched": touched}
+
     def fresh_cache(self) -> Dict[str, Any]:
         return mimo.fresh_cache(self.cfg, self.slots,
                                 {FULL: self.kv_blocks, WINDOW: self.window_blocks},
                                 self.kv_block_t)
 
     def build_step(self, chunk: int):
-        cfg, trash = self.cfg, self.trash
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 4))
-        def step(params, cache, tok, temps, rngs, full_table, window_table, live):
-            """``chunk`` tokens for every slot, like the other family's
-            step, and the expert layers' counters of the whole chunk."""
-            def one(carry, _):
-                cache, tok, rngs, stats = carry
-                logits, cache, st = mimo.decode_step(
-                    cfg, params, cache, tok, full_table, window_table, live, trash)
-                with jax.named_scope("sample"):
-                    nxt, rngs = sample_next(logits, temps, rngs)
-                return (cache, nxt, rngs, stats + st), nxt
-
-            (cache, tok, rngs, stats), toks = jax.lax.scan(
-                one, (cache, tok, rngs, jnp.zeros((3,), jnp.int32)), None, length=chunk)
-            return cache, tok, rngs, jnp.moveaxis(toks, 0, 1), stats
-
-        return step
+        return _build_counted_step(
+            functools.partial(mimo.decode_step, self.cfg, trash=self.trash), chunk)
 
     def build_chunk_prefill(self):
         cfg = self.cfg
@@ -358,29 +375,128 @@ class MimoFamily:
             logits, cache, stats = mimo.prefill_chunk(
                 cfg, params, cache, ids, start, n_valid,
                 read_full, write_full, read_window, write_window)
-            # only the LAST chunk's token is read
-            greedy = jnp.argmax(logits).astype(jnp.int32)
-            sampled = jax.random.categorical(
-                key, logits / jnp.maximum(temperature, 1e-6)).astype(jnp.int32)
-            return cache, jnp.where(temperature > 0.0, sampled, greedy), stats
+            return cache, _sample_first(logits, temperature, key), stats
 
         return prefill_chunk
 
     def build_activate(self):
-        @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-        def activate(cache, last_tok, temps, rngs, slot, true_len, first_tok,
-                     temperature, key):
-            """A prefilled row joins the decode batch: its cursor, its
-            first token and its sampling state."""
-            cache = dict(cache, cursors=cache["cursors"].at[slot].set(true_len))
-            return (cache, last_tok.at[slot].set(first_tok),
-                    temps.at[slot].set(temperature), rngs.at[slot].set(key))
+        return _build_activate()
 
-        return activate
+
+def _build_counted_step(decode_step, chunk: int):
+    """The decode program of a family whose step returns three counters
+    beside the logits: ``chunk`` tokens for every slot, like the GPT
+    family's step, and the counters of the whole chunk.
+    ``decode_step(params, cache, tok, table, ring_table, live)``."""
+    @functools.partial(jax.jit, donate_argnums=(1, 2, 4))
+    def step(params, cache, tok, temps, rngs, table, ring_table, live):
+        def one(carry, _):
+            cache, tok, rngs, stats = carry
+            logits, cache, st = decode_step(params, cache, tok, table, ring_table, live)
+            with jax.named_scope("sample"):
+                nxt, rngs = sample_next(logits, temps, rngs)
+            return (cache, nxt, rngs, stats + st), nxt
+
+        (cache, tok, rngs, stats), toks = jax.lax.scan(
+            one, (cache, tok, rngs, jnp.zeros((3,), jnp.int32)), None, length=chunk)
+        return cache, tok, rngs, jnp.moveaxis(toks, 0, 1), stats
+
+    return step
+
+
+def _two_kinds_only(paged: bool, kv_dtype: str) -> None:
+    if not paged:
+        raise ValueError("this model family keeps two kinds of cache: "
+                         "it needs the paged layout (paged=True)")
+    if kv_dtype != "bf16":
+        raise ValueError("this model family has no int8 arenas yet")
+
+
+def _sample_first(logits, temperature, key):
+    """The token a prompt's last position yields (only the LAST chunk's is
+    read)."""
+    greedy = jnp.argmax(logits).astype(jnp.int32)
+    sampled = jax.random.categorical(
+        key, logits / jnp.maximum(temperature, 1e-6)).astype(jnp.int32)
+    return jnp.where(temperature > 0.0, sampled, greedy)
+
+
+def _build_activate():
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+    def activate(cache, last_tok, temps, rngs, slot, true_len, first_tok,
+                 temperature, key):
+        """A prefilled row joins the decode batch: its cursor, its
+        first token and its sampling state."""
+        cache = dict(cache, cursors=cache["cursors"].at[slot].set(true_len))
+        return (cache, last_tok.at[slot].set(first_tok),
+                temps.at[slot].set(temperature), rngs.at[slot].set(key))
+
+    return activate
+
+
+class EvaFamily:
+    """``models/evabyte.py`` in the engine: the local and the summary kind
+    of paged cache, prompts prefilled chunk by chunk straight into the
+    arenas, a count of the summaries written riding home with the tokens."""
+
+    has_stats = True
+    prefills_in_arena = True
+
+    def __init__(self, cfg: EvaConfig, *, slots: int, paged: bool = True,
+                 kv_blocks: int = 0, kv_block_t: int = 16,
+                 kv_dtype: str = "bf16"):
+        _two_kinds_only(paged, kv_dtype)
+        self.cfg, self.slots, self.kv_block_t = cfg, slots, kv_block_t
+        #: a row of the block table is a chunk's summary
+        self.kv_stride = int(cfg.chunk_size)
+        self.kv_blocks = kv_blocks or slots * (
+            cfg.max_seq // (kv_block_t * self.kv_stride))
+
+    def rings(self, lookahead: int, engine_id: str = "0") -> AlignedWindows:
+        """The local kind's accounting, a whole ring for every slot (its
+        arena's size is not the engine's knob: the cache and the step are
+        built after this)."""
+        made = AlignedWindows(self.slots, self.cfg.window, self.kv_block_t,
+                              lookahead, engine_id=engine_id)
+        self.local_blocks = made.alloc.n_blocks
+        return made
+
+    def account(self, stats, tokens: int) -> Dict[str, int]:
+        """An event's counters: the summaries its live rows (or the prompt
+        a first token closes) wrote, and the windows they completed."""
+        written, windows, _ = stats
+        METRICS.counter("serving_eva_windows_summarised_total").inc(windows)
+        return {"summaries_written": written}
+
+    def fresh_cache(self) -> Dict[str, Any]:
+        return evabyte.fresh_cache(self.cfg, self.slots, self.local_blocks,
+                                   self.kv_blocks, self.kv_block_t)
+
+    def build_step(self, chunk: int):
+        return _build_counted_step(
+            functools.partial(evabyte.decode_step, self.cfg, summary_trash=self.kv_blocks),
+            chunk)
+
+    def build_chunk_prefill(self):
+        cfg, trash = self.cfg, self.kv_blocks
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def prefill_chunk(params, cache, ids, start, n_valid, temperature, key,
+                          read_summary, read_local, write_local):
+            logits, cache, stats = evabyte.prefill_chunk(
+                cfg, params, cache, ids, start, n_valid, read_summary, read_local,
+                write_local, trash)
+            return cache, _sample_first(logits, temperature, key), stats
+
+        return prefill_chunk
+
+    def build_activate(self):
+        return _build_activate()
 
 
 _FAMILIES: Tuple[Tuple[type, type], ...] = ((GptConfig, GptFamily),
-                                            (MimoConfig, MimoFamily))
+                                            (MimoConfig, MimoFamily),
+                                            (EvaConfig, EvaFamily))
 
 
 def family_for(cfg: Any, **geometry: Any):

@@ -8,8 +8,10 @@ block) and a host-side per-slot block table mapping absolute positions to
 arena rows. This module owns the host-side half: the free-list allocator
 that reserves capacity at admission and grants physical blocks as cursors
 advance, published as ``serving_kv_blocks_{free,used}`` gauges so arena
-sizing is an observable capacity knob rather than a silent OOM; the window
-kind of cache (:class:`WindowRings`); and :class:`SlotKV`, the ONE owner of
+sizing is an observable capacity knob rather than a silent OOM; the kinds
+of cache that keep a ring of blocks a slot beside the block table
+(:class:`WindowRings`, a sliding window; :class:`AlignedWindows`, the
+current one of a row of aligned windows); and :class:`SlotKV`, the ONE owner of
 every slot's KV on the host, whose verbs the engine
 (``serving/continuous.py``) performs without knowing table, trash or rings.
 
@@ -97,9 +99,10 @@ class KVBlockAllocator:
 
     def __init__(self, n_blocks: int, block_t: int, *, engine_id: str = "0",
                  kind: str = ""):
-        """``kind`` ("full" / "window") labels the gauges of an engine that
-        keeps two kinds of cache side by side; an engine with one kind
-        leaves it empty and publishes the gauges it always did."""
+        """``kind`` ("full" / "window", "summary" / "local") labels the
+        gauges of an engine that keeps two kinds of cache side by side; an
+        engine with one kind leaves it empty and publishes the gauges it
+        always did."""
         if n_blocks <= 0:
             raise ValueError(f"need at least one KV block, got {n_blocks}")
         self.n_blocks = int(n_blocks)
@@ -215,18 +218,27 @@ class WindowRings:
     With one step a dispatch that is ``ceil(window / block_t) + 1``.
     """
 
+    kind = "window"
+
     def __init__(self, slots: int, window: int, block_t: int, lookahead: int,
                  *, engine_id: str = "0"):
         self.window, self.block_t = int(window), int(block_t)
-        self.cols = -(-(self.window + max(int(lookahead), 1) - 1) // self.block_t) + 1
+        self.cols = self._columns(max(int(lookahead), 1))
         # a whole ring for every slot: a slot can never hold more
         self.alloc = KVBlockAllocator(slots * self.cols, block_t,
-                                      engine_id=engine_id, kind="window")
+                                      engine_id=engine_id, kind=self.kind)
         self.trash = self.alloc.trash
         self.tables = np.full((slots, self.cols), self.trash, np.int32)
         self._res: Dict[int, KVReservation] = {}
         self._held: Dict[int, Dict[int, int]] = {}     # slot -> logical block -> id
         self._frontier = np.zeros((slots,), np.int64)  # positions a full kind would hold
+
+    def _columns(self, lookahead: int) -> int:
+        return -(-(self.window + lookahead - 1) // self.block_t) + 1
+
+    def _first_kept(self, cursor: int) -> int:
+        """The oldest logical block a step at ``cursor`` can still read."""
+        return max(0, cursor - self.window + 1) // self.block_t
 
     def reserve(self) -> KVReservation:
         """A slot's ring, promised at admission. The arena holds one for
@@ -244,11 +256,11 @@ class WindowRings:
     def advance(self, slot: int, cursor: int, frontier: int) -> List[int]:
         """Before a dispatch that starts with the slot's cursor at
         ``cursor`` and may write positions below ``frontier``: give back
-        the blocks wholly behind ``cursor - window + 1`` and grant those up
-        to the frontier. Returns the ids of the logical blocks
-        ``[first kept .. last]`` now held, oldest first."""
+        the blocks no step from ``cursor`` on can read (:meth:`_first_kept`)
+        and grant those up to the frontier. Returns the ids of the logical
+        blocks ``[first kept .. last]`` now held, oldest first."""
         res, held = self._res[slot], self._held[slot]
-        first = max(0, cursor - self.window + 1) // self.block_t
+        first = self._first_kept(cursor)
         last = (frontier - 1) // self.block_t
         for b in [b for b in held if b < first]:
             self.tables[slot, b % self.cols] = self.trash       # table first
@@ -278,15 +290,62 @@ class WindowRings:
         one per ``block_t`` positions of every attached row's frontier."""
         return int(sum(-(-int(self._frontier[s]) // self.block_t) for s in self._res))
 
+    def dispatch_stats(self, moves: Sequence[Tuple[int, int]]) -> Dict[str, int]:
+        """The ``serving.engine.dispatch`` region's stats of this kind, for
+        a dispatch that moves its live rows' cursors ``(from, to)``."""
+        return {"window_blocks": self.used(),
+                "window_blocks_unreleased": self.unreleased()}
+
+
+class AlignedWindows(WindowRings):
+    """The local kind of cache: windows are ALIGNED (position ``t`` lies in
+    window ``t // window``), not sliding, and a slot holds the blocks of its
+    CURRENT window only. All of a window's blocks go back at once, at the
+    first dispatch that starts past its end (table entries to trash first).
+    A dispatch that crosses the line in its middle holds both: its steps
+    before the line read the old window whole, those after it write the new
+    window's first blocks, and the ring has a column for each:
+
+    ``cols = window / block_t + ceil((lookahead - 1) / block_t)``: of a
+    dispatch's ``lookahead`` positions at most ``lookahead - 1`` lie past a
+    line that an earlier one lies before."""
+
+    kind = "local"
+
+    def _columns(self, lookahead: int) -> int:
+        if self.window % self.block_t:
+            raise ValueError(f"an aligned window of {self.window} is not whole "
+                             f"blocks of {self.block_t}")
+        return self.window // self.block_t + -(-(lookahead - 1) // self.block_t)
+
+    def _first_kept(self, cursor: int) -> int:
+        return cursor // self.window * (self.window // self.block_t)
+
+    def dispatch_stats(self, moves: Sequence[Tuple[int, int]]) -> Dict[str, int]:
+        """Blocks in use, the pages the dispatch's LAST step reads of each
+        live row's window, and the rows whose cursor crosses a window's end
+        in this dispatch."""
+        pages = lambda to: -(-((to - 1) % self.window + 1) // self.block_t)
+        return {"local_blocks": self.used(),
+                "local_blocks_read": sum(pages(to) for _, to in moves if to > 0),
+                "rollovers": sum(to // self.window > at // self.window for at, to in moves)}
+
 
 class SlotKV:
     """The one owner of every slot's KV on the host. Only this class knows
     the trash id, the table's layout (``[slots, max_blocks]``, ONE table for
     every layer of the full kind; entries default to trash, so unallocated
-    positions can never hit real data), the two kinds of blocks (the full
-    kind here, the window kind in ``rings`` for a family with window
-    layers), the widths a decode dispatch reads the table at, and the order
-    in which a slot gives everything back.
+    positions can never hit real data), the two kinds of blocks (the
+    append-only kind here, the kind that keeps a ring a slot in ``rings``
+    for a family that has one), the widths a decode dispatch reads the
+    table at, and the order in which a slot gives everything back.
+
+    ``stride``: positions a ROW of the append-only kind stands for. 1 is
+    the full kind (a row a position). Over 1 is the summary kind (a row a
+    whole chunk of ``stride`` positions, beside :class:`AlignedWindows`): a
+    row is WRITTEN when its chunk completes, so ``tokens`` positions need
+    ``tokens // stride`` rows, and is READ only once the cursor has left its
+    window, so what a step reads (:meth:`_rows_read`) lags what is written.
 
     A slot's life, in the engine's verbs: :meth:`check` at submit;
     :meth:`reserve` before any compute is spent; :meth:`hold` once the
@@ -300,13 +359,14 @@ class SlotKV:
 
     def __init__(self, slots: int, max_seq: int, block_t: int, n_blocks: int,
                  *, engine_id: str = "0",
-                 rings: Optional[WindowRings] = None):
+                 rings: Optional[WindowRings] = None, stride: int = 1):
         self.slots, self.max_seq, self.block_t = int(slots), int(max_seq), int(block_t)
-        self.engine_id = engine_id
+        self.engine_id, self.stride = engine_id, int(stride)
         self.rings = rings
+        self.kind = ("" if rings is None else "full" if self.stride == 1 else "summary")
         self.alloc = KVBlockAllocator(n_blocks, block_t, engine_id=engine_id,
-                                      kind="full" if rings is not None else "")
-        self.max_blocks = self.max_seq // self.block_t
+                                      kind=self.kind)
+        self.max_blocks = self.max_seq // (self.block_t * self.stride)
         self.tables = np.full((self.slots, self.max_blocks), self.alloc.trash,
                               np.int32)
         # jit specialises the decode program on each of these widths
@@ -316,27 +376,43 @@ class SlotKV:
         # — spec rounds advance the real cursor by a data-dependent amount,
         # so granting tracks the bound
         self._cursor = np.zeros((self.slots,), np.int64)
+        # where the latest dispatch found each cursor (its stats say how far
+        # it moves them)
+        self._origin = np.zeros((self.slots,), np.int64)
         # the table of a row that prefills straight into the arenas, while
         # it fills: the shared row stays on trash until bind, so decode
         # dispatches in between write nothing of this dead row into its blocks
         self._filling: Dict[int, np.ndarray] = {}
 
+    def blocks_for(self, tokens: int) -> int:
+        """Blocks of the append-only kind that ``tokens`` positions write."""
+        return self.alloc.blocks_for(int(tokens) // self.stride)
+
+    def _rows_read(self, cursor: int) -> int:
+        """Rows of the append-only kind that the step BEFORE ``cursor``
+        reads: every position up to its own, or the summaries of the
+        windows it has left."""
+        if self.stride == 1:
+            return cursor
+        window = self.rings.window
+        return max(cursor - 1, 0) // window * (window // self.stride)
+
     def check(self, tokens: int) -> None:
         """ValueError for a request of ``tokens`` positions that can NEVER
         fit: waiting cannot help, so it must not pend forever behind an
         arena that is too small by construction."""
-        need = self.alloc.blocks_for(tokens)
+        need = self.blocks_for(tokens)
         if need > self.alloc.n_blocks:
             raise ValueError(
                 f"prompt + budget needs {need} KV blocks; the arena has "
                 f"{self.alloc.n_blocks} (raise kv_blocks)")
 
     def reserve(self, tokens: int) -> KVReservation:
-        """Promise a request its worst case: ``ceil(tokens / block_t)``
-        blocks of the full kind and, with window layers, one ring (a request
-        that has a slot always gets one). :class:`KVBlocksExhausted` is
-        back-pressure and leaves nothing taken."""
-        res = self.alloc.reserve(self.alloc.blocks_for(tokens))
+        """Promise a request its worst case: the blocks ``tokens`` positions
+        write of the append-only kind and, with a ring kind, one ring (a
+        request that has a slot always gets one). :class:`KVBlocksExhausted`
+        is back-pressure and leaves nothing taken."""
+        res = self.alloc.reserve(self.blocks_for(tokens))
         if self.rings is not None:
             try:
                 res.ring = self.rings.reserve()
@@ -361,13 +437,13 @@ class SlotKV:
         snapshots the ids. Returns what that dispatch takes beside the
         rows: the ids ``[n, ceil(padded / block_t)]``, trash behind each
         row's own (``padded`` 0: as wide as the longest prompt needs)."""
-        cols = self.alloc.blocks_for(padded or max(prompt_lens))
+        cols = self.blocks_for(padded or max(prompt_lens))
         ids = np.full((len(slots), cols), self.alloc.trash, np.int32)
         for i, (slot, res, n) in enumerate(zip(slots, reservations, prompt_lens)):
             if self._res.get(slot) is not res:
                 self.hold(slot, res)
             self._filling.pop(slot, None)
-            self.alloc.grant(res, self.alloc.blocks_for(n))
+            self.alloc.grant(res, self.blocks_for(n))
             ids[i, :len(res.granted)] = res.granted
             self.tables[slot, :len(res.granted)] = res.granted
             self._cursor[slot] = n
@@ -401,7 +477,7 @@ class SlotKV:
                 continue
             cursor = int(self._cursor[slot])
             ub = min(cursor + tokens, self.max_seq)
-            self._cursor[slot] = ub
+            self._origin[slot], self._cursor[slot] = cursor, ub
             if self.rings is not None:
                 self.rings.advance(slot, cursor, ub)
             self._grant_into(self.tables[slot], res, ub)
@@ -410,7 +486,7 @@ class SlotKV:
         """Grant ``res`` the blocks ``tokens`` positions need and put the new
         ones behind those ``row`` already shows."""
         base = len(res.granted)
-        new = self.alloc.grant(res, self.alloc.blocks_for(tokens))
+        new = self.alloc.grant(res, self.blocks_for(tokens))
         row[base:base + len(new)] = new
 
     def dispatch_tables(self, active: Iterable[int]
@@ -418,12 +494,13 @@ class SlotKV:
         """What a decode dispatch of the ``active`` slots takes after the
         cache, and the ``serving.engine.dispatch`` region's stats. The
         table's first columns, up to the longest granted row
-        (``view_blocks``); with window layers also every LIVE row's ring (a
+        (``view_blocks``); with a ring kind also every LIVE row's ring (a
         row still prefilling keeps its ring to itself: a decode step writes
         every row's token somewhere, and a dead row's must land in trash),
-        which rows are live, the blocks in use by kind, and the full-kind
-        pages the last step's attention fetches (``ops/paged_attention``:
-        each live row its own pages up to its cursor, within the view)."""
+        which rows are live, the blocks in use by kind, and the pages of
+        the append-only kind that the last step's attention fetches
+        (``ops/paged_attention``: each live row its own pages, within the
+        view; :meth:`_rows_read`), then the ring kind's own."""
         view = view_blocks(self.tables, self.alloc.trash, self.view_widths)
         METRICS.gauge("serving_decode_view_blocks",
                       replica=self.engine_id).set(view)
@@ -431,14 +508,15 @@ class SlotKV:
         stats = {"view_blocks": view, "max_blocks": self.max_blocks}
         rings = self.rings
         if rings is not None:
+            active = list(active)
             live = np.zeros((self.slots,), bool)
-            live[list(active)] = True
-            stats.update(
-                full_blocks=self.alloc.used(), window_blocks=rings.used(),
-                window_blocks_unreleased=rings.unreleased(),
-                full_blocks_read=sum(
-                    min(self.alloc.blocks_for(self._cursor[slot]), view)
-                    for slot in active))
+            live[active] = True
+            stats[f"{self.kind}_blocks"] = self.alloc.used()
+            stats[f"{self.kind}_blocks_read"] = sum(
+                min(self.alloc.blocks_for(self._rows_read(int(self._cursor[slot]))), view)
+                for slot in active)
+            stats.update(rings.dispatch_stats(
+                [(int(self._origin[slot]), int(self._cursor[slot])) for slot in active]))
             tables += (jnp.asarray(np.where(live[:, None], rings.tables,
                                             rings.trash)),
                        jnp.asarray(live))
@@ -456,32 +534,36 @@ class SlotKV:
                 + ring for width in self.view_widths]
 
     def chunk_tables(self, slot: int, start: int, end: int, chunk: int
-                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-        """The four tables of ONE prefill chunk (positions ``[start, end)``
-        in a program of ``chunk``) of a held slot that prefills straight
-        into the arenas: the row's own table up to the narrowest view width
-        covering ``end``, the full-kind blocks the chunk writes, the ring as
-        the previous chunk left it, the ring blocks the chunk writes. Grants
-        the chunk its blocks of both kinds first; the ring's older blocks go
-        back before the new ones are granted, so the chunk writes only what
-        the next reader (the next chunk, or decode) can still see."""
+                     ) -> Tuple[np.ndarray, ...]:
+        """The tables of ONE prefill chunk (positions ``[start, end)`` in a
+        program of ``chunk``) of a held slot that prefills straight into
+        the arenas: the row's own table up to the narrowest view width
+        covering ``end``, the full-kind blocks the chunk writes (a row a
+        position only: the summary kind's program finds its rows in the
+        table), the ring as the previous chunk left it, the ring blocks the
+        chunk writes. Grants the chunk its blocks of both kinds first; the
+        ring's older blocks go back before the new ones are granted, so the
+        chunk writes only what the next reader (the next chunk, or decode)
+        can still see."""
         res, bt, trash = self._res[slot], self.block_t, self.alloc.trash
         table = self._filling.get(slot)
         if table is None:
             table = self._filling[slot] = np.full((self.max_blocks,), trash,
                                                   np.int32)
         self._grant_into(table, res, end)
-        held, first_block = self.alloc.blocks_for(end), start // bt
-        write_full = np.full((chunk // bt,), trash, np.int32)
-        write_full[:held - first_block] = table[first_block:held]
+        held, first_block = self.blocks_for(end), start // bt
         view = next(w for w in self.view_widths if w >= held)
         rings = self.rings
-        read_window = rings.row(slot).copy()
+        read_ring = rings.row(slot).copy()
         rings.advance(slot, end, end)
-        write_window = np.asarray(
+        write_ring = np.asarray(
             [rings.block_of(slot, first_block + j) for j in range(chunk // bt)],
             np.int32)
-        return table[:view], write_full, read_window, write_window
+        if self.stride > 1:
+            return table[:view], read_ring, write_ring
+        write_full = np.full((chunk // bt,), trash, np.int32)
+        write_full[:held - first_block] = table[first_block:held]
+        return table[:view], write_full, read_ring, write_ring
 
 
 class ContiguousKV:

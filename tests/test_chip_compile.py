@@ -171,6 +171,88 @@ def test_mimo_decode_program_copies_no_arena(chip, monkeypatch):
 
 
 
+# The EvaByte cell's shapes (ISSUE 32): 24 slots, 32 heads of 128 with a KV
+# head each, arenas of 16-row blocks 4,096 wide; the local kind's 24 rings of
+# 129 blocks, the summary kind's 1,536 blocks, each with its trash block.
+EVA_LOCAL, EVA_SUMMARY = 24 * 129 + 1, 1536 + 1
+
+
+@pytest.mark.parametrize("blocks,columns", [(EVA_LOCAL, 128), (EVA_SUMMARY, 32),
+                                            (EVA_SUMMARY, 128)],
+                         ids=["local", "summary_narrowest", "summary_widest"])
+def test_paged_decode_attention_eva_decode_shape(chip, blocks, columns):
+    """The decode kernel as the EvaByte step calls it, once a kind: queries
+    heads apart (32 x 4,096), the whole-product value branch, the softmax's
+    maximum and sum as two more outputs, 16 pages a group."""
+    from kubeflow_tpu.models.evabyte import PAGES_PER_GROUP
+
+    def attend(q, keys, vals, table, lengths):
+        return paged_decode_attention(q, keys, vals, table, lengths, scale=128 ** -0.5,
+                                      kv_heads=32, pages=PAGES_PER_GROUP, stats=True,
+                                      interpret=False)
+
+    arena = ((blocks, 16, 32 * 128), BF16)
+    shapes = [((24, 32, 32 * 128), BF16), arena, arena, ((24, columns), I32), ((24,), I32)]
+    assert _compile(chip, attend, *shapes) == 1
+
+
+@pytest.mark.parametrize("chunk,keys", [(2048, 2048 + 2048), (512, 2048 + 2048 + 512)],
+                         ids=["a_whole_window", "a_quarter"])
+def test_chunk_attention_eva_prefill_shape(chip, chunk, keys):
+    """The prefill kernel at the EvaByte cell's shapes: 32 heads of 128, one
+    query head a KV head, a chunk's queries against the summaries' widest
+    view (128 blocks of 16 rows), the window's earlier chunks where the
+    chunk is not a whole window, and the chunk itself."""
+    def attend(q, k, v, q_pos, k_pos):
+        return chunk_attention(q, k, v, q_pos, k_pos, scale=128 ** -0.5, interpret=False)
+
+    shapes = [((32, chunk, 128), BF16), ((32, keys, 128), BF16), ((32, keys, 128), BF16),
+              ((chunk,), I32), ((keys,), I32)]
+    assert _compile(chip, attend, *shapes) == 1
+
+
+def test_eva_decode_program_copies_no_arena(chip, monkeypatch):
+    """The whole scanned ``step`` program of the cell (8 layers at the
+    published widths, 24 slots, 16 steps a dispatch, the widest view): every
+    layer holds the kernel twice (a kind each), and nothing between the
+    token's scatter, the summary's scatter and the kernels moves an arena
+    into another layout (PERF.md section 6, PR 28); its temporaries are
+    megabytes beside 13 GB of weights and arenas."""
+    import re
+
+    from kubeflow_tpu.models import evabyte
+    from kubeflow_tpu.ops import paged_attention
+    from kubeflow_tpu.serving.family import EvaFamily
+
+    monkeypatch.setattr(paged_attention, "_interpret_default", lambda: False)
+    cfg = evabyte.EvaConfig()
+    family = EvaFamily(cfg, slots=24, kv_blocks=1536, kv_block_t=16)
+    rings = family.rings(16)
+    assert rings.cols == 129 and family.local_blocks + 1 == EVA_LOCAL
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+
+    params = described(jax.eval_shape(lambda: evabyte.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = described(jax.eval_shape(family.fresh_cache))
+    rows = [((24,), I32), ((24,), F32), ((24, 2), jnp.uint32), ((24, 128), I32),
+            ((24, rings.cols), I32), ((24,), jnp.bool_)]
+    compiled = family.build_step(16).lower(
+        params, cache, *[jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+                         for shape, dtype in rows]).compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and "paged_decode_attention" in line]
+    assert len(kernels) == 2 * cfg.n_layers
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= bf16\[\d+,16,4096\]\S* copy(-start)?\(", line)]
+    assert not copies, copies
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64e6 and 12.5e9 < memory.argument_size_in_bytes < 13.5e9
+
+
 def test_composite_step_stacks_no_scores_over_the_layers(topo):
     """``composite.make_train_step`` at the sizes of the cell
     ``gpt2-large.train4.fsdp2-tp2`` (36 layers of 1,280, two sequences of

@@ -178,6 +178,11 @@ F32_MATMUL_ALLOWLIST = {
     ("mimo.py", "_decode_attention"),
     ("mimo.py", "decode_step"),
     ("mimo.py", "prefill_chunk"),
+    # the EvaByte family: the float32 logits head (bf16 operands, float32
+    # accumulation), and a chunk's pooling into its summary, which ISSUE 32
+    # states in float32 (16 keys a chunk: a thousandth of a layer's work)
+    ("evabyte.py", "_head"),
+    ("evabyte.py", "summarise"),
 }
 
 _MATMUL_CALLEES = {"einsum", "matmul", "dot", "tensordot", "dot_general"}

@@ -544,6 +544,75 @@ def test_gpt_device_programs_are_what_the_parent_engine_lowered(gpt_params, prog
     assert ours.as_text() == theirs.as_text()
 
 
+def _parent_mimo_step(cfg, trash, chunk):
+    """``MimoFamily.build_step`` as PR 31's tree had it."""
+    from kubeflow_tpu.serving.family import sample_next
+
+    @functools.partial(jax.jit, donate_argnums=(1, 2, 4))
+    def step(params, cache, tok, temps, rngs, full_table, window_table, live):
+        def one(carry, _):
+            cache, tok, rngs, stats = carry
+            logits, cache, st = mimo.decode_step(
+                cfg, params, cache, tok, full_table, window_table, live, trash)
+            with jax.named_scope("sample"):
+                nxt, rngs = sample_next(logits, temps, rngs)
+            return (cache, nxt, rngs, stats + st), nxt
+
+        (cache, tok, rngs, stats), toks = jax.lax.scan(
+            one, (cache, tok, rngs, jnp.zeros((3,), jnp.int32)), None, length=chunk)
+        return cache, tok, rngs, jnp.moveaxis(toks, 0, 1), stats
+
+    return step
+
+
+def _parent_mimo_chunk_prefill(cfg):
+    """``MimoFamily.build_chunk_prefill`` as PR 31's tree had it."""
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill_chunk(params, cache, ids, start, n_valid, temperature, key,
+                      read_full, write_full, read_window, write_window):
+        logits, cache, stats = mimo.prefill_chunk(
+            cfg, params, cache, ids, start, n_valid,
+            read_full, write_full, read_window, write_window)
+        greedy = jnp.argmax(logits).astype(jnp.int32)
+        sampled = jax.random.categorical(
+            key, logits / jnp.maximum(temperature, 1e-6)).astype(jnp.int32)
+        return cache, jnp.where(temperature > 0.0, sampled, greedy), stats
+
+    return prefill_chunk
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
+def test_mimo_device_programs_are_what_the_parent_engine_lowered(params, program):
+    """The GPT test's twin, since PR 32 put a stride and a third and fourth
+    kind of cache behind ``SlotKV``'s verbs and a second output mode into
+    the decode kernel: StableHLO text of the MiMo family's decode and
+    chunk-prefill programs, lowered from the engine with the tables its
+    ``SlotKV`` hands out, equal to the text of the parent's builders given
+    the parent's arguments. (PR 32 also lowered both trees' programs side by
+    side, the kernel's file included: byte-equal.)"""
+    eng = engine(params)
+    try:
+        trash = {mimo.FULL: eng.kv.alloc.n_blocks, mimo.WINDOW: eng.kv.rings.alloc.n_blocks}
+        if program == "decode":
+            args = (eng.params, eng.cache, eng.last_tok, eng.temps, eng.rngs,
+                    *eng.kv.warm_tables()[1])
+            ours = eng._step_fn.lower(*args)
+            theirs = _parent_mimo_step(CFG, trash, eng.chunk).lower(*args)
+        else:
+            eng.kv.hold(0, eng.kv.reserve(40))
+            tables = eng.kv.chunk_tables(0, 0, 16, 16)
+            assert len(tables) == 4
+            args = (eng.params, eng.cache, jnp.zeros((16,), jnp.int32), jnp.asarray(0, jnp.int32),
+                    jnp.asarray(16, jnp.int32), jnp.asarray(0.0, jnp.float32),
+                    jnp.zeros((2,), jnp.uint32), *map(jnp.asarray, tables))
+            ours = eng.family.build_chunk_prefill().lower(*args)
+            theirs = _parent_mimo_chunk_prefill(CFG).lower(*args)
+            eng.kv.release(0)
+    finally:
+        eng.close()
+    assert ours.as_text() == theirs.as_text()
+
+
 @pytest.mark.parametrize("family", ["gpt", "mimo", "gpt_contiguous"])
 def test_the_engine_keeps_the_names_the_benchmark_reads(params, gpt_params, family):
     """``benchmark/runners/{gpt,mimo}_serve.py`` read the engine by name:

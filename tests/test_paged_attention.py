@@ -117,3 +117,84 @@ def test_queries_must_be_as_wide_as_a_row_of_keys():
                                jnp.zeros((3, BT, 64), jnp.bfloat16),
                                jnp.zeros((1, 2), jnp.int32), jnp.ones((1,), jnp.int32),
                                scale=1.0, kv_heads=KV)
+
+
+# -- two sources under one softmax (models/evabyte.py) ---------------------------------
+
+HEADS, DIM = 4, 16           # a KV head a query head: the kernel's whole-product branch
+
+
+@functools.lru_cache(maxsize=None)
+def two_sources():
+    """Every query head with a KV head of its own, a local and a summary
+    arena with a table each, every pair of lengths out of ``PAIRS``: (the
+    joined output, a plain softmax over both sources' positions together,
+    the kernel's stats a source)."""
+    from kubeflow_tpu.models.evabyte import _heads_apart as apart
+    from kubeflow_tpu.ops.paged_attention import join_softmax
+
+    rng = np.random.default_rng(11)
+    slots = len(PAIRS)
+    arenas, tables = [], []
+    for _ in range(2):
+        blocks = slots * COLS
+        tables.append(rng.permutation(blocks).astype(np.int32).reshape(slots, COLS))
+        arenas.append((jnp.asarray(rng.normal(size=(blocks + 1, BT, HEADS * DIM)), jnp.bfloat16),
+                       jnp.asarray(3 * rng.normal(size=(blocks + 1, BT, HEADS * DIM)), jnp.bfloat16)))
+    q = jnp.asarray(2 * rng.normal(size=(slots, HEADS, DIM)), jnp.bfloat16)
+    lengths = np.asarray(PAIRS, np.int32).T                       # [2, slots]
+    parts = [paged_decode_attention(apart(q), k, v, jnp.asarray(t), jnp.asarray(n),
+                                    scale=DIM ** -0.5, kv_heads=HEADS, pages=PAGES, stats=True)
+             for (k, v), t, n in zip(arenas, tables, lengths)]
+    want = []
+    for s in range(slots):
+        ks, vs = [], []
+        for (k, v), t, n in zip(arenas, tables, lengths):
+            own = t[s, :-(-int(n[s]) // BT)]
+            ks.append(k[own].astype(jnp.float32).reshape(-1, HEADS, DIM)[:n[s]])
+            vs.append(v[own].astype(jnp.float32).reshape(-1, HEADS, DIM)[:n[s]])
+        ks, vs = jnp.concatenate(ks), jnp.concatenate(vs)
+        if not len(ks):
+            want.append(jnp.zeros((HEADS, DIM)))
+            continue
+        p = jax.nn.softmax(jnp.einsum("hd,thd->ht", q[s].astype(jnp.float32), ks) * DIM ** -0.5, -1)
+        want.append(jnp.einsum("ht,thd->hd", p, vs))
+    return np.asarray(join_softmax(*parts)), np.asarray(jnp.stack(want)), parts
+
+
+#: (local length, summary length): both empty (a dead row), one source
+#: empty either way, a page and a group's edges, the whole tables
+PAIRS = ((0, 0), (1, 0), (0, 17), (5, 16), (SPAN, SPAN + 1), (SPAN + 9, 3), (COLS * BT, COLS * BT))
+
+
+@pytest.mark.parametrize("row", range(len(PAIRS)), ids=[f"{a}+{b}" for a, b in PAIRS])
+def test_two_sources_join_under_one_softmax(row):
+    """The kernel called once a source with ``stats=True`` and the results
+    joined: equal to ONE softmax over both sources' positions (bfloat16
+    probabilities against float32: a hundredth of the row's largest
+    output), zeros where neither holds anything; and each head read its OWN
+    block of the value columns (the whole-product branch)."""
+    got, want, parts = two_sources()
+    assert got.shape == want.shape == (len(PAIRS), HEADS, DIM)
+    assert np.abs(got[row] - want[row]).max() <= 0.01 * max(1.0, np.abs(want[row]).max())
+    if PAIRS[row] == (0, 0):
+        assert not got[row].any()
+    for (out, m, l), n in zip(parts, PAIRS[row]):
+        assert m.shape == l.shape == (len(PAIRS), HEADS)
+        if n == 0:                      # an empty source: no mass, and it joins as nothing
+            assert float(l[row].max()) == 0.0 and float(m[row].max()) < -1e29
+        else:
+            assert 1.0 <= float(l[row].min()) and float(l[row].max()) <= n
+
+
+def test_stats_do_not_change_the_output():
+    """``stats=True`` adds two outputs and moves nothing of the first."""
+    qk, dv = WIDTHS["24x16"]
+    rng = np.random.default_rng(5)
+    table = jnp.asarray(rng.permutation(8).astype(np.int32).reshape(2, 4))
+    keys = jnp.asarray(rng.normal(size=(9, BT, KV * qk)), jnp.bfloat16)
+    vals = jnp.asarray(rng.normal(size=(9, BT, KV * dv)), jnp.bfloat16)
+    q = _heads_apart(jnp.asarray(rng.normal(size=(2, KV, GROUP, qk)), jnp.bfloat16), KV)
+    run = functools.partial(paged_decode_attention, q, keys, vals, table, jnp.asarray([37, 9]),
+                            scale=qk ** -0.5, kv_heads=KV, pages=PAGES)
+    assert np.array_equal(run(), run(stats=True)[0])

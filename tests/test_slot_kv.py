@@ -1,21 +1,29 @@
 """serving/paged.py's ``SlotKV`` — the one owner of every slot's KV on the
 host — alone, with no engine and no model: the verbs the engine performs
 (check, reserve, hold, bind, advance, dispatch_tables, warm_tables,
-chunk_tables, release), for one kind of cache and for two, and the
-contiguous twin that has nothing behind them."""
+chunk_tables, release), for one kind of cache, for two (full + window) and
+for the other two (summary + local: a row a chunk, aligned windows), and
+the contiguous twin that has nothing behind them."""
 
 import numpy as np
 import pytest
 
 from kubeflow_tpu.runtime.metrics import METRICS
-from kubeflow_tpu.serving.paged import (ContiguousKV, KVBlocksExhausted,
+from kubeflow_tpu.serving.paged import (AlignedWindows, ContiguousKV, KVBlocksExhausted,
                                         KVReservation, SlotKV, WindowRings)
 
 SLOTS, MAX_SEQ, BT = 3, 64, 4          # 16 columns a row: widths 4, 8, 12, 16
 WINDOW, LOOKAHEAD = 8, 4               # rings of ceil((8 + 4 - 1) / 4) + 1 = 4
+# the summary + local pair: aligned windows of 16 (4 blocks, and one more for
+# the 3 positions a dispatch of 4 can lie past a line), a summary row a chunk
+# of 2 positions: 64 / (4 * 2) = 8 columns a row, widths 2, 4, 6, 8
+EVA, ALIGNED, STRIDE = "eva", 16, 2
 
 
 def owner(kinds, n_blocks=SLOTS * MAX_SEQ // BT, engine_id="0"):
+    if kinds == EVA:
+        return SlotKV(SLOTS, MAX_SEQ, BT, n_blocks, engine_id=engine_id, stride=STRIDE,
+                      rings=AlignedWindows(SLOTS, ALIGNED, BT, LOOKAHEAD, engine_id=engine_id))
     rings = (WindowRings(SLOTS, WINDOW, BT, LOOKAHEAD, engine_id=engine_id)
              if kinds == 2 else None)
     return SlotKV(SLOTS, MAX_SEQ, BT, n_blocks, engine_id=engine_id, rings=rings)
@@ -32,36 +40,39 @@ def whole(kv):
     return bool(ok)
 
 
-@pytest.mark.parametrize("kinds", [1, 2])
+@pytest.mark.parametrize("kinds", [1, 2, EVA])
 def test_a_round_trip_leaves_both_kinds_whole(kinds):
     kv = owner(kinds)
     res = kv.reserve(9 + 20)
-    assert res.total == 8 and (res.ring is not None) == (kinds == 2)
-    assert kv.alloc.available() == kv.alloc.n_blocks - 8 and kv.alloc.used() == 0
+    # 29 positions: 8 blocks of 4 rows a position, 4 of 4 rows a chunk of 2
+    total, prompt = (4, 1) if kinds == EVA else (8, 3)
+    assert res.total == total and (res.ring is not None) == (kinds != 1)
+    assert kv.alloc.available() == kv.alloc.n_blocks - total and kv.alloc.used() == 0
     kv.hold(1, res)
     assert (kv.tables == kv.alloc.trash).all()          # held: still on trash
     (ids,) = kv.bind([1], [res], [9])
-    assert ids.shape == (1, 3) and list(ids[0]) == res.granted
-    assert list(kv.tables[1, :3]) == res.granted and kv.alloc.used() == 3
+    assert ids.shape == (1, prompt) and list(ids[0]) == res.granted
+    assert list(kv.tables[1, :prompt]) == res.granted and kv.alloc.used() == prompt
     for _ in range(5):
         kv.advance([1], 4)
-    assert kv.alloc.used() == 8 and (kv.tables[[0, 2]] == kv.alloc.trash).all()
-    if kinds == 2:
+    assert kv.alloc.used() == total and (kv.tables[[0, 2]] == kv.alloc.trash).all()
+    if kinds != 1:
         assert 0 < kv.rings.used() <= kv.rings.cols
     kv.release(1)
     assert whole(kv)
 
 
-@pytest.mark.parametrize("kind", ["full", "window"])
+@pytest.mark.parametrize("kind", ["full", "window", "summary", "local"])
 def test_release_trashes_the_row_before_its_blocks_are_grantable(kind):
     """The retire order: at the moment a kind's blocks go back to the free
     list (from where the next admission may be granted them), no table of
     that kind shows any of them."""
-    kv = owner(2)
+    kv = owner(2 if kind in ("full", "window") else EVA)
     res = kv.reserve(30)
     kv.bind([0], [res], [10])
     kv.advance([0], 4)
-    alloc, tables = ((kv.alloc, kv.tables) if kind == "full"
+    assert {kv.kind, kv.rings.kind} >= {kind}
+    alloc, tables = ((kv.alloc, kv.tables) if kind in ("full", "summary")
                      else (kv.rings.alloc, kv.rings.tables))
     seen, release = [], alloc.release
     alloc.release = lambda r: (seen.extend(b in tables for b in r.granted),
@@ -153,15 +164,16 @@ def test_check_refuses_what_can_never_fit():
     assert whole(kv)
 
 
-@pytest.mark.parametrize("kind", ["full", "window"])
+@pytest.mark.parametrize("kind", ["full", "window", "summary", "local"])
 def test_exhaustion_raises_and_leaves_state_unchanged(kind):
-    kv = owner(2, n_blocks=10)
-    held = kv.reserve(24)                      # 6 of 10 full blocks, 1 of 3 rings
-    if kind == "window":
+    eva = kind in ("summary", "local")
+    kv = owner(EVA if eva else 2, n_blocks=5 if eva else 10)
+    held = kv.reserve(24)                      # 6 of 10 full blocks (3 of 5 summary), 1 of 3 rings
+    if kind in ("window", "local"):
         held = [held, kv.reserve(4), kv.reserve(4)]      # all three rings
     before = (kv.alloc.available(), kv.rings.alloc.available())
     with pytest.raises(KVBlocksExhausted):
-        kv.reserve(20 if kind == "full" else 4)
+        kv.reserve(20 if kind in ("full", "summary") else 4)
     # a ring that cannot be had gives the full kind's promise back
     assert (kv.alloc.available(), kv.rings.alloc.available()) == before
     assert kv.alloc.used() == kv.rings.used() == 0
@@ -236,18 +248,143 @@ def test_chunk_tables_fill_a_row_of_its_own_until_bind():
     assert whole(kv)
 
 
-@pytest.mark.parametrize("kinds", [1, 2])
+@pytest.mark.parametrize("kinds", [1, 2, EVA])
 def test_warm_tables_are_all_trash_one_set_a_width(kinds):
     kv = owner(kinds)
     sets = kv.warm_tables()
-    assert [np.asarray(t[0]).shape for t in sets] == [(SLOTS, w) for w in (4, 8, 12, 16)]
+    widths = (2, 4, 6, 8) if kinds == EVA else (4, 8, 12, 16)
+    assert [np.asarray(t[0]).shape for t in sets] == [(SLOTS, w) for w in widths]
     for tables in sets:
-        assert len(tables) == (3 if kinds == 2 else 1)
+        assert len(tables) == (1 if kinds == 1 else 3)
         assert (np.asarray(tables[0]) == kv.alloc.trash).all()
-        if kinds == 2:
+        if kinds != 1:
             assert np.asarray(tables[1]).shape == (SLOTS, kv.rings.cols)
             assert (np.asarray(tables[1]) == kv.rings.trash).all()
             assert not np.asarray(tables[2]).any()
+    assert whole(kv)
+
+
+# -- a row a chunk, and aligned windows -------------------------------------------------
+
+def test_the_summary_kind_reckons_check_and_reserve_in_chunks():
+    """A row stands for ``stride`` positions and exists once its chunk is
+    whole: ``tokens // stride`` rows, a block of 4 rows to 8 positions."""
+    kv = owner(EVA, n_blocks=6)
+    assert kv.kind == "summary" and kv.rings.kind == "local" and kv.max_blocks == 8
+    assert [kv.blocks_for(n) for n in (0, 1, 2, 7, 8, 9, 10, 47, 48, 49, 50)] == [
+        0, 0, 1, 1, 1, 1, 2, 6, 6, 6, 7]
+    kv.check(49)                               # 24 rows: 6 blocks, fits when empty
+    with pytest.raises(ValueError, match="needs 7 KV blocks; the arena has 6"):
+        kv.check(50)
+    assert kv.reserve(33).total == 4 and kv.reserve(9).total == 1
+    with pytest.raises(KVBlocksExhausted):
+        kv.reserve(17)                         # 2 blocks of the 1 left
+    assert whole(owner(EVA))
+
+
+@pytest.mark.parametrize("prompt,steps", [(5, 1), (5, 4), (14, 4), (16, 3)])
+def test_the_summary_kind_grants_what_is_written_and_reads_what_is_visible(prompt, steps):
+    """``advance`` grants the rows the frontier's whole chunks need (a row
+    is WRITTEN when its chunk completes); ``dispatch_tables`` counts the
+    pages the last step reads, which are the summaries of the windows it
+    has LEFT (visible), not of what is written."""
+    kv = owner(EVA, engine_id="eva-stats")
+    res = kv.reserve(prompt + 40)
+    kv.bind([1], [res], [prompt])
+    cursor = prompt
+    for _ in range(9):
+        kv.advance([1], steps)
+        tables, stats = kv.dispatch_tables([1])
+        before, cursor = cursor, cursor + steps
+        assert kv._cursor[1] == cursor and kv._origin[1] == before
+        written = cursor // STRIDE                                  # rows
+        assert len(res.granted) == -(-written // BT) == stats["summary_blocks"]
+        last = cursor - 1                                           # the last step's position
+        visible = last // ALIGNED * (ALIGNED // STRIDE)             # rows
+        assert stats["summary_blocks_read"] == -(-visible // BT) <= stats["view_blocks"]
+        assert visible <= written
+        assert stats["local_blocks_read"] == -(-(last % ALIGNED + 1) // BT)
+        assert stats["rollovers"] == int(cursor // ALIGNED > before // ALIGNED)
+        assert stats["local_blocks"] == kv.rings.used() <= kv.rings.cols
+        assert set(stats) == {"view_blocks", "max_blocks", "summary_blocks", "local_blocks",
+                              "summary_blocks_read", "local_blocks_read", "rollovers"}
+        summary, ring, live = (np.asarray(t) for t in tables)
+        assert summary.shape == (SLOTS, stats["view_blocks"]) and list(live) == [False, True, False]
+        assert list(summary[1, :len(res.granted)]) == res.granted
+    assert METRICS.gauge("serving_kv_blocks_used", replica="eva-stats",
+                         kind="summary").value == len(res.granted)
+    assert METRICS.gauge("serving_kv_blocks_used", replica="eva-stats",
+                         kind="local").value == kv.rings.used()
+    kv.release(1)
+    assert whole(kv)
+
+
+@pytest.mark.parametrize("lookahead,cols", [(1, 4), (2, 5), (4, 5), (5, 5), (6, 6), (16, 8)])
+def test_the_local_kind_returns_a_whole_window_at_roll_over(lookahead, cols):
+    """Aligned windows of 16 in blocks of 4: ``16 / 4 + ceil((lookahead - 1)
+    / 4)`` blocks a slot. Every position a dispatch reads (its window, from
+    the window's start) or writes has its block; a dispatch that crosses a
+    line holds the old window whole and the new one's first blocks; the
+    first dispatch that starts past the line has given ALL of the old
+    window back, each table entry on trash before its block returned."""
+    rings = AlignedWindows(2, ALIGNED, BT, lookahead)
+    assert rings.cols == cols == ALIGNED // BT + -(-(lookahead - 1) // BT)
+    rings.attach(0, rings.reserve())
+    seen, give_back = [], rings.alloc.give_back
+    rings.alloc.give_back = lambda res, blk: (seen.append(blk in rings.tables), give_back(res, blk))
+    cursor, most = 3, 0
+    for _ in range(40):
+        rings.advance(0, cursor, cursor + lookahead)
+        held = rings._held[0]
+        most = max(most, len(held))
+        first = cursor // ALIGNED * (ALIGNED // BT)
+        assert min(held) == first                       # nothing of an earlier window
+        for p in range(cursor // ALIGNED * ALIGNED, cursor + lookahead):
+            assert rings.tables[0, (p // BT) % cols] == held[p // BT]
+        cursor += lookahead
+    assert most <= cols and rings.used() == len(rings._held[0])
+    assert seen and not any(seen)
+    rings.release(0)
+    assert rings.used() == 0 and rings.alloc.available() == rings.alloc.n_blocks
+    assert (rings.tables == rings.trash).all()
+
+
+def test_a_window_that_is_not_whole_blocks_is_refused():
+    with pytest.raises(ValueError, match="not whole blocks"):
+        AlignedWindows(1, 18, 4, 4)
+
+
+def test_chunk_tables_of_the_summary_and_local_kinds():
+    """Prefill chunks of 8 in windows of 16: the summary table grows with
+    the whole chunks written; a chunk's local blocks are kept only where a
+    later chunk of its window or decode can still read them."""
+    kv = owner(EVA)
+    res = kv.reserve(37 + 6)
+    kv.hold(1, res)
+    trash = kv.rings.trash
+    # [0, 8): the window goes on, so the chunk's two blocks are kept
+    read_summary, read_local, write_local = kv.chunk_tables(1, 0, 8, 8)
+    assert read_summary.shape == (2,) and list(read_summary[:1]) == res.granted   # 4 rows
+    assert (read_local == trash).all() and (write_local != trash).all()
+    kept = list(write_local)
+    # [8, 16): ends ON the line: nobody reads these keys again; the first
+    # chunk's blocks are read (the ring as it was) and then given back
+    read_summary, read_local, write_local = kv.chunk_tables(1, 8, 16, 8)
+    assert len(res.granted) == 2 and list(read_summary) == res.granted
+    assert sorted(b for b in read_local if b != trash) == sorted(kept)
+    assert (write_local == trash).all() and kv.rings.used() == 0
+    # [16, 24) and [24, 32): the second window, kept and then not
+    assert (kv.chunk_tables(1, 16, 24, 8)[2] != trash).all()
+    assert (kv.chunk_tables(1, 24, 32, 8)[2] == trash).all()
+    # [32, 37): the prompt's last window, read by decode: kept (one block and
+    # a part), the rest of the program's chunk to trash
+    read_summary, read_local, write_local = kv.chunk_tables(1, 32, 37, 8)
+    assert read_summary.shape == (6,) and list(read_summary[:5]) == res.granted   # 18 rows
+    assert (write_local != trash).all() and kv.rings.used() == 2
+    assert (kv.tables == kv.alloc.trash).all()              # shared row: still trash
+    kv.bind([1], [res], [37])
+    assert list(kv.tables[1, :5]) == res.granted and kv._cursor[1] == 37
+    kv.release(1)
     assert whole(kv)
 
 
