@@ -14,8 +14,9 @@ lengths). TPU-first choices:
   conventions (query/key/value → heads, up_proj/down_proj → mlp,
   embedding → vocab/embed), so dp/fsdp/tp placement is a rules swap,
 - optional MoE FFN (parallel/moe) for expert parallelism,
-- optional per-block remat (``jax.checkpoint``) — trade recompute for HBM
-  at long context.
+- optional per-block remat (``jax.checkpoint`` under ``SAVED_IN_BLOCK``'s
+  policy): the backward keeps a block's input, its matmul outputs and the
+  flash kernel's residuals, and recomputes only the elementwise pieces.
 """
 
 from __future__ import annotations
@@ -27,8 +28,38 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from kubeflow_tpu.ops.flash_attention import flash_attention
+from kubeflow_tpu.ops.flash_attention import SAVED_RESIDUALS, flash_attention
+
+#: What ``cfg.remat`` keeps of a block for its backward, besides the block's
+#: input: every value whose recomputation would cost a matmul or the flash
+#: kernel. The three projections before RoPE, the kernel's output and
+#: log-sum-exp, the output projection's result (the second LayerNorm reads
+#: the block's input plus it) and the up-projection's result before the
+#: GELU. Both LayerNorms, RoPE, the GELU, casts and residual sums are
+#: recomputed. The down-projection's result feeds only the residual sum,
+#: whose backward reads nothing, so it is not kept. An MoE FFN names nothing
+#: and is recomputed whole.
+SAVED_IN_BLOCK = ("query", "key", "value", *SAVED_RESIDUALS, "attn_out", "mlp_pre")
+
+
+def _remat(block, **kwargs):
+    """``block`` (a module class) under :data:`SAVED_IN_BLOCK`'s policy, for
+    both of ``GptLM``'s layouts."""
+    return nn.remat(
+        block, policy=jax.checkpoint_policies.save_only_these_names(*SAVED_IN_BLOCK),
+        **kwargs)
+
+
+def _kept(cfg: "GptConfig", x: jax.Array, name: str) -> jax.Array:
+    """``x`` under ``name`` where ``cfg.remat`` asks for :func:`_remat`'s
+    policy, ``x`` itself elsewhere. Outside a ``jax.checkpoint`` a name
+    computes nothing, but it is an equation, and a module's second distinct
+    one renumbers its other private functions as it lowers (``_where_116``
+    becomes ``_where_117``): another text and compile-cache key for the same
+    instructions. So the serving programs of this block hold no name."""
+    return checkpoint_name(x, name) if cfg.remat else x
 
 
 @dataclass(frozen=True)
@@ -137,11 +168,15 @@ class GptAttention(nn.Module):
                     raise ValueError("paged KV decode requires per_slot=True")
                 return self._paged_decode_attention(x, dense, block_tables)
             return self._decode_attention(x, dense)
-        q = rope(dense(name="query")(x), positions, cfg.rope_theta)
-        k = rope(dense(name="key")(x), positions, cfg.rope_theta)
-        v = dense(name="value")(x)
+
+        def proj(name):
+            return _kept(cfg, dense(name=name)(x), name)
+
+        q = rope(proj("query"), positions, cfg.rope_theta)
+        k = rope(proj("key"), positions, cfg.rope_theta)
+        v = proj("value")
         ctx = self.attention_fn(q, k, v)  # [b, L, heads, head_dim]
-        return self._out_proj(ctx)
+        return _kept(cfg, self._out_proj(ctx), "attn_out")
 
     def _out_proj(self, ctx: jax.Array) -> jax.Array:
         return nn.DenseGeneral(
@@ -347,7 +382,7 @@ class GptMlp(nn.Module):
         cfg = self.cfg
         h = nn.Dense(cfg.d_ff, dtype=cfg.dtype, param_dtype=jnp.float32,
                      use_bias=False, name="up_proj")(x)
-        h = nn.gelu(h)
+        h = nn.gelu(_kept(cfg, h, "mlp_pre"))
         return nn.Dense(cfg.d_model, dtype=cfg.dtype, param_dtype=jnp.float32,
                         use_bias=False, name="down_proj")(h)
 
@@ -427,11 +462,12 @@ class GptLM(nn.Module):
         positions = jnp.arange(input_ids.shape[1])  # decode path derives its own
         if cfg.scan_blocks and not self.decode:
             # One traced block, n_layers iterations: params stack on a
-            # leading layer axis under ``blocks/``; remat wraps the body so
-            # each layer's activations rematerialize in backward.
+            # leading layer axis under ``blocks/``; remat wraps the body, so
+            # the backward scan reads ``SAVED_IN_BLOCK`` stacked over the
+            # layers and runs no forward matmul or kernel again.
             body = GptBlock
             if cfg.remat:
-                body = nn.remat(body, prevent_cse=False, methods=["scan_body"])
+                body = _remat(body, prevent_cse=False, methods=["scan_body"])
             stack = nn.scan(
                 body,
                 variable_axes={"params": 0},
@@ -452,7 +488,7 @@ class GptLM(nn.Module):
                 )
             block = GptBlock
             if cfg.remat:
-                block = nn.remat(GptBlock, static_argnums=())
+                block = _remat(GptBlock, static_argnums=())
             for i in range(cfg.n_layers):
                 x = block(cfg, self.attention_fn, self.mesh, self.decode,
                           self.per_slot, self.paged, self.kv_blocks,

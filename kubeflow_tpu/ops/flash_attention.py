@@ -26,11 +26,20 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_BIG = -1e30
 _LANE = 128
+
+#: ``checkpoint_name``s of the two residuals the forward kernel makes: its
+#: output and the log-sum-exp. A ``jax.checkpoint`` whose policy saves both
+#: keeps the forward ``pallas_call`` out of its backward (the other three
+#: residuals, q, k and v, are the caller's to keep or rebuild). Outside a
+#: ``jax.checkpoint`` the names compute nothing, and only a differentiated
+#: program traces them (``_flash_fwd``): no forward-only program holds one.
+SAVED_RESIDUALS = ("flash_out", "flash_lse")
 
 
 def _interpret_default() -> bool:
@@ -423,6 +432,8 @@ def _flash_fwd(q, k, v, causal, scale, q_offset, k_offset, block_q, block_k, int
         q, k, v, causal=causal, scale=scale, q_offset=q_offset, k_offset=k_offset,
         block_q=block_q, block_k=block_k, interpret=interpret, bf16_dots=bf16_dots,
     )
+    out = checkpoint_name(out, SAVED_RESIDUALS[0])
+    lse = checkpoint_name(lse, SAVED_RESIDUALS[1])
     return out, (q, k, v, out, lse)
 
 

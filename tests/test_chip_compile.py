@@ -276,3 +276,41 @@ def test_composite_step_stacks_no_scores_over_the_layers(topo):
     stacked = set(re.findall(r"\w+\[(?:\d+,)*36,(?:\d+,)*1024,1024\]", compiled.as_text()))
     assert not stacked, stacked
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+def test_gpt_train_step_runs_the_forward_kernel_once(chip, monkeypatch):
+    """``bench.gpt_train_step`` at the sizes of the cell
+    ``gpt2-medium.train.b8x1024`` (24 layers of 1,024, 8 x 1,024 tokens,
+    AdamW, ``bench.GPT_TRAIN_KNOBS``): remat keeps the flash kernel's output
+    and log-sum-exp (``models/gpt.SAVED_IN_BLOCK``), so the compiled step
+    holds ONE forward kernel beside the two backward ones (two before
+    PR 34, the second under ``rematted_computation``), and it fits the
+    chip: 4.24 GB of arguments and 9.65 GB of temporaries of 15.75 GiB
+    (3.99 GB of temporaries before)."""
+    import importlib
+    import re
+
+    import optax
+
+    import bench
+    from kubeflow_tpu.models.gpt import GptConfig
+
+    # the backend here is the CPU and the kernels would lower interpreted
+    flash = importlib.import_module("kubeflow_tpu.ops.flash_attention")
+    monkeypatch.setattr(flash, "_interpret_default", lambda: False)
+    cfg = GptConfig(vocab_size=50257, d_model=1024, n_layers=24, n_heads=16, d_ff=4096,
+                    max_seq=1024, **bench.GPT_TRAIN_KNOBS)     # as runners/gpt_train.py builds it
+    opt = optax.adamw(3e-4, weight_decay=0.01)
+    model, train_step = bench.gpt_train_step(cfg, opt)
+    ids = jax.ShapeDtypeStruct((8, cfg.max_seq), I32, sharding=chip)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros(ids.shape, I32))["params"])
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+    compiled = jax.jit(train_step, donate_argnums=(0, 1)).lower(
+        described(params), described(jax.eval_shape(opt.init, params)), ids).compile()
+    kernels = re.findall(r'custom_call_target="tpu_custom_call".*?(flash_\w+)/pallas_call',
+                         compiled.as_text())
+    assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75 * 2**30
