@@ -27,7 +27,12 @@ product reads a row whole; values are sliced a KV head at a time.
 Where every query head has a KV head of its own (``kv_heads == heads``:
 models/evabyte.py) a head's slice of the probabilities would be ONE row, so
 the value product is taken whole (``[heads, span] x [span, heads * v]``) and
-each head keeps its own block of columns. With ``stats=True`` the call also
+each head keeps its own block of columns. A slot may bring more than one
+query POSITION (models/sdar.py: a block of 4 positions x 32 heads): they
+ride as more rows of the same matmul, a KV head's ``positions x group`` rows
+together, and all of them read the row up to the one length, so the block
+sees itself whole; nothing in the kernel knows a row's position. With
+``stats=True`` the call also
 returns the softmax's running maximum and sum, so that a caller with more
 than one source of keys (a local window and a summary arena, each with a
 table of its own) calls once a source and joins the results under ONE

@@ -150,6 +150,19 @@ def sigmoid_top_k(h: jax.Array, router: jax.Array, bias: jax.Array, k: int
     return idx.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
 
 
+def softmax_top_k(h: jax.Array, router: jax.Array, k: int
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """Router in float32 over every expert: ``r = softmax(h @ router)``, the
+    ``k`` experts with the largest ``r``, weights ``r`` normalised over the k
+    chosen (``norm_topk_prob``). h: [T, d]. Returns (expert ids [T, k]
+    int32, weights [T, k] float32)."""
+    f32 = jnp.float32
+    probs = jax.nn.softmax(jnp.dot(h.astype(f32), router.astype(f32),
+                                   precision=jax.lax.Precision.HIGHEST), axis=-1)
+    w, idx = jax.lax.top_k(probs, k)
+    return idx.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+
+
 def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                      first_held: int = 0, live: Optional[jax.Array] = None,

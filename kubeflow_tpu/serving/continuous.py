@@ -135,6 +135,9 @@ class _Request:
     max_new_tokens: int
     done: threading.Event = field(default_factory=threading.Event)
     tokens: List[int] = field(default_factory=list)
+    #: beside each token, where the family's step hands one out: the forward
+    #: pass of its block that revealed it (0: it was the prompt's)
+    reveal_passes: List[int] = field(default_factory=list)
     error: Optional[BaseException] = None
     eos_id: Optional[int] = None
     temperature: float = 0.0  # 0 = greedy; >0 samples with a per-slot key
@@ -416,11 +419,12 @@ class ContinuousBatcher:
         # the one owner of every slot's KV on the host. A family may keep a
         # second kind of cache, a ring a slot beside the block table (a
         # dispatch moves a cursor by up to ``chunk`` positions), and a row
-        # of its table may stand for more than one position.
+        # of its table may stand for more than one position, and a step
+        # may work on a block of positions past the cursor.
         self.kv = (SlotKV(slots, cfg.max_seq, self.kv_block_t, self.family.kv_blocks,
                           engine_id=self.engine_id,
                           rings=self.family.rings(self.chunk, self.engine_id),
-                          stride=self.family.kv_stride)
+                          stride=self.family.kv_stride, ahead=self.family.kv_ahead)
                    if paged else ContiguousKV())
         # every view width's decode program is compiled once, at the first
         # prewarm: "no" -> "asked" (prewarm) -> "done" (the engine thread,
@@ -459,6 +463,10 @@ class ContinuousBatcher:
                 raise ValueError(
                     f"{type(cfg).__name__} prefills in chunks: prefill_chunk "
                     "must not be 0")
+        # the most positions one decode dispatch moves a slot's cursor, and
+        # the decode dispatches whose events are still to be processed
+        self._moves = self.spec_k or self.family.cursor_moves(self.chunk)
+        self._decodes_in_flight = 0
         self.cache = self.family.fresh_cache()
         if self.spec_k:
             self.draft_cache = self._draft_family.fresh_cache()
@@ -1096,8 +1104,7 @@ class ContinuousBatcher:
         in_arena = self.family.prefills_in_arena    # no private cache, then
         self._chunked = _ChunkedPrefill(
             req=req, slot=slot, key=key, res=res,
-            cache=None if in_arena else self.family.prefill_cache(1),
-            stats=jnp.zeros((3,), jnp.int32) if in_arena else None)
+            cache=None if in_arena else self.family.prefill_cache(1))
         _ev(req, "chunked_prefill_start", slot=slot,
             chunks=-(-len(req.prompt) // self.prefill_chunk))
         return True
@@ -1236,7 +1243,7 @@ class ContinuousBatcher:
             jnp.asarray(start, jnp.int32), jnp.asarray(end - start, jnp.int32),
             jnp.asarray(req.temperature, jnp.float32), cp.key,
             *map(jnp.asarray, tables))
-        cp.stats = cp.stats + stats
+        cp.stats = stats if cp.stats is None else cp.stats + stats
         cp.pos = start + c
         METRICS.counter("serving_prefill_chunks_total").inc()
         _ev(req, "prefill_chunk", start=start)
@@ -1541,29 +1548,36 @@ class ContinuousBatcher:
         a row whose request finished in an earlier event is a discarded
         tail; a row adopted after the dispatch is not in the snapshot."""
         kind, dev, meta, dispatched_at = event
-        widths = stats = None
+        widths = marks = cursors = stats = None
+        if kind != "first":
+            self._decodes_in_flight -= 1
         if self.family.has_stats:
             # the family's counters ride with the tokens
             dev, stats = dev
         with profiling.annotate("serving.engine.fetch", kind=kind):
-            if kind == "spec":
-                # one speculative round: [slots, spec_k] candidate tokens
-                # plus the per-slot accepted width m (1..spec_k) — only the
-                # first m are real, the rest were refuted by the verify
-                # forward
-                toks_dev, acc_dev = dev
-                block = np.asarray(toks_dev)
-                widths = np.asarray(acc_dev)
+            # host fetch (async copy started at dispatch)
+            if isinstance(dev, tuple):
+                # the event says how many of each row's tokens are real: a
+                # speculative round's [slots, spec_k] candidates with the
+                # accepted width m (1..spec_k; the rest were refuted by the
+                # verify forward), or the blocks each slot completed in the
+                # dispatch, with a mark beside each token and the device's
+                # cursors after it
+                block, widths, *rest = (np.asarray(a) for a in dev)
+                if rest:
+                    marks, cursors = rest
             else:
-                # host fetch (async copy started at dispatch)
                 block = np.asarray(dev)
-                if stats is not None:
-                    stats = np.asarray(stats)
+            if stats is not None:
+                stats = np.asarray(stats)
         now = time.perf_counter()
         with profiling.annotate("serving.engine.deliver", kind=kind,
                                 rows=int(block.size)) as span:
             if kind == "first":
-                tokens, retired = self._deliver_first(meta, block, now)
+                # a family whose prefill yields no token fetched only the
+                # prompt's counters: the first token comes with a block
+                tokens, retired = (self._deliver_first(meta, block, now)
+                                   if self.family.prefill_yields_token else (0, 0))
             else:
                 # dispatch→fetch-complete latency of one pipelined decode
                 # chunk (``now`` is where the fetch region closed)
@@ -1572,7 +1586,14 @@ class ContinuousBatcher:
                     buckets=DECODE_CHUNK_BUCKETS
                 ).observe(now - dispatched_at)
                 tokens, retired = self._deliver_block(
-                    meta, block, widths, now)
+                    meta, block, widths, now, marks, drafted=kind == "spec")
+                if cursors is not None:
+                    # the cursors moved by what the slots committed: the
+                    # bound the next grants start from comes down to them
+                    for slot, req in meta.items():
+                        if self._active.get(slot) is req:
+                            self.kv.settle(slot, int(cursors[slot])
+                                           + self._decodes_in_flight * self._moves)
             span.set_metadata(tokens=tokens, retired=retired)
             if stats is not None:
                 # over the event's live tokens: a decode chunk's rows, or
@@ -1596,13 +1617,8 @@ class ContinuousBatcher:
                 continue
             req.tokens.append(int(tok))
             tokens += 1
-            req.first_token_at = req.last_token_at = now
             METRICS.counter("serving_tokens_out_total").inc()
-            if req.submit_at is not None:
-                METRICS.histogram(
-                    "serving_ttft_seconds", buckets=TTFT_BUCKETS
-                ).observe(now - req.submit_at, trace_id=_trace_id(req))
-            _ev(req, "first_token")
+            self._note_first(req, now)
             hit_eos = req.eos_id is not None and req.tokens[-1] == req.eos_id
             if req.max_new_tokens <= 1 or hit_eos:
                 # the slot was activated at admission, so the normal
@@ -1611,17 +1627,20 @@ class ContinuousBatcher:
                 retired += 1
         return tokens, retired
 
-    def _deliver_block(self, snapshot, block, widths, now: float
-                       ) -> Tuple[int, int]:
-        """One decode chunk's (or speculative round's) token block to the
-        requests of its dispatch-time snapshot. Returns (tokens appended
-        to live requests, requests retired)."""
+    def _deliver_block(self, snapshot, block, widths, now: float,
+                       marks=None, drafted: bool = False) -> Tuple[int, int]:
+        """One decode dispatch's token block to the requests of its
+        dispatch-time snapshot. ``widths`` [slots], where the event carries
+        them, says how many of each row's tokens are real (None: the whole
+        row); ``marks`` [slots, width] an integer beside each token;
+        ``drafted``: the widths are a speculative round's accepted prefixes.
+        Returns (tokens appended to live requests, requests retired)."""
         tokens = retired = 0
         for slot, req in snapshot.items():
-            # usable tokens this row produced: the whole chunk, or the
-            # accepted prefix of a speculative round
+            # usable tokens this row produced: the event's width, or the
+            # whole chunk
             width = int(widths[slot]) if widths is not None else block.shape[1]
-            if widths is not None and not req.done.is_set():
+            if drafted and not req.done.is_set():
                 # accept-rate numerators: spec_k - 1 verifiable drafts per
                 # round; width - 1 of them accepted (the +1 is the target's
                 # own token, drafted or not)
@@ -1648,6 +1667,8 @@ class ContinuousBatcher:
             for j in range(width):
                 tok = int(block[slot, j])
                 req.tokens.append(tok)
+                if marks is not None:
+                    req.reveal_passes.append(int(marks[slot, j]))
                 appended += 1
                 tokens += 1
                 hit_eos = req.eos_id is not None and tok == req.eos_id
@@ -1667,9 +1688,24 @@ class ContinuousBatcher:
                 self._note_tokens(req, appended, now)
         return tokens, retired
 
+    def _note_first(self, req: _Request, now: float) -> None:
+        """The request's first token reached the host."""
+        req.first_token_at = req.last_token_at = now
+        if req.submit_at is not None:
+            METRICS.histogram(
+                "serving_ttft_seconds", buckets=TTFT_BUCKETS
+            ).observe(now - req.submit_at, trace_id=_trace_id(req))
+        _ev(req, "first_token")
+
     def _note_tokens(self, req: _Request, n: int, now: float) -> None:
+        """``n`` tokens of one event reached ``req``: the gap between tokens
+        is the time since the last event's over the ``n`` it brought. The
+        first tokens of a request whose prefill yielded none are its first
+        block's: they stamp TTFT, and no gap among themselves."""
         METRICS.counter("serving_tokens_out_total").inc(n)
-        if req.last_token_at is not None:
+        if req.first_token_at is None:
+            self._note_first(req, now)
+        elif req.last_token_at is not None:
             METRICS.histogram(
                 "serving_inter_token_seconds", buckets=ITL_BUCKETS
             ).observe((now - req.last_token_at) / n, count=n,
@@ -1705,10 +1741,6 @@ class ContinuousBatcher:
     def _turn(self, events: "collections.deque[Tuple[str, Any, Any, float]]"
               ) -> bool:
         """One pass of the engine loop; False once the loop is over."""
-
-        def chunk_depth() -> int:
-            return sum(1 for kind, _, _, _ in events
-                       if kind in ("chunk", "spec"))
 
         # drain arrivals into the pending deque; block only when fully
         # idle (no busy-wait). Coalescing the drain is what lets a burst
@@ -1794,7 +1826,7 @@ class ContinuousBatcher:
                 with profiling.annotate("serving.engine.dispatch",
                                         rows=self.slots * width,
                                         live=len(self._active)) as span:
-                    self.kv.advance(self._active, width)
+                    self.kv.advance(self._active, self._moves)
                     tables, stats = self.kv.dispatch_tables(self._active)
                     span.set_metadata(**stats)
                     kind, out = self._run_decode(tables)
@@ -1805,11 +1837,12 @@ class ContinuousBatcher:
                         pass
                     events.append((kind, out, dict(self._active),
                                    time.perf_counter()))
+                    self._decodes_in_flight += 1
                 dispatched = True
             # keep the dispatch frontier at most ``pipeline`` chunks
             # ahead of the processed state; when nothing new could be
             # dispatched, drain one event so the pipeline empties
-            while chunk_depth() > self.pipeline:
+            while self._decodes_in_flight > self.pipeline:
                 self._process_event(events.popleft())
             if not dispatched and events:
                 self._process_event(events.popleft())

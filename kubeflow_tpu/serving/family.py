@@ -25,13 +25,30 @@ only (``paged.AlignedWindows``), the SUMMARY kind a row a chunk of every
 window the slot has left (the block table at a stride). Prompts prefill in
 chunks straight into the arenas, as the MiMo family's.
 
+``SdarFamily`` (``SdarConfig``): generation by diffusion over blocks. One
+kind of cache (the block table alone), prompts prefilled in chunks straight
+into the arena, an expert layer with EVERY expert held, and a decode
+program that carries per-slot block state beside the arenas (the block's
+ids, which are still masked, the pass that revealed each): a dispatch is a
+fixed number of forward passes, and what it hands a slot is the blocks the
+slot committed in it, a variable number of tokens.
+
 What the engine asks of a family, beside its programs: ``rings(lookahead,
 engine_id)`` (the kind of cache that keeps a ring a slot beside the block
 table, or None), ``kv_stride`` (positions a row of the block table stands
 for), ``prefills_in_arena`` (every prompt in chunks straight into the
 arenas: no private cache, no adopt, no speculation or hand-off roles),
-``has_stats`` (the step and the prefill return three counters beside the
-tokens, which ``account`` reads).
+``has_stats`` (the step and the prefill return counters beside the tokens,
+which ``account`` reads), ``prefill_yields_token`` (a prompt's last position
+gives the first token; where not, the first token comes with the first
+block the decode program hands out), ``kv_ahead`` (positions past a slot's
+cursor that a step writes and reads: the block in flight), ``cursor_moves(
+chunk)`` (the most positions a dispatch of ``chunk`` steps moves a cursor).
+A step's fetched result is the tokens ``[slots, chunk]`` or a tuple
+``(tokens [slots, width], widths [slots], marks [slots, width], cursors
+[slots])``: how many of a row's tokens are real, a small integer beside each
+token (the pass of its block that revealed it) and where the device's
+cursors stand after the dispatch.
 """
 
 from __future__ import annotations
@@ -45,7 +62,8 @@ import jax.numpy as jnp
 from ..models.evabyte import EvaConfig
 from ..models.gpt import GptConfig, GptLM
 from ..models.mimo import FULL, WINDOW, MimoConfig
-from ..models import evabyte, mimo
+from ..models.sdar import SdarConfig
+from ..models import evabyte, mimo, sdar
 from ..runtime.metrics import METRICS
 from .paged import AlignedWindows, WindowRings
 
@@ -76,7 +94,20 @@ def _contiguous_cache(cfg: GptConfig, rows: int, cursor: str) -> Dict[str, Any]:
     }
 
 
-class GptFamily:
+class _TokenAStep:
+    """The answers of a family whose decode step yields one token a slot."""
+
+    #: a prompt's last position gives the request's first token
+    prefill_yields_token = True
+    #: a step writes and reads no position past its cursor
+    kv_ahead = 0
+
+    def cursor_moves(self, chunk: int) -> int:
+        """A dispatch of ``chunk`` steps moves a cursor by as many positions."""
+        return chunk
+
+
+class GptFamily(_TokenAStep):
     """``GptLM`` in the engine: the per-slot decode model, the scalar-cursor
     prefill model, their caches and the programs that move cache leaves."""
 
@@ -312,7 +343,7 @@ def _splice_rows(cache, small, slots, true_lens):
     return out
 
 
-class MimoFamily:
+class MimoFamily(_TokenAStep):
     """``models/mimo.py`` in the engine: two kinds of paged cache, prompts
     prefilled chunk by chunk straight into the arenas, expert counters."""
 
@@ -434,7 +465,7 @@ def _build_activate():
     return activate
 
 
-class EvaFamily:
+class EvaFamily(_TokenAStep):
     """``models/evabyte.py`` in the engine: the local and the summary kind
     of paged cache, prompts prefilled chunk by chunk straight into the
     arenas, a count of the summaries written riding home with the tokens."""
@@ -494,9 +525,135 @@ class EvaFamily:
         return _build_activate()
 
 
+class SdarFamily:
+    """``models/sdar.py`` in the engine: one kind of paged cache, prompts
+    prefilled chunk by chunk straight into the arena, and a decode program
+    of ``chunk`` forward passes in which every slot works on a block of its
+    own: it reveals, or it commits and starts the next."""
+
+    has_stats = True
+    prefills_in_arena = True
+    prefill_yields_token = False
+    kv_stride = 1
+
+    def __init__(self, cfg: SdarConfig, *, slots: int, paged: bool = True,
+                 kv_blocks: int = 0, kv_block_t: int = 16,
+                 kv_dtype: str = "bf16"):
+        if not paged or kv_dtype != "bf16":
+            raise ValueError("this model family prefills into a paged bf16 arena "
+                             "(paged=True, kv_dtype='bf16')")
+        self.cfg, self.slots, self.kv_block_t = cfg, slots, kv_block_t
+        self.kv_blocks = kv_blocks or slots * (cfg.max_seq // kv_block_t)
+        #: the block in flight lies past the cursor
+        self.kv_ahead = int(cfg.block_len)
+
+    def rings(self, lookahead: int, engine_id: str = "0") -> None:
+        """One kind of cache: no ring beside the block table."""
+        return None
+
+    def cursor_moves(self, chunk: int) -> int:
+        """A block takes a denoising pass and a commit at least: of
+        ``chunk`` passes at most every other one commits."""
+        return -(-chunk // 2) * self.cfg.block_len
+
+    def account(self, stats, tokens: int) -> Dict[str, int]:
+        """An event's counters (``models/sdar.STATS``) into the program's
+        own and the ``serving.engine.deliver`` region's stats."""
+        (on_held, busiest, touched, denoise, commit, blocks, revealed, pages) = stats
+        METRICS.counter("serving_moe_assignments_total", held="true").inc(on_held)
+        METRICS.counter("serving_block_forwards_total", kind="denoise").inc(denoise)
+        METRICS.counter("serving_block_forwards_total", kind="commit").inc(commit)
+        METRICS.counter("serving_blocks_committed_total").inc(blocks)
+        METRICS.counter("serving_tokens_revealed_total").inc(revealed)
+        return {"expert_tokens": on_held, "expert_tokens_max": busiest,
+                "experts_touched": touched, "forwards": denoise + commit,
+                "commit_forwards": commit, "blocks_committed": blocks,
+                "revealed": revealed, "blocks_read": pages}
+
+    def fresh_cache(self) -> Dict[str, Any]:
+        return sdar.fresh_cache(self.cfg, self.slots, self.kv_blocks, self.kv_block_t)
+
+    def build_step(self, chunk: int):
+        """``chunk`` passes for every slot. Fetched: the tokens of the
+        blocks each slot committed, first block's prompt tail left out
+        ``[slots, width]``; how many ``[slots]``; the pass that revealed
+        each; the cursors after the last pass; the counters."""
+        cfg, trash = self.cfg, self.kv_blocks
+        B = cfg.block_len
+        width = self.cursor_moves(chunk)
+
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 4))
+        def step(params, cache, tok, temps, rngs, table):
+            S = tok.shape[0]
+            put = jax.vmap(lambda row, new, at, do: jnp.where(
+                do, jax.lax.dynamic_update_slice(row, new, (at,)), row))
+
+            def one(carry, _):
+                cache, rngs, toks, marks, n_out, starts, stats = carry
+                pairs = jax.vmap(jax.random.split)(rngs)
+                rngs, keys = pairs[:, 0], pairs[:, 1]
+                cache, commit, ids, shown_at, skip, st = sdar.block_pass(
+                    cfg, params, cache, table, temps, keys, trash)
+                with jax.named_scope("hand_out"):
+                    toks = put(toks, ids, n_out * B, commit)
+                    marks = put(marks, shown_at, n_out * B, commit)
+                    starts = jnp.where(commit & (n_out == 0), skip, starts)
+                return (cache, rngs, toks, marks, n_out + commit, starts, stats + st), None
+
+            zeros = jnp.zeros((S,), jnp.int32)
+            (cache, rngs, toks, marks, n_out, starts, stats), _ = jax.lax.scan(
+                one, (cache, rngs, jnp.zeros((S, width), jnp.int32),
+                      jnp.zeros((S, width), jnp.int32), zeros, zeros,
+                      jnp.zeros((sdar.STATS,), jnp.int32)), None, length=chunk)
+            with jax.named_scope("hand_out"):
+                # a slot's tokens from its row's first column on
+                cols = jnp.mod(jnp.arange(width)[None, :] + starts[:, None], width)
+                out = (jnp.take_along_axis(toks, cols, axis=1), n_out * B - starts,
+                       jnp.take_along_axis(marks, cols, axis=1), cache["cursors"])
+            return cache, tok, rngs, out, stats
+
+        return step
+
+    def build_chunk_prefill(self):
+        cfg = self.cfg
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def prefill_chunk(params, cache, ids, start, n_valid, temperature, key,
+                          read, write):
+            opening, cache, stats = sdar.prefill_chunk(
+                cfg, params, cache, ids, start, n_valid, read, write)
+            return cache, opening, stats
+
+        return prefill_chunk
+
+    def build_activate(self):
+        cfg = self.cfg
+        B = cfg.block_len
+
+        @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+        def activate(cache, last_tok, temps, rngs, slot, true_len, opening,
+                     temperature, key):
+            """A prefilled row joins the decode batch: its cursor behind the
+            prompt's whole blocks, the block its tail opens, its sampling
+            state."""
+            tail = jnp.mod(true_len, B)
+            cache = dict(
+                cache, cursors=cache["cursors"].at[slot].set(true_len - tail),
+                block_ids=cache["block_ids"].at[slot].set(opening),
+                masked=cache["masked"].at[slot].set(jnp.arange(B) >= tail),
+                revealed_at=cache["revealed_at"].at[slot].set(0),
+                passes=cache["passes"].at[slot].set(0),
+                skip=cache["skip"].at[slot].set(tail))
+            return (cache, last_tok, temps.at[slot].set(temperature),
+                    rngs.at[slot].set(key))
+
+        return activate
+
+
 _FAMILIES: Tuple[Tuple[type, type], ...] = ((GptConfig, GptFamily),
                                             (MimoConfig, MimoFamily),
-                                            (EvaConfig, EvaFamily))
+                                            (EvaConfig, EvaFamily),
+                                            (SdarConfig, SdarFamily))
 
 
 def family_for(cfg: Any, **geometry: Any):

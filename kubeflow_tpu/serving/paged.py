@@ -347,6 +347,15 @@ class SlotKV:
     ``tokens // stride`` rows, and is READ only once the cursor has left its
     window, so what a step reads (:meth:`_rows_read`) lags what is written.
 
+    ``ahead``: positions past a slot's cursor that a step writes and reads.
+    0 where a step appends the position at its cursor. Over 0 is a family
+    that works on a BLOCK of ``ahead`` positions for several steps before
+    the cursor passes it: the block in flight is written many times, so its
+    pages are granted before the first write (the frontier a dispatch is
+    granted to is its cursor bound plus ``ahead``), and the cursor moves by
+    whole blocks, by a data-dependent count a dispatch, so the engine
+    brings the bound down (:meth:`settle`) once the device's cursor is known.
+
     A slot's life, in the engine's verbs: :meth:`check` at submit;
     :meth:`reserve` before any compute is spent; :meth:`hold` once the
     request has a slot (its row stays on trash); :meth:`bind` when the
@@ -359,9 +368,10 @@ class SlotKV:
 
     def __init__(self, slots: int, max_seq: int, block_t: int, n_blocks: int,
                  *, engine_id: str = "0",
-                 rings: Optional[WindowRings] = None, stride: int = 1):
+                 rings: Optional[WindowRings] = None, stride: int = 1,
+                 ahead: int = 0):
         self.slots, self.max_seq, self.block_t = int(slots), int(max_seq), int(block_t)
-        self.engine_id, self.stride = engine_id, int(stride)
+        self.engine_id, self.stride, self.ahead = engine_id, int(stride), int(ahead)
         self.rings = rings
         self.kind = ("" if rings is None else "full" if self.stride == 1 else "summary")
         self.alloc = KVBlockAllocator(n_blocks, block_t, engine_id=engine_id,
@@ -480,7 +490,16 @@ class SlotKV:
             self._origin[slot], self._cursor[slot] = cursor, ub
             if self.rings is not None:
                 self.rings.advance(slot, cursor, ub)
-            self._grant_into(self.tables[slot], res, ub)
+            self._grant_into(self.tables[slot], res, ub + self.ahead)
+
+    def settle(self, slot: int, bound: int) -> None:
+        """The device's cursor of ``slot`` is known to stay at or under
+        ``bound`` at the dispatch frontier (what an earlier dispatch left,
+        plus the most the dispatches in flight can move it): the bound that
+        :meth:`advance` grants from comes down to it. Nothing granted goes
+        back."""
+        if slot in self._res:
+            self._cursor[slot] = min(int(self._cursor[slot]), int(bound))
 
     def _grant_into(self, row: np.ndarray, res: KVReservation, tokens: int) -> None:
         """Grant ``res`` the blocks ``tokens`` positions need and put the new
@@ -540,8 +559,8 @@ class SlotKV:
         the arenas: the row's own table up to the narrowest view width
         covering ``end``, the full-kind blocks the chunk writes (a row a
         position only: the summary kind's program finds its rows in the
-        table), the ring as the previous chunk left it, the ring blocks the
-        chunk writes. Grants the chunk its blocks of both kinds first; the
+        table), and with a ring kind the ring as the previous chunk left it
+        and the ring blocks the chunk writes. Grants the chunk its blocks of both kinds first; the
         ring's older blocks go back before the new ones are granted, so the
         chunk writes only what the next reader (the next chunk, or decode)
         can still see."""
@@ -553,7 +572,12 @@ class SlotKV:
         self._grant_into(table, res, end)
         held, first_block = self.blocks_for(end), start // bt
         view = next(w for w in self.view_widths if w >= held)
+        write_full = np.full((chunk // bt,), trash, np.int32)
+        if self.stride == 1:
+            write_full[:held - first_block] = table[first_block:held]
         rings = self.rings
+        if rings is None:
+            return table[:view], write_full
         read_ring = rings.row(slot).copy()
         rings.advance(slot, end, end)
         write_ring = np.asarray(
@@ -561,8 +585,6 @@ class SlotKV:
             np.int32)
         if self.stride > 1:
             return table[:view], read_ring, write_ring
-        write_full = np.full((chunk // bt,), trash, np.int32)
-        write_full[:held - first_block] = table[first_block:held]
         return table[:view], write_full, read_ring, write_ring
 
 
@@ -589,4 +611,4 @@ class ContiguousKV:
     def _nothing(self, *args: Any) -> None:
         pass
 
-    check = hold = release = advance = _nothing
+    check = hold = release = advance = settle = _nothing

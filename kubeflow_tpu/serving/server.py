@@ -161,7 +161,8 @@ class ModelServer:
     def _predict(self, model: ServedModel, instances,
                  deadline: Optional[float] = None,
                  priority: str = "interactive",
-                 model_id: Optional[str] = None) -> List[Any]:
+                 model_id: Optional[str] = None,
+                 reveal_passes: Optional[List[Any]] = None) -> List[Any]:
         from .batching import BatcherClosed
 
         batcher = self._batchers.get(model.name)
@@ -175,7 +176,8 @@ class ModelServer:
                 pass
         if isinstance(model, GenerativeModel):
             return model.predict(instances, deadline=deadline,
-                                 priority=priority, model=model_id)
+                                 priority=priority, model=model_id,
+                                 reveal_passes=reveal_passes)
         return model.predict(instances)
 
     def close(self) -> None:
@@ -214,13 +216,19 @@ class ModelServer:
             deadline, priority = request_deadline_opts(req, body)
             # multiplexed servables route on the body's "model" id
             model_id = body.get("model") if isinstance(body, dict) else None
+            # "reveal_passes": true asks, beside each generated token, for
+            # the forward pass of its block that revealed it (a family that
+            # generates by unmasking blocks; empty rows from any other)
+            passes: Optional[List[Any]] = (
+                [] if isinstance(body, dict) and body.get("reveal_passes") else None)
 
             t0 = time.perf_counter()
             try:
                 predictions = self._predict(model, instances,
                                             deadline=deadline,
                                             priority=priority,
-                                            model_id=model_id)
+                                            model_id=model_id,
+                                            reveal_passes=passes)
             except HttpError:
                 raise
             except DeadlineExceeded as e:
@@ -233,7 +241,9 @@ class ModelServer:
             METRICS.histogram("serving_predict_seconds", model=model.name).observe(
                 time.perf_counter() - t0
             )
-            return {"predictions": predictions}
+            if passes is None:
+                return {"predictions": predictions}
+            return {"predictions": predictions, "reveal_passes": passes}
 
     def serve(self, port: int = 0):
         return self.app.serve(port)
@@ -352,7 +362,11 @@ class GenerativeModel(ServedModel):
     def predict(self, instances: Sequence[Any],
                 deadline: Optional[float] = None,
                 priority: str = "interactive",
-                model: Optional[str] = None) -> List[Any]:
+                model: Optional[str] = None,
+                reveal_passes: Optional[List[Any]] = None) -> List[Any]:
+        """``reveal_passes``, where given, is filled with a row a prompt:
+        the engine's mark beside each generated token (the forward pass of
+        its block that revealed it, from a family that hands one out)."""
         from kubeflow_tpu.models.gpt import generate
 
         if not instances:
@@ -422,6 +436,8 @@ class GenerativeModel(ServedModel):
                     remaining = max(0.0, deadline - time.monotonic())
                     out.append(row.tolist()
                                + f.result(timeout=remaining + DEADLINE_GRACE_S))
+                    if reveal_passes is not None:
+                        reveal_passes.append(list(f.reveal_passes))
                 return out
             except FleetSaturated as e:
                 raise HttpError(503, f"fleet saturated: {e}",

@@ -253,6 +253,71 @@ def test_eva_decode_program_copies_no_arena(chip, monkeypatch):
     assert memory.temp_size_in_bytes < 64e6 and 12.5e9 < memory.argument_size_in_bytes < 13.5e9
 
 
+@pytest.mark.parametrize("columns", [64, 128, 192])
+def test_paged_decode_attention_sdar_block_shape(chip, columns):
+    """The SDAR cell's block attention: 64 slots, each 4 positions x 32
+    query heads = 128 query rows laid apart over 4 KV heads of 128, pages of
+    16 rows of 512, a view of ``columns`` pages (quarters of a row of 256;
+    the mix's longest row, 2,304 positions, reaches the third)."""
+    def attend(q, k, v, table, lengths):
+        return paged_decode_attention(q, k, v, table, lengths, scale=128 ** -0.5,
+                                      kv_heads=4, interpret=False)
+
+    shapes = [((64, 128, 512), BF16), ((9217, 16, 512), BF16), ((9217, 16, 512), BF16),
+              ((64, columns), I32), ((64,), I32)]
+    assert _compile(chip, attend, *shapes) == 1
+
+
+def test_sdar_programs_fit_the_chip_and_copy_no_arena(chip, monkeypatch):
+    """The cell's two device programs at the published widths (6 layers, all
+    128 experts, the whole vocabulary, 64 slots, 9,216 pages): the dispatch
+    of 16 block passes holds the kernel once a layer and moves no arena into
+    another layout; the chunk program of 2,048 rows holds ``chunk_attention``
+    once a layer and no head; weights and arenas are 10.5 GB, the
+    temporaries half a gigabyte (the logits of 256 rows over 151,936 ids and
+    the sorted expert rows), under the chip's 16."""
+    import re
+
+    from kubeflow_tpu.models import sdar
+    from kubeflow_tpu.ops import chunk_attention as chunk_module, paged_attention
+    from kubeflow_tpu.serving.family import SdarFamily
+
+    monkeypatch.setattr(paged_attention, "_interpret_default", lambda: False)
+    monkeypatch.setattr(chunk_module, "_interpret_default", lambda: False)
+    cfg = sdar.SdarConfig()
+    family = SdarFamily(cfg, slots=64, kv_blocks=9216, kv_block_t=16)
+    assert family.cursor_moves(16) == 32 and family.kv_ahead == 4
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+
+    one = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    params = described(jax.eval_shape(lambda: sdar.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = described(jax.eval_shape(family.fresh_cache))
+    step = family.build_step(16).lower(
+        params, cache, one((64,), I32), one((64,), F32), one((64, 2), jnp.uint32),
+        one((64, 192), I32)).compile()
+    text = step.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and "paged_decode_attention" in line]
+    assert len(kernels) == cfg.n_layers
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= bf16\[\d+,16,512\]\S* copy(-start)?\(", line)]
+    assert not copies, copies
+    memory = step.memory_analysis()
+    assert 10.3e9 < memory.argument_size_in_bytes < 10.8e9
+    assert memory.temp_size_in_bytes < 0.7e9
+    chunk = family.build_chunk_prefill().lower(
+        params, cache, one((2048,), I32), one((), I32), one((), I32), one((), F32),
+        one((2,), jnp.uint32), one((128,), I32), one((128,), I32)).compile()
+    text = chunk.as_text()
+    assert sum('custom_call_target="tpu_custom_call"' in line and "chunk_attention" in line
+               for line in text.splitlines()) == cfg.n_layers
+    assert chunk.memory_analysis().temp_size_in_bytes < 0.7e9
+
+
 def test_composite_step_stacks_no_scores_over_the_layers(topo):
     """``composite.make_train_step`` at the sizes of the cell
     ``gpt2-large.train4.fsdp2-tp2`` (36 layers of 1,280, two sequences of

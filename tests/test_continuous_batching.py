@@ -591,3 +591,87 @@ def test_kv_wire_frame_round_trip_and_crc():
     bad[-1] ^= 0xFF
     with pytest.raises(ValueError, match="crc"):
         unpack(bytes(bad))
+
+
+# -- an event's width: a chunk, a speculative round, the blocks a slot committed ------
+
+def _event_engine(kind, params):
+    """(engine, tokens the request's FIRST event brings, prompt)."""
+    if kind == "blocks":
+        from kubeflow_tpu.models import sdar
+
+        cfg = sdar.SdarConfig.tiny()
+        tree = sdar.init_params(cfg, jax.random.PRNGKey(3))
+        # a prompt of 10: its tail of 2 opens the first block, which hands out 2
+        return (ContinuousBatcher(cfg, tree, slots=2, chunk=5, kv_block_t=4, prefill_chunk=16),
+                2, np.arange(1, 11, dtype=np.int32))
+    kw = {}
+    if kind == "speculative":
+        draft_cfg = _self_draft()
+        rng = jax.random.PRNGKey(42)
+        kw = {"spec_draft": (draft_cfg, GptLM(draft_cfg).init(
+            rng, jax.random.randint(rng, (1, 8), 0, CFG.vocab_size))["params"]), "spec_k": 4}
+    return ContinuousBatcher(CFG, params, slots=2, chunk=4, **kw), 1, prompt(5, 10)
+
+
+@pytest.mark.parametrize("kind", ["token_a_step", "speculative", "blocks"])
+def test_an_events_width_ttft_and_gaps(params, kind):
+    """Whatever the event's width is (a whole chunk, a round's accepted
+    prefix, the blocks a slot committed): a request gets exactly its budget
+    (the surplus of its last event is discarded), TTFT is stamped once, at
+    the event that brings its first token, and every token after that
+    event's counts one gap. Only a speculative round counts drafts."""
+    from kubeflow_tpu.runtime.metrics import METRICS
+
+    count = lambda name: (METRICS.histogram_counts(name) or (0, 0, 0))[2]
+    eng, first, p = _event_engine(kind, params)
+    ttft, gaps = count("serving_ttft_seconds"), count("serving_inter_token_seconds")
+    out0 = METRICS.total("serving_tokens_out_total")
+    drafted0 = METRICS.total("serving_spec_tokens_drafted_total")
+    try:
+        fut = eng.submit(p, 11)
+        toks = fut.result(timeout=600)
+    finally:
+        eng.close()
+    assert len(toks) == 11 and fut.finish_reason == "ok"
+    assert count("serving_ttft_seconds") == ttft + 1
+    assert count("serving_inter_token_seconds") == gaps + 11 - first
+    assert METRICS.total("serving_tokens_out_total") == out0 + 11
+    assert (METRICS.total("serving_spec_tokens_drafted_total") > drafted0) == (
+        kind == "speculative")
+    assert len(fut.reveal_passes) == (11 if kind == "blocks" else 0)
+
+
+@pytest.mark.parametrize("family", ["blocks", "token_a_step"])
+def test_the_reply_carries_the_reveal_passes_when_the_request_asks(params, family):
+    """``"reveal_passes": true`` in the body: a row a prompt beside
+    ``predictions``, the engine's mark beside each generated token from a
+    family that hands one out, empty from one that does not; a body that
+    does not ask gets ``predictions`` alone."""
+    from kubeflow_tpu.models import sdar
+    from kubeflow_tpu.serving.server import GenerativeModel, ModelServer
+
+    if family == "blocks":
+        cfg = sdar.SdarConfig.tiny()
+        tree, kw = sdar.init_params(cfg, jax.random.PRNGKey(3)), {"kv_block_t": 4,
+                                                                  "prefill_chunk": 16}
+    else:
+        cfg, tree, kw = CFG, params, {}
+    served = GenerativeModel(name="m", apply_fn=None, params=tree, cfg=cfg,
+                             max_new_tokens=6, slots=2, **kw)
+    server = ModelServer()
+    server.add(served)
+    try:
+        body = {"instances": [list(range(1, 8))]}
+        plain = server.app.call("POST", "/v1/models/m:predict", body)
+        asked = server.app.call("POST", "/v1/models/m:predict", {**body, "reveal_passes": True})
+    finally:
+        served.close()
+    assert plain.status == asked.status == 200, (plain.body, asked.body)
+    assert set(plain.body) == {"predictions"}
+    assert asked.body["predictions"] == plain.body["predictions"]
+    (marks,) = asked.body["reveal_passes"]
+    if family == "blocks":
+        assert len(marks) == 6 and all(1 <= m <= 4 for m in marks)
+    else:
+        assert marks == []

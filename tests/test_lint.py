@@ -183,6 +183,9 @@ F32_MATMUL_ALLOWLIST = {
     # states in float32 (16 keys a chunk: a thousandth of a layer's work)
     ("evabyte.py", "_head"),
     ("evabyte.py", "summarise"),
+    # the SDAR family: the float32 logits head (bf16 operands, float32
+    # accumulation); its router's float32 product lives in parallel/moe.py
+    ("sdar.py", "_head"),
 }
 
 _MATMUL_CALLEES = {"einsum", "matmul", "dot", "tensordot", "dot_general"}

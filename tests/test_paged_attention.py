@@ -198,3 +198,48 @@ def test_stats_do_not_change_the_output():
     run = functools.partial(paged_decode_attention, q, keys, vals, table, jnp.asarray([37, 9]),
                             scale=qk ** -0.5, kv_heads=KV, pages=PAGES)
     assert np.array_equal(run(), run(stats=True)[0])
+
+
+# -- a block of query positions a slot: ``block x heads`` rows --------------------
+
+BLOCK = 4
+#: cursors (whole blocks) of the rows: a dead row, the first block of a
+#: sequence, a block at a page's start, in its middle and at its end, past a
+#: page group, and at the end of the table
+CURSORS = (None, 0, 16, 36, 44, SPAN + 8, COLS * BT - BLOCK)
+
+
+@functools.lru_cache(maxsize=None)
+def block_case():
+    """Every slot brings BLOCK query positions x (KV x GROUP) heads, laid out
+    as ``models/sdar.py`` does (a KV head's BLOCK x GROUP rows together), and
+    reads its own pages up to ``cursor + BLOCK`` with no mask inside the
+    block: (kernel's output [slots, BLOCK, heads, dv], a dense reference)."""
+    qk = dv = 16
+    rng = np.random.default_rng(11)
+    slots, blocks = len(CURSORS), len(CURSORS) * COLS
+    table = rng.permutation(blocks).astype(np.int32).reshape(slots, COLS)
+    keys = jnp.asarray(rng.normal(size=(blocks + 1, BT, KV * qk)), jnp.bfloat16)
+    vals = jnp.asarray(rng.normal(size=(blocks + 1, BT, KV * dv)), jnp.bfloat16)
+    q = jnp.asarray(rng.normal(size=(slots, BLOCK, KV, GROUP, qk)), jnp.bfloat16)
+    lengths = np.asarray([0 if c is None else c + BLOCK for c in CURSORS], np.int32)
+    rows = jnp.swapaxes(q, 1, 2).reshape(slots, KV, BLOCK * GROUP, qk)
+    got = paged_decode_attention(
+        _heads_apart(rows, KV), keys, vals, jnp.asarray(table), jnp.asarray(lengths),
+        scale=qk ** -0.5, kv_heads=KV, pages=PAGES)
+    got = jnp.swapaxes(got.reshape(slots, KV, BLOCK, GROUP, dv), 1, 2)
+    want = np.stack([plain(q[:, t].reshape(slots, KV * GROUP, qk), keys, vals, table, lengths,
+                           qk ** -0.5) for t in range(BLOCK)], axis=1)
+    return np.asarray(got).reshape(slots, BLOCK, KV * GROUP, dv), want
+
+
+@pytest.mark.parametrize("row", range(len(CURSORS)), ids=[f"cursor{c}" for c in CURSORS])
+def test_a_block_of_queries_reads_its_row_up_to_the_blocks_end(row):
+    """Each of a slot's BLOCK positions against a dense softmax over ``[0,
+    cursor + BLOCK)``: the block's own later positions included, whatever
+    the position of the query inside it."""
+    got, want = block_case()
+    assert got.shape == want.shape
+    if CURSORS[row] is None:
+        assert not got[row].any()
+    assert np.abs(got[row] - want[row]).max() <= 0.01 * max(1.0, np.abs(want[row]).max())

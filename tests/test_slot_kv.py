@@ -399,3 +399,78 @@ def test_the_contiguous_twin_takes_nothing_and_hands_out_nothing():
     assert kv.dispatch_tables([0]) == ((), {})
     assert kv.warm_tables() == [] and kv.block_t == 0
     kv.release(0), kv.release(res)
+
+
+# -- a block in flight past the cursor (``ahead``): the fourth family's kind ----------
+
+BLOCK = 4                              # a block of 4 positions, pages of 4: a page a block
+
+
+def block_owner(n_blocks=SLOTS * MAX_SEQ // BT):
+    return SlotKV(SLOTS, MAX_SEQ, BT, n_blocks, engine_id="blocks", ahead=BLOCK)
+
+
+@pytest.mark.parametrize("prompt,moves,granted", [(8, 4, 4), (9, 4, 5), (11, 12, 7), (3, 4, 3)],
+                         ids=["tail_0", "tail_1", "three_blocks_a_dispatch", "short_prompt"])
+def test_the_block_in_flight_is_granted_before_its_first_write(prompt, moves, granted):
+    """``bind`` leaves the bound at the prompt's length (the device's cursor
+    stands behind the prompt's whole blocks, at or under it); a dispatch
+    that may move the cursor by ``moves`` positions is granted up to the
+    bound + moves + the block in flight, BEFORE it snapshots the table: the
+    positions ``cursor .. cursor + 3`` are written at every pass."""
+    kv = block_owner()
+    res = kv.reserve(prompt + 40)
+    kv.bind([0], [res], [prompt])
+    assert len(res.granted) == -(-prompt // BT)
+    kv.advance([0], moves)
+    assert len(res.granted) == granted == -(-(prompt + moves + BLOCK) // BT)
+    assert list(kv.tables[0, :granted]) == res.granted
+    assert (kv.tables[0, granted:] == kv.alloc.trash).all()
+    kv.release(0)
+    assert whole(kv)
+
+
+def test_settle_brings_the_bound_down_and_gives_nothing_back():
+    """The device's cursor moves by what the slots committed, at most
+    ``moves`` a dispatch: the host grants from an upper bound and the engine
+    settles it once an event says where the cursor stood. Later grants then
+    start from the settled bound; what was granted stays."""
+    kv = block_owner()
+    res = kv.reserve(8 + 48)
+    kv.bind([2], [res], [8])
+    for _ in range(3):
+        kv.advance([2], 12)                      # the bound: 8 + 36, plus the block: 12 pages
+    assert len(res.granted) == 12
+    kv.settle(2, 8 + 3 * 4)                      # one block a dispatch was committed
+    kv.advance([2], 12)
+    assert len(res.granted) == 12                # 20 + 12 + 4 = 36 positions: 9 pages, under 12
+    kv.settle(2, 10_000)                         # never up
+    kv.advance([2], 12)
+    assert len(res.granted) == 12                # 32 + 12 + 4 = 48: 12 pages
+    kv.advance([2], 12)
+    assert len(res.granted) == res.total == 14   # capped at the reservation
+    kv.settle(1, 4)                              # a slot that holds nothing: a no-op
+    kv.release(2)
+    assert whole(kv)
+
+
+def test_chunk_tables_of_one_kind_name_the_view_and_the_pages_to_write():
+    """A family that prefills straight into the arena and keeps no ring: a
+    chunk's tables are the row's own view and the pages the chunk writes,
+    trash behind the prompt's end; the shared row stays on trash until
+    bind."""
+    kv = block_owner()
+    res = kv.reserve(23 + 9)
+    kv.hold(1, res)
+    view, write = kv.chunk_tables(1, 0, 16, 16)
+    assert view.shape == (4,) and list(view) == res.granted[:4] and list(write) == res.granted[:4]
+    view, write = kv.chunk_tables(1, 16, 23, 16)
+    assert view.shape == (8,) and list(view[:6]) == res.granted[:6]
+    assert list(write) == res.granted[4:6] + [kv.alloc.trash] * 2
+    assert (kv.tables == kv.alloc.trash).all()
+    kv.bind([1], [res], [23])
+    assert list(kv.tables[1, :6]) == res.granted[:6]
+    tables, stats = kv.dispatch_tables([1])
+    assert len(tables) == 1 and stats == {"view_blocks": 8, "max_blocks": 16}
+    kv.release(1)
+    assert whole(kv)
