@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from kubeflow_tpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
 from kubeflow_tpu.parallel.mesh import AXIS_EXPERT, BATCH_AXES
 
 
@@ -179,8 +180,9 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
 
     Every assignment has a row of its own in a [T * k] buffer sorted by
     expert (assignments elsewhere sort past the last group), so no skew
-    drops a token; ``jax.lax.ragged_dot`` multiplies each group by its
-    expert and leaves the rows past the groups alone.
+    drops a token; the grouped products (``ops/grouped_matmul.py``) read
+    each touched expert's matrices once, multiply each group by its expert
+    and never visit the rows past the groups.
     """
     T, k = idx.shape
     n_held = w_gate.shape[0]
@@ -196,13 +198,9 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
         rows = h[order // k]                                     # [T*k, d]
         in_group = jnp.arange(T * k) < jnp.sum(sizes)
     with jax.named_scope("moe_experts"):
-        gate = jax.lax.ragged_dot(rows, w_gate, sizes,
-                                  preferred_element_type=jnp.float32)
-        up = jax.lax.ragged_dot(rows, w_up, sizes,
-                                preferred_element_type=jnp.float32)
-        mid = (jax.nn.silu(gate) * up).astype(h.dtype)
-        out = jax.lax.ragged_dot(mid, w_down, sizes,
-                                 preferred_element_type=jnp.float32)
+        mid = grouped_swiglu(rows, w_gate, w_up, sizes)          # in h's type
+        out = grouped_matmul(mid, w_down, sizes,
+                             preferred_element_type=jnp.float32)
     with jax.named_scope("moe_combine"):
         out = jnp.where(in_group[:, None], out, 0.0)
         back = jnp.argsort(order)                                # inverse

@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 from kubeflow_tpu.ops.chunk_attention import chunk_attention
 from kubeflow_tpu.ops.flash_attention import flash_attention
 from kubeflow_tpu.ops.fused_bottleneck import fused_bottleneck, fused_transition
+from kubeflow_tpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
 from kubeflow_tpu.ops.paged_attention import paged_decode_attention
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -55,6 +56,14 @@ def _compile(chip, fn, *shapes):
             for shape, dtype in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     return text.count('custom_call_target="tpu_custom_call"')
+
+
+def _grouped_kernels(text):
+    """The expert layers' grouped products in a compiled program: the gated
+    pair and the down product, a Mosaic kernel each."""
+    return sum('custom_call_target="tpu_custom_call"' in line
+               and ("grouped_swiglu" in line or "grouped_matmul" in line)
+               for line in text.splitlines())
 
 
 def _bottleneck_shapes(n, hw, cin, cmid, cout, proj):
@@ -140,11 +149,12 @@ def test_mimo_decode_program_copies_no_arena(chip, monkeypatch):
     import re
 
     from kubeflow_tpu.models import mimo
-    from kubeflow_tpu.ops import paged_attention
+    from kubeflow_tpu.ops import grouped_matmul as grouped_module, paged_attention
     from kubeflow_tpu.serving.family import MimoFamily
 
-    # the backend here is the CPU, where the kernel would run interpreted
+    # the backend here is the CPU, where the kernels would run interpreted
     monkeypatch.setattr(paged_attention, "_interpret_default", lambda: False)
+    monkeypatch.setattr(grouped_module, "_interpret_default", lambda: False)
     cfg = mimo.MimoConfig()
     family = MimoFamily(cfg, slots=32, kv_blocks=16384, kv_block_t=16)
     rings = family.rings(16)
@@ -164,6 +174,7 @@ def test_mimo_decode_program_copies_no_arena(chip, monkeypatch):
                if 'custom_call_target="tpu_custom_call"' in line
                and "paged_decode_attention" in line]
     assert len(kernels) == 2
+    assert _grouped_kernels(text) == 2 * sum(cfg.moe_layers) and "ragged-dot" not in text
     arena = r"bf16\[\d+,16,(768|512|1536|1024)\]"
     copies = [line.strip()[:160] for line in text.splitlines()
               if re.search(rf"= {arena}\S* copy(-start)?\(", line)]
@@ -279,11 +290,13 @@ def test_sdar_programs_fit_the_chip_and_copy_no_arena(chip, monkeypatch):
     import re
 
     from kubeflow_tpu.models import sdar
-    from kubeflow_tpu.ops import chunk_attention as chunk_module, paged_attention
+    from kubeflow_tpu.ops import (chunk_attention as chunk_module,
+                                  grouped_matmul as grouped_module, paged_attention)
     from kubeflow_tpu.serving.family import SdarFamily
 
     monkeypatch.setattr(paged_attention, "_interpret_default", lambda: False)
     monkeypatch.setattr(chunk_module, "_interpret_default", lambda: False)
+    monkeypatch.setattr(grouped_module, "_interpret_default", lambda: False)
     cfg = sdar.SdarConfig()
     family = SdarFamily(cfg, slots=64, kv_blocks=9216, kv_block_t=16)
     assert family.cursor_moves(16) == 32 and family.kv_ahead == 4
@@ -303,6 +316,7 @@ def test_sdar_programs_fit_the_chip_and_copy_no_arena(chip, monkeypatch):
                if 'custom_call_target="tpu_custom_call"' in line
                and "paged_decode_attention" in line]
     assert len(kernels) == cfg.n_layers
+    assert _grouped_kernels(text) == 2 * cfg.n_layers and "ragged-dot" not in text
     copies = [line.strip()[:160] for line in text.splitlines()
               if re.search(r"= bf16\[\d+,16,512\]\S* copy(-start)?\(", line)]
     assert not copies, copies
@@ -315,7 +329,27 @@ def test_sdar_programs_fit_the_chip_and_copy_no_arena(chip, monkeypatch):
     text = chunk.as_text()
     assert sum('custom_call_target="tpu_custom_call"' in line and "chunk_attention" in line
                for line in text.splitlines()) == cfg.n_layers
+    # no head reads the last layer's output, so its experts are not computed
+    assert _grouped_kernels(text) == 2 * (cfg.n_layers - 1) and "ragged-dot" not in text
     assert chunk.memory_analysis().temp_size_in_bytes < 0.7e9
+
+
+@pytest.mark.parametrize("rows,groups,d,f", [
+    (2048, 128, 2048, 768), (16384, 128, 2048, 768),
+    (256, 16, 4096, 2048), (16384, 16, 4096, 2048)],
+    ids=["sdar_dispatch", "sdar_chunk", "mimo_step", "mimo_chunk"])
+def test_grouped_products_held_experts_shapes(chip, rows, groups, d, f):
+    """The four shapes ``held_experts_ffn`` hands the kernel: SDAR's 128
+    experts of 2,048 x 768 (matrices whole, 3.1 MB a block) and MiMo's 16
+    held of 4,096 x 2,048 (column blocks of 4.2 MB), a dispatch's and a
+    prefill chunk's assignment rows."""
+    def ffn(x, w_gate, w_up, w_down, sizes):
+        mid = grouped_swiglu(x, w_gate, w_up, sizes, interpret=False)
+        return grouped_matmul(mid, w_down, sizes, interpret=False)
+
+    shapes = [((rows, d), BF16), ((groups, d, f), BF16), ((groups, d, f), BF16),
+              ((groups, f, d), BF16), ((groups,), I32)]
+    assert _compile(chip, ffn, *shapes) == 2
 
 
 def test_composite_step_stacks_no_scores_over_the_layers(topo):
