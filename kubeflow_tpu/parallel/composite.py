@@ -53,8 +53,12 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..tpu import profiling
 from .mesh import AXIS_FSDP, AXIS_MODEL, AXIS_PIPE, BATCH_AXES
 from .pipeline import deinterleave_stage_params, interleave_stage_params, pipeline_apply
+
+# the sharded weights are made before ``make_train_step``: their programs count
+profiling.watch_compiles()
 
 GATHER_MODES = ("eager", "overlap", "amortized")
 

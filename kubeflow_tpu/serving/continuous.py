@@ -51,6 +51,9 @@ from .errors import (DeadlineExceeded, EngineClosed, FleetSaturated,
 from .family import family_for
 from .paged import ContiguousKV, KVReservation, SlotKV
 
+# the programs a server compiles before it builds its engine are counted too
+profiling.watch_compiles()
+
 #: admission priority classes; batch is shed first under saturation
 PRIORITIES = ("interactive", "batch")
 
@@ -208,6 +211,12 @@ class _Request:
 def _ev(req: _Request, name: str, **attrs: Any) -> None:
     if req.span is not None:
         req.span.add_event(name, **attrs)
+
+
+def _compile_counts() -> Dict[str, float]:
+    """``xla_compiles_total`` by outcome (``tpu.profiling.watch_compiles``)."""
+    return {outcome: METRICS.value("xla_compiles_total", outcome=outcome)
+            for outcome in ("compiled", "loaded")}
 
 
 def _trace_id(req: _Request) -> Optional[str]:
@@ -369,6 +378,7 @@ class ContinuousBatcher:
         exported KV manifests so a decode replica can refuse a wire blob
         from the wrong model.
         """
+        built_from = time.time_ns()
         self.cfg = cfg
         self.params = params
         self.slots = slots
@@ -512,10 +522,37 @@ class ContinuousBatcher:
         self._worker = threading.Thread(target=self._loop, name="continuous-batcher",
                                         daemon=True)
         self._worker.start()
+        # arenas, tables and the family's program builders (nothing compiles
+        # before a program's first call): a replica's start, before prewarm
+        TRACER.emit_span("serving.engine.build", built_from, time.time_ns(),
+                         replica=self.engine_id, slots=slots)
 
     # -- compiled pieces -----------------------------------------------------
     # (the family's: serving/family.py builds every program that knows what
     # a cache leaf is; the engine keeps the ones that only sequence models)
+    def _launch(self, program: str, fn: Callable[..., Any], *args: Any,
+                first: Optional[_Request] = None) -> Any:
+        """Every call of a device program on the engine thread: one
+        ``serving.engine.launch`` region, the innermost of its phase, so an
+        idle gap of the device is put down to the launch of a named
+        ``program``; a call that compiled or loaded an executable says so
+        itself (``compiles``). The call returns when the program is
+        enqueued, which is late where the device's queue is full. ``first``:
+        the first request of a batched prefill, whose trace the call's wall
+        time is filed under in ``serving_prefill_seconds``."""
+        seen = profiling.compiles_seen()
+        with profiling.annotate("serving.engine.launch", program=program) as region:
+            t0 = time.perf_counter()
+            out = fn(*args)
+            if first is not None:
+                METRICS.histogram(
+                    "serving_prefill_seconds", buckets=PREFILL_BUCKETS_S
+                ).observe(time.perf_counter() - t0, trace_id=_trace_id(first))
+            compiles = profiling.compiles_seen() - seen
+            if compiles:
+                region.set_metadata(compiles=compiles)
+        return out
+
     def _build_spec_step(self):
         """One speculative round: the draft model greedily proposes
         ``spec_k`` tokens (``spec_k - 1`` of them verifiable), the target
@@ -583,7 +620,8 @@ class ContinuousBatcher:
 
     def _prefill_group(self, prompts: Sequence[np.ndarray],
                        temperatures: Sequence[float], keys,
-                       draft: bool = False) -> Tuple[Any, Any]:
+                       draft: bool = False,
+                       first: Optional[_Request] = None) -> Tuple[Any, Any]:
         """ONE batched prefill for a same-length-bucket admission group:
         [n_pad, bucket] prompt forward on a reused zero [n_pad, max_seq]
         cache (shared cursor 0 — every row starts at position 0), padded
@@ -632,10 +670,12 @@ class ContinuousBatcher:
         if keys.shape[0] != n_pad:  # pad the key rows (unused rows ignored)
             keys = jnp.concatenate(
                 [keys, jnp.zeros((n_pad - n, 2), keys.dtype)], axis=0)
-        return self._prefill_fns[(bucket, n_pad, draft)](
+        return self._launch(
+            "draft_prefill" if draft else "prefill",
+            self._prefill_fns[(bucket, n_pad, draft)],
             self._draft_params if draft else self.params, small,
             jnp.asarray(ids), jnp.asarray(true_lens),
-            jnp.asarray(temps), keys)
+            jnp.asarray(temps), keys, first=first)
 
     # -- public API ----------------------------------------------------------
     def submit(self, prompt_ids, max_new_tokens: int,
@@ -772,7 +812,10 @@ class ContinuousBatcher:
         so no wave of dummies would meet them all. Compilations land in the
         persistent JAX cache when one is configured. ``timeout`` becomes each dummy
         request's deadline, so a wedged compile surfaces as
-        :class:`DeadlineExceeded` instead of an 1800 s magic wait."""
+        :class:`DeadlineExceeded` instead of an 1800 s magic wait. Each call
+        is one ``serving.engine.prewarm`` span that says how many
+        executables the process ``compiled`` and how many it ``loaded`` from
+        the persistent cache meanwhile."""
         deadline = time.monotonic() + timeout
         if self._view_warmup == "no":
             # before the first wave is enqueued: the engine thread compiles
@@ -785,23 +828,30 @@ class ContinuousBatcher:
         sizes = sorted({min(s, self._group_pad) for s in
                         (group_sizes if group_sizes is not None
                          else range(1, self._group_pad + 1))})
-        for idx, n in enumerate(sizes):
-            # waves run SEQUENTIALLY (each fully retired before the next is
-            # enqueued) so the worker sees exactly one n-sized admission —
-            # concurrent waves would coalesce in the pending queue
-            budget = self.chunk + 1 if idx == len(sizes) - 1 else 1
-            wave = [_Request(np.zeros((prompt_len,), np.int32), budget,
-                             deadline=deadline)
-                    for _ in range(n)]
-            with self._lock:
-                if self._closed:
-                    raise EngineClosed("batcher closed")
-                self._queue.put(wave)
-            for req in wave:
-                # the wait derives from the request's own deadline (plus a
-                # grace period for the worker to reap+fail it) — the worker
-                # raises DeadlineExceeded through result() at expiry
-                req.result(timeout=max(0.0, deadline - time.monotonic()) + 5.0)
+        before = _compile_counts()
+        with TRACER.span("serving.engine.prewarm", replica=self.engine_id,
+                         prompt_len=int(prompt_len), group_sizes=sizes) as span:
+            for idx, n in enumerate(sizes):
+                # waves run SEQUENTIALLY (each fully retired before the next
+                # is enqueued) so the worker sees exactly one n-sized
+                # admission — concurrent waves would coalesce in the pending
+                # queue
+                budget = self.chunk + 1 if idx == len(sizes) - 1 else 1
+                wave = [_Request(np.zeros((prompt_len,), np.int32), budget,
+                                 deadline=deadline)
+                        for _ in range(n)]
+                with self._lock:
+                    if self._closed:
+                        raise EngineClosed("batcher closed")
+                    self._queue.put(wave)
+                for req in wave:
+                    # the wait derives from the request's own deadline (plus
+                    # a grace period for the worker to reap+fail it) — the
+                    # worker raises DeadlineExceeded through result() at
+                    # expiry
+                    req.result(timeout=max(0.0, deadline - time.monotonic()) + 5.0)
+            for outcome, now in _compile_counts().items():
+                span.set(outcome, int(now - before[outcome]))
 
     def close(self) -> None:
         with self._lock:
@@ -880,18 +930,14 @@ class ContinuousBatcher:
                 # handoff sink (the fleet routes it to a decode replica).
                 try:
                     keys = jnp.stack([k for _, k in group])
-                    t0 = time.perf_counter()
                     small, first = self._prefill_group(
                         [r.prompt for r, _ in group],
-                        [r.temperature for r, _ in group], keys)
+                        [r.temperature for r, _ in group], keys,
+                        first=group[0][0])
                 except Exception as e:
                     for req, _ in group:
                         _fail(req, e)
                     continue
-                METRICS.histogram(
-                    "serving_prefill_seconds", buckets=PREFILL_BUCKETS_S
-                ).observe(time.perf_counter() - t0,
-                          trace_id=_trace_id(group[0][0]))
                 self._export_group(group, small, first)
                 continue
             # reserve the worst case BEFORE spending prefill compute;
@@ -915,22 +961,18 @@ class ContinuousBatcher:
                 continue
             try:
                 keys = jnp.stack([k for _, k in group])
-                t0 = time.perf_counter()
+                # its launch's wall time goes to ``serving_prefill_seconds``
+                # (the tokens surface later via the pipelined 'first' event)
                 small, first = self._prefill_group(
                     [r.prompt for r, _ in group],
-                    [r.temperature for r, _ in group], keys)
+                    [r.temperature for r, _ in group], keys,
+                    first=group[0][0])
             except Exception as e:  # whole-group failure takes no slots
                 for res in reserved:
                     self.kv.release(res)
                 for req, _ in group:
                     _fail(req, e)
                 continue
-            # dispatch wall time of ONE batched group prefill (the tokens
-            # surface later via the pipelined 'first' event)
-            METRICS.histogram(
-                "serving_prefill_seconds", buckets=PREFILL_BUCKETS_S
-            ).observe(time.perf_counter() - t0,
-                      trace_id=_trace_id(group[0][0]))
             n = len(group)
             slots = [self._free.pop() for _ in range(n)]
             lens = [len(r.prompt) for r, _ in group]
@@ -944,7 +986,8 @@ class ContinuousBatcher:
                 small = self.family.kv_of(small)
                 first_n = first[:n]
                 self.cache, self.last_tok, self.temps, self.rngs = \
-                    self._adopt_fn(
+                    self._launch(
+                        "adopt", self._adopt_fn,
                         self.cache, small, *map(jnp.asarray, block_ids),
                         slots_arr, true_lens_arr,
                         self.last_tok, self.temps, self.rngs, first_n,
@@ -961,7 +1004,8 @@ class ContinuousBatcher:
                     dsmall, _ = self._prefill_group(
                         [r.prompt for r, _ in group],
                         [r.temperature for r, _ in group], keys, draft=True)
-                    self.draft_cache = self._draft_adopt_fn(
+                    self.draft_cache = self._launch(
+                        "draft_adopt", self._draft_adopt_fn,
                         self.draft_cache, self.family.kv_of(dsmall),
                         slots_arr, true_lens_arr)
             except Exception as e:
@@ -1156,7 +1200,8 @@ class ContinuousBatcher:
         # positions >= n; adoption sets the cursor to n, so the mask hides
         # it until decode overwrites position n onward
         first_idx = (n - 1) - start if last else 0
-        cp.cache, first = self._chunk_prefill_fn(
+        cp.cache, first = self._launch(
+            "chunk_prefill", self._chunk_prefill_fn,
             self.params, cp.cache, jnp.asarray(ids),
             jnp.asarray(first_idx, jnp.int32),
             jnp.asarray(req.temperature, jnp.float32), cp.key)
@@ -1183,7 +1228,8 @@ class ContinuousBatcher:
         true_lens_arr = jnp.asarray([n], jnp.int32)
         # whole blocks of the padded prompt: block_t divides the chunk
         block_ids = self.kv.bind([slot], [cp.res], [n], cp.pos)
-        self.cache, self.last_tok, self.temps, self.rngs = self._adopt_fn(
+        self.cache, self.last_tok, self.temps, self.rngs = self._launch(
+            "adopt", self._adopt_fn,
             self.cache, small, *map(jnp.asarray, block_ids), slots_arr,
             true_lens_arr, self.last_tok, self.temps, self.rngs, first_arr,
             jnp.asarray([req.temperature], jnp.float32),
@@ -1197,9 +1243,11 @@ class ContinuousBatcher:
                 self._draft_full_prefill_fn = self._build_draft_full_prefill()
             dids = np.zeros((1, cp.pos), np.int32)
             dids[0, :n] = req.prompt
-            dsmall = self._draft_full_prefill_fn(
+            dsmall = self._launch(
+                "draft_prefill", self._draft_full_prefill_fn,
                 self._draft_params, dzero, jnp.asarray(dids))
-            self.draft_cache = self._draft_adopt_fn(
+            self.draft_cache = self._launch(
+                "draft_adopt", self._draft_adopt_fn,
                 self.draft_cache, self.family.kv_of(dsmall), slots_arr,
                 true_lens_arr)
         return self._activate_chunked(req, slot, first_arr)
@@ -1238,7 +1286,8 @@ class ContinuousBatcher:
         ids = np.zeros((c,), np.int32)
         ids[:end - start] = req.prompt[start:end]
         tables = self.kv.chunk_tables(slot, start, end, c)
-        self.cache, first, stats = self._chunk_prefill_fn(
+        self.cache, first, stats = self._launch(
+            "chunk_prefill", self._chunk_prefill_fn,
             self.params, self.cache, jnp.asarray(ids),
             jnp.asarray(start, jnp.int32), jnp.asarray(end - start, jnp.int32),
             jnp.asarray(req.temperature, jnp.float32), cp.key,
@@ -1250,7 +1299,8 @@ class ContinuousBatcher:
         if end < n:
             return []
         self.kv.bind([slot], [cp.res], [n])
-        self.cache, self.last_tok, self.temps, self.rngs = self._activate_fn(
+        self.cache, self.last_tok, self.temps, self.rngs = self._launch(
+            "activate", self._activate_fn,
             self.cache, self.last_tok, self.temps, self.rngs,
             jnp.asarray(slot, jnp.int32), jnp.asarray(n, jnp.int32), first,
             jnp.asarray(req.temperature, jnp.float32),
@@ -1322,7 +1372,8 @@ class ContinuousBatcher:
                 self._rng_counter += 1
                 key = jax.random.fold_in(self._base_rng, self._rng_counter)
                 (self.cache, self.last_tok, self.temps, self.rngs) = \
-                    self._import_fn(
+                    self._launch(
+                        "import", self._import_fn,
                         self.cache, wire, jnp.asarray(block_ids),
                         self.last_tok, self.temps, self.rngs,
                         jnp.asarray(slot, jnp.int32),
@@ -1342,9 +1393,11 @@ class ContinuousBatcher:
                     pad = nb * self.kv_block_t
                     dids = np.zeros((1, pad), np.int32)
                     dids[0, :n] = req.prompt
-                    dsmall = self._draft_full_prefill_fn(
+                    dsmall = self._launch(
+                        "draft_prefill", self._draft_full_prefill_fn,
                         self._draft_params, dzero, jnp.asarray(dids))
-                    self.draft_cache = self._draft_adopt_fn(
+                    self.draft_cache = self._launch(
+                        "draft_adopt", self._draft_adopt_fn,
                         self.draft_cache, self.family.kv_of(dsmall),
                         jnp.asarray([slot], jnp.int32),
                         jnp.asarray([n], jnp.int32))
@@ -1369,11 +1422,13 @@ class ContinuousBatcher:
         event's kind and what the host fetches for it."""
         if self.spec_k:
             (self.cache, self.draft_cache, self.last_tok, self.rngs, toks,
-             acc) = self._spec_fn(
+             acc) = self._launch(
+                "spec", self._spec_fn,
                 self.params, self._draft_params, self.cache, self.draft_cache,
                 self.last_tok, self.temps, self.rngs, *tables)
             return "spec", (toks, acc)
-        self.cache, self.last_tok, self.rngs, toks, *stats = self._step_fn(
+        self.cache, self.last_tok, self.rngs, toks, *stats = self._launch(
+            "step", self._step_fn,
             self.params, self.cache, self.last_tok, self.temps, self.rngs,
             *tables)
         return "chunk", (toks, *stats) if stats else toks

@@ -96,9 +96,14 @@ def enable_compile_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside and JAX
     reads it itself, so nothing is set in code. Unset, the cache goes to
     ``<checkout>/.jax_cache``: a fixed path, because the path is part of
-    the cache key and a directory that moves never hits."""
+    the cache key and a directory that moves never hits. From here on
+    ``profiling.watch_compiles()`` says which programs were compiled and
+    which loaded."""
     import jax
 
+    from . import profiling
+
+    profiling.watch_compiles()
     path = os.environ.get(ENV_COMPILE_CACHE_DIR)
     if not path:
         path = str(Path(__file__).resolve().parents[2] / ".jax_cache")

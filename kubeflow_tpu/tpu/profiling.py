@@ -69,6 +69,117 @@ def annotate(name: str, **stats: Any):
     return jax.profiler.TraceAnnotation(name, **stats)
 
 
+# -- compiles and cache loads: the host's time inside JAX's runtime -----------
+#
+# JAX reports each phase of a compile through ``jax.monitoring``: a scalar
+# when tracing, lowering or the backend's compile begins, and at its end a
+# time span with ``time.time()`` stamps and the function's name; inside the
+# backend's span, on the compiling thread, the persistent cache's events (a
+# hit, the seconds the read took, the compile seconds it saved). None fires
+# on a call of a program that is compiled already.
+
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s",
+}
+
+_watch_lock = threading.Lock()
+_watching = False
+_compiles_seen = 0
+#: per thread: ``depth``, the phases open on it, and ``hit``, the cache
+#: events of the backend phase among them
+_compiling = threading.local()
+
+
+def _on_phase_begin(event: str, _value: float, **_: Any) -> None:
+    if event in _COMPILE_PHASES:
+        _compiling.depth = getattr(_compiling, "depth", 0) + 1
+
+
+def _on_cache_hit(event: str, **_: Any) -> None:
+    if event == _CACHE_HIT:
+        _compiling.hit = {}
+
+
+def _on_cache_seconds(event: str, seconds: float, **_: Any) -> None:
+    key = _CACHE_SECONDS.get(event)
+    hit = getattr(_compiling, "hit", None)
+    if key is not None and hit is not None:
+        hit[key] = float(seconds)
+
+
+def _on_phase_end(event: str, start: float, end: float, **kw: Any) -> None:
+    global _compiles_seen
+    phase = _COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    _compiling.depth = outer = max(0, getattr(_compiling, "depth", 1) - 1)
+    if outer and phase != "backend":
+        # tracing one program traces every jitted function it calls (a toy
+        # engine: 2,600 of 2,900 events): their time is their caller's
+        return
+    from kubeflow_tpu.runtime.metrics import METRICS
+    from kubeflow_tpu.runtime.tracing import TRACER
+
+    attrs: Dict[str, Any] = {"phase": phase, "fun_name": str(kw.get("fun_name", ""))}
+    counted, seconds = phase, end - start
+    if phase == "backend":
+        hit = getattr(_compiling, "hit", None)
+        _compiling.hit = None
+        attrs["outcome"] = "compiled" if hit is None else "loaded"
+        if hit is not None:
+            attrs.update(hit)
+            counted, seconds = "cache_load", hit.get("retrieval_s", 0.0)
+        METRICS.counter("xla_compiles_total", outcome=attrs["outcome"]).inc()
+        with _watch_lock:
+            _compiles_seen += 1
+    METRICS.counter("xla_compile_seconds_total", phase=counted).inc(seconds)
+    TRACER.emit_span("xla.compile", int(start * 1e9), int(end * 1e9), **attrs)
+
+
+def watch_compiles() -> None:
+    """Name the host's time inside JAX's runtime, from now on and for the
+    life of the process (idempotent; called where the modules that hold the
+    program's entry points are imported, so that the programs compiled
+    before an engine or a train step is built are counted too).
+
+    Every trace, lowering and backend compile that is not part of another
+    on its thread becomes one ``xla.compile`` span of
+    ``runtime.tracing.TRACER`` with JAX's own stamps (``phase`` = ``trace``
+    | ``lower`` | ``backend``, ``fun_name``); a ``backend`` span says
+    whether the executable was ``compiled`` or ``loaded`` from the
+    persistent cache (``outcome``; a load carries ``retrieval_s`` and
+    ``saved_s``). The counters ``xla_compiles_total{outcome}`` and
+    ``xla_compile_seconds_total{phase}`` (``backend``: misses only;
+    ``cache_load``: the reads of the hits) say the same with no trace open,
+    and ``compiles_seen()`` counts the backend spans. A call of a compiled
+    program fires none of JAX's events and costs nothing here."""
+    global _watching
+    with _watch_lock:
+        if _watching:
+            return
+        from jax import monitoring
+
+        monitoring.register_scalar_listener(_on_phase_begin)
+        monitoring.register_event_listener(_on_cache_hit)
+        monitoring.register_event_duration_secs_listener(_on_cache_seconds)
+        monitoring.register_event_time_span_listener(_on_phase_end)
+        _watching = True
+
+
+def compiles_seen() -> int:
+    """Executables this process has compiled or loaded since
+    ``watch_compiles()``: a caller that reads it before and after a call
+    knows whether the call compiled."""
+    return _compiles_seen
+
+
 class StepClock:
     """Wall-clock step breakdown for training/bench loops.
 

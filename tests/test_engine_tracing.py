@@ -143,6 +143,141 @@ def test_turn_self_time_excludes_the_waits(capture):
     assert 0 < ms < sum(t.dur_ns for t in turns) / len(turns) / 1e6
 
 
+# -- the launches of device programs (ISSUE 37) ----------------------------------
+
+LAUNCH = ENGINE + "launch"
+LAUNCHING_PHASES = ("admit", "prefill_chunk", "dispatch", "import")
+
+
+def _innermost_phase(spans, launch):
+    """The shortest engine region other than the turn that holds a launch."""
+    holders = [s for s in spans if s.line == launch.line and s.name != launch.name
+               and s.name.startswith(ENGINE) and s.name != _spans.TURN
+               and s.start_ns <= launch.start_ns and launch.end_ns <= s.end_ns]
+    return min(holders, key=lambda s: s.dur_ns).name[len(ENGINE):] if holders else None
+
+
+def test_every_launch_names_its_program_and_lies_inside_its_phase(capture):
+    spans, _ = capture
+    launches = [s for s in spans if s.name == LAUNCH]
+    by_phase = {}
+    for s in launches:
+        by_phase.setdefault(_innermost_phase(spans, s), set()).add(s.stats["program"])
+    # no prewarm here, so no view warm-up: every launch has a phase around it
+    assert by_phase == {"admit": {"prefill", "adopt"},
+                        "prefill_chunk": {"chunk_prefill", "adopt"},
+                        "dispatch": {"step"}, "import": {"import"}}
+    turns = [s for s in spans if s.name == _spans.TURN]
+    for s in launches:
+        mine_turns = [t for t in turns if t.line == s.line]
+        if s.start_ns >= min(t.start_ns for t in mine_turns):
+            assert any(t.start_ns <= s.start_ns and s.end_ns <= t.end_ns for t in mine_turns)
+    # the innermost region of its phase: no engine region lies inside a launch
+    assert not [s for s in spans for l in launches
+                if s is not l and s.line == l.line and s.name.startswith(ENGINE)
+                and l.start_ns <= s.start_ns and s.end_ns <= l.end_ns]
+
+
+@pytest.mark.parametrize("program", ["prefill", "adopt", "chunk_prefill", "step", "import"])
+def test_a_launch_that_compiled_says_so_and_a_later_one_does_not(capture, program):
+    spans, _ = capture
+    by_line = {}
+    for s in spans:
+        if s.name == LAUNCH and s.stats["program"] == program:
+            by_line.setdefault(s.line, []).append(s)
+    assert by_line
+    for line, launches in by_line.items():
+        # every engine builds its own programs: its first call of one
+        # compiles (or loads) at least that executable
+        assert launches[0].stats.get("compiles", 0) >= 1, (line, program)
+        said = [l for l in launches if "compiles" in l.stats]
+        if program == "step":
+            # ... and so does the first dispatch at each view width (nothing
+            # was prewarmed here), and no other
+            widths = {d.stats["view_blocks"] for d in spans
+                      if d.name == ENGINE + "dispatch" and d.line == line}
+            assert len(said) == len(widths) < len(launches), line
+        elif program in ("chunk_prefill", "import"):
+            assert said == launches[:1], (line, program)      # a repeat says nothing
+    if program in ("step", "chunk_prefill", "import"):
+        assert max(map(len, by_line.values())) > 1
+
+
+def test_launch_and_own_time_add_up_to_the_turn_s_self_time(capture):
+    spans, _ = capture
+    window = (min(s.start_ns for s in spans), max(s.end_ns for s in spans))
+    obs = {"kind": "serve", "trace_window": window, "serving_spans": spans}
+    host, launch, own = (harness.load_reader(f"engine_{m}_ms_per_turn.serve")(obs)
+                         for m in ("host", "launch", "own"))
+    assert launch > 0 and own > 0 and launch + own == pytest.approx(host)
+    share = harness.load_reader("admit_launch_share.serve")(obs)
+    assert 0 < share < 100
+
+
+@pytest.fixture(scope="module")
+def warmed(params, tmp_path_factory):
+    """One engine built and prewarmed twice INSIDE a profiler session (its
+    first turn opens in it): the capture's spans and the tracer's."""
+    logdir = str(tmp_path_factory.mktemp("prewarm_trace"))
+    TRACER.reset()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=opts)
+    try:
+        eng = ContinuousBatcher(CFG, params, engine_id="w", slots=2, chunk=4,
+                                pipeline=1, prefill_chunk=16)
+        try:
+            eng.prewarm(8)
+            eng.prewarm(8, group_sizes=[1])
+            widths = len(list(eng.kv.warm_tables()))
+        finally:
+            eng.close()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb"))
+    spans = sorted(_spans.spans_in_file(path)[0], key=lambda s: s.start_ns)
+    tracer = TRACER.finished_spans()
+    TRACER.reset()
+    return spans, tracer, widths
+
+
+def test_the_view_warm_up_launches_lie_directly_under_the_turn(warmed):
+    spans, _, widths = warmed
+    bare = [s for s in spans if s.name == LAUNCH and _innermost_phase(spans, s) is None]
+    assert widths > 1 and len(bare) == widths
+    turns = [s for s in spans if s.name == _spans.TURN]
+    for s in bare:
+        assert s.stats["program"] == "step" and s.stats["compiles"] >= 1
+        assert any(t.line == s.line and t.start_ns <= s.start_ns and s.end_ns <= t.end_ns
+                   for t in turns)
+    # all in the turn that took the first wave, ahead of admitting it
+    first_admit = min(s.start_ns for s in spans if s.name == ENGINE + "admit")
+    assert max(s.end_ns for s in bare) <= first_admit
+
+
+def test_build_and_prewarm_are_spans_of_the_tracer_with_their_attributes(warmed):
+    _, tracer, _ = warmed
+    (build,) = [s for s in tracer if s.name == "serving.engine.build"]
+    assert build.attributes["replica"] == "w" and build.attributes["slots"] == 2
+    first, second = [s for s in tracer if s.name == "serving.engine.prewarm"]
+    assert build.start_ns <= build.end_ns <= first.start_ns <= first.end_ns <= second.start_ns
+    assert first.attributes["prompt_len"] == 8 and first.attributes["group_sizes"] == [1, 2]
+    assert second.attributes["group_sizes"] == [1]
+    # the first compiles the engine's programs, a repeat of its shapes none;
+    # the tests run with no persistent cache, so nothing is loaded
+    backend = [s for s in tracer if s.name == "xla.compile"
+               and s.attributes["phase"] == "backend"]
+    inside = [s for s in backend if first.start_ns <= s.start_ns <= first.end_ns]
+    assert first.attributes["compiled"] == len(inside) > 0
+    assert {s.attributes["fun_name"] for s in inside} >= {
+        "jit(step)", "jit(prefill)", "jit(paged_adopt)"}
+    assert first.attributes["loaded"] == 0
+    assert second.attributes["compiled"] == second.attributes["loaded"] == 0
+    assert harness.load_reader("setup_prewarm_s.serve")(
+        {"kind": "serve", "prewarm_spans": [first, second]}) == pytest.approx(
+            first.duration_ms / 1e3 + second.duration_ms / 1e3)
+
+
 # -- per-request records ---------------------------------------------------------
 
 def test_request_span_has_dequeued_between_enqueued_and_admitted(capture):
@@ -284,13 +419,18 @@ def serve_obs():
     spans = [
         sp("turn", 0, 1000), sp("idle", 0, 100), sp("drain", 100, 10, arrivals=1),
         sp("dispatch", 200, 100, rows=32, live=3, view_blocks=2, max_blocks=8),
+        sp("launch", 250, 40, program="step"),
         sp("fetch", 400, 500, kind="chunk"),
         sp("deliver", 900, 50, kind="chunk", rows=32, tokens=20, retired=1),
         sp("turn", 1000, 500), sp("fetch", 1100, 100, kind="first"),
         sp("deliver", 1200, 10, kind="first", rows=2, tokens=2, retired=0),
         sp("deliver", 1250, 10, kind="chunk", rows=32, tokens=12, retired=0),
+        sp("admit", 1300, 100, requests=1, bucket=16),
+        sp("launch", 1310, 30, program="prefill", compiles=1),
+        sp("launch", 1350, 20, program="adopt"),
         sp("turn", 1500, 2000),                       # not wholly inside the window
         sp("fetch", 1100, 100, line="other thread"),  # another thread's is not a child
+        sp("launch", 1300, 90, line="other thread", program="step"),
     ]
     ops = {"/device:TPU:0": [
         ev(WHILE, 100, 800), ev(op("gather.1"), 100, 200), ev(op("dot.1"), 300, 500),
@@ -330,6 +470,10 @@ def train_obs():
 SERVE_READERS = {
     # turns wholly inside: (1000 - 100 idle - 500 fetch) and (500 - 100 fetch): mean 400 ns
     "engine_host_ms_per_turn.serve": 400e-6,
+    # of those, inside calls of device programs: 40 and 30 + 20
+    "engine_launch_ms_per_turn.serve": 45e-6,
+    "engine_own_ms_per_turn.serve": 355e-6,
+    "admit_launch_share.serve": 100.0 * (30 + 20) / 100,        # this thread's alone
     "decode_live_row_share.serve": 100.0 * (20 + 12) / 64,     # chunk events only
     "slot_wait_ms.serve": 3.0,                                 # admitted - dequeued
     "first_token_lag_ms.serve": 6.0,                           # first_token - admitted
