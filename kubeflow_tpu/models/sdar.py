@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.chunk_attention import chunk_attention
+from ..ops.head_choice import head_choice
 from ..ops.paged_attention import paged_decode_attention
 from ..parallel.moe import held_experts_ffn, softmax_top_k
 from .mimo import _heads_apart, partial_rope, rms_norm
@@ -65,8 +66,9 @@ from .mimo import _heads_apart, partial_rope, rms_norm
 #: assignments on held experts, the busiest held expert's, held experts
 #: touched (``parallel/moe.held_experts_ffn``); a live slot's denoising
 #: passes and commit passes; blocks committed; tokens revealed; pages of the
-#: cache the attention read, a layer
-STATS = 8
+#: cache the attention read, a layer; passes that chose from logits written
+#: out because a live slot samples (:func:`block_pass`)
+STATS = 9
 
 
 @dataclass(frozen=True)
@@ -174,9 +176,16 @@ def _experts(cfg: SdarConfig, layer: Dict[str, Any], h: jax.Array, live: jax.Arr
                             first_held=cfg.first_held_expert, live=live)
 
 
-def _head(cfg: SdarConfig, params, x: jax.Array) -> jax.Array:
+def _final_norm(cfg: SdarConfig, params, x: jax.Array) -> jax.Array:
+    """The residual stream after the last layer, float32 [T, d], as the
+    head's operand in the compute type."""
     with jax.named_scope("lm_head"):
-        last = rms_norm(x, params["norm_final"], cfg.norm_eps).astype(cfg.dtype)
+        return rms_norm(x, params["norm_final"], cfg.norm_eps).astype(cfg.dtype)
+
+
+def _head(params, last: jax.Array) -> jax.Array:
+    """Float32 logits [T, vocab], written out."""
+    with jax.named_scope("lm_head"):
         return jnp.dot(last, params["head"], preferred_element_type=jnp.float32)
 
 
@@ -214,26 +223,25 @@ def _block_attention(cfg: SdarConfig, layer, arena, h, cursors, table, live, tra
 
 def choose(cfg: SdarConfig, logits: jax.Array, temps: jax.Array, keys: jax.Array
            ) -> Tuple[jax.Array, jax.Array]:
-    """logits [S, B, vocab] float32 -> (candidate ids [S, B] int32, their
-    confidences [S, B] float32): the argmax where a slot's temperature is
-    0, else a sample on the slot's key (position ``j`` on ``fold_in(key,
-    j)``); the confidence is the candidate's share of the softmax (of the
-    tempered logits where sampled)."""
-    S, B, _ = logits.shape
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    def sampled(_):
-        scaled = logits / jnp.maximum(temps, 1e-6)[:, None, None]
-        each = jax.vmap(lambda key: jax.vmap(lambda j: jax.random.fold_in(key, j))(jnp.arange(B)))(keys)
-        drawn = jax.vmap(jax.vmap(jax.random.categorical))(each, scaled).astype(jnp.int32)
-        hot = (temps > 0.0)[:, None]
-        return jnp.where(hot, drawn, greedy), jnp.where(hot[..., None], scaled, logits)
-
-    # the draw is 151,936 random numbers a row: skipped whole where no slot samples
-    x0, lg = jax.lax.cond(jnp.any(temps > 0.0), sampled, lambda _: (greedy, logits), None)
-    conf = jnp.exp(jnp.take_along_axis(lg, x0[..., None], axis=-1)[..., 0]
+    """logits [S * B, vocab] float32 (slot ``s``'s block in rows ``s * B ..``)
+    -> (candidate ids [S, B] int32, their confidences [S, B] float32): the
+    argmax where a slot's temperature is 0, else a sample on the slot's key
+    (position ``j`` on ``fold_in(key, j)``); the confidence is the
+    candidate's share of the softmax (of the tempered logits where sampled).
+    The rows stay rows to the end: every reduction runs over the last axis
+    as the head wrote it (a ``[S, B, vocab]`` view of them is a relayout on
+    the chip, 4 rows in a tile of 8)."""
+    B = cfg.block_len
+    S = logits.shape[0] // B
+    hot = jnp.repeat(temps > 0.0, B)
+    lg = jnp.where(hot[:, None], logits / jnp.repeat(jnp.maximum(temps, 1e-6), B)[:, None],
+                   logits)
+    each = jax.vmap(lambda key: jax.vmap(lambda j: jax.random.fold_in(key, j))(jnp.arange(B)))(keys)
+    drawn = jax.vmap(jax.random.categorical)(each.reshape(S * B, -1), lg)
+    x0 = jnp.where(hot, drawn, jnp.argmax(logits, axis=-1)).astype(jnp.int32)
+    conf = jnp.exp(jnp.take_along_axis(lg, x0[:, None], axis=-1)[:, 0]
                    - jax.nn.logsumexp(lg, axis=-1))
-    return x0, conf
+    return x0.reshape(S, B), conf.reshape(S, B)
 
 
 def reveal(cfg: SdarConfig, masked: jax.Array, conf: jax.Array) -> jax.Array:
@@ -251,13 +259,13 @@ def reveal(cfg: SdarConfig, masked: jax.Array, conf: jax.Array) -> jax.Array:
     return jnp.where(enough, high, most)
 
 
-def block_logits(cfg: SdarConfig, params, cache, table: jax.Array, trash: int):
-    """The forward pass of :func:`block_pass` alone: every slot's block
-    (``cache["block_ids"]`` at ``cursors .. + B - 1``) through the layers,
-    its keys and values written into the slot's page. Returns (logits [S,
-    B, vocab] float32, the cache with the arenas written, live [S] bool,
-    expert stats int32 [3])."""
-    S, B = cache["cursors"].shape[0], cfg.block_len
+def block_layers(cfg: SdarConfig, params, cache, table: jax.Array, trash: int):
+    """Every slot's block (``cache["block_ids"]`` at ``cursors .. + B - 1``)
+    through the layers, its keys and values written into the slot's page.
+    Returns (the residual stream after the last layer [S * B, d] float32,
+    the cache with the arenas written, live [S] bool, expert stats int32
+    [3])."""
+    B = cfg.block_len
     cursors = cache["cursors"]
     live = table[:, 0] != trash
     row_live = jnp.repeat(live, B)
@@ -272,7 +280,16 @@ def block_logits(cfg: SdarConfig, params, cache, table: jax.Array, trash: int):
         f, st = _experts(cfg, layer, rms_norm(x, layer["norm_ffn"], cfg.norm_eps), row_live)
         x = x + f
         moe = moe + st
-    return _head(cfg, params, x).reshape(S, B, -1), out_cache, live, moe
+    return x, out_cache, live, moe
+
+
+def block_logits(cfg: SdarConfig, params, cache, table: jax.Array, trash: int):
+    """The forward pass of :func:`block_pass` alone, its logits written out:
+    (logits [S, B, vocab] float32, the cache with the arenas written, live
+    [S] bool, expert stats int32 [3])."""
+    x, out_cache, live, moe = block_layers(cfg, params, cache, table, trash)
+    logits = _head(params, _final_norm(cfg, params, x))
+    return logits.reshape(-1, cfg.block_len, cfg.vocab_size), out_cache, live, moe
 
 
 def block_pass(cfg: SdarConfig, params, cache, table: jax.Array, temps: jax.Array,
@@ -281,15 +298,34 @@ def block_pass(cfg: SdarConfig, params, cache, table: jax.Array, temps: jax.Arra
     its reveal or its commit. ``table`` [S, view] (the block table's first
     columns; a row whose first column is trash belongs to no request: it
     reads nothing, takes no expert and keeps its state), ``keys`` [S, 2]
-    this pass's sampling keys. Returns (cache, committed [S] bool, the
-    committed blocks' ids [S, B] and the pass that revealed each position
-    [S, B] (0: it was the prompt's), how many of a committed block's first
-    positions were the prompt's [S], stats int32 [STATS])."""
+    this pass's sampling keys. While no live slot samples, the candidate and
+    its confidence come straight out of the head (``ops.head_choice``: no
+    logits are written); a draw wants them all, so a pass with a sampling
+    slot writes them out and chooses from them (:func:`choose`). Returns
+    (cache, committed [S] bool, the committed blocks' ids [S, B] and the
+    pass that revealed each position [S, B] (0: it was the prompt's), how
+    many of a committed block's first positions were the prompt's [S],
+    stats int32 [STATS])."""
     B = cfg.block_len
     cursors, ids, masked = cache["cursors"], cache["block_ids"], cache["masked"]
-    logits, out_cache, live, moe = block_logits(cfg, params, cache, table, trash)
+    x, out_cache, live, moe = block_layers(cfg, params, cache, table, trash)
+    last = _final_norm(cfg, params, x)
+
+    def streamed(last):
+        with jax.named_scope("lm_head"):
+            best, top, lse = head_choice(last, params["head"])
+            return best.reshape(-1, B), jnp.exp(top - lse).reshape(-1, B)
+
+    def sampled(last):
+        logits = _head(params, last)
+        with jax.named_scope("unmask"):
+            return choose(cfg, logits, temps, keys)
+
+    # the draw is 151,936 random numbers a row and 155 MB of logits: skipped
+    # whole where no slot samples
+    sampling = jnp.any(live & (temps > 0.0))
+    x0, conf = jax.lax.cond(sampling, sampled, streamed, last)
     with jax.named_scope("unmask"):
-        x0, conf = choose(cfg, logits, temps, keys)
         commit = live & ~jnp.any(masked, axis=-1)
         denoise = live & ~commit
         shown = reveal(cfg, masked, conf) & denoise[:, None]
@@ -307,7 +343,8 @@ def block_pass(cfg: SdarConfig, params, cache, table: jax.Array, temps: jax.Arra
     pages = jnp.sum(jnp.where(live, -(-(cursors + B) // bt), 0), dtype=jnp.int32)
     count = lambda what: jnp.sum(what, dtype=jnp.int32)
     stats = jnp.concatenate([moe, jnp.stack([
-        count(denoise), count(commit), count(commit), count(shown), pages])])
+        count(denoise), count(commit), count(commit), count(shown), pages,
+        count(sampling)])])
     return out_cache, commit, ids, cache["revealed_at"], cache["skip"], stats
 
 

@@ -559,10 +559,16 @@ class SdarFamily:
     def account(self, stats, tokens: int) -> Dict[str, int]:
         """An event's counters (``models/sdar.STATS``) into the program's
         own and the ``serving.engine.deliver`` region's stats."""
-        (on_held, busiest, touched, denoise, commit, blocks, revealed, pages) = stats
+        (on_held, busiest, touched, denoise, commit, blocks, revealed, pages,
+         drawn) = stats
         METRICS.counter("serving_moe_assignments_total", held="true").inc(on_held)
         METRICS.counter("serving_block_forwards_total", kind="denoise").inc(denoise)
         METRICS.counter("serving_block_forwards_total", kind="commit").inc(commit)
+        if denoise + commit:
+            # a dispatch (a prefill chunk's event runs no pass): the slots'
+            # temperatures are one for all its passes, so is the path
+            METRICS.counter("serving_block_choice_dispatches_total",
+                            path="materialised" if drawn else "streamed").inc()
         METRICS.counter("serving_blocks_committed_total").inc(blocks)
         METRICS.counter("serving_tokens_revealed_total").inc(revealed)
         return {"expert_tokens": on_held, "expert_tokens_max": busiest,

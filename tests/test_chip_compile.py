@@ -20,6 +20,7 @@ from kubeflow_tpu.ops.chunk_attention import chunk_attention
 from kubeflow_tpu.ops.flash_attention import flash_attention
 from kubeflow_tpu.ops.fused_bottleneck import fused_bottleneck, fused_transition
 from kubeflow_tpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
+from kubeflow_tpu.ops.head_choice import head_choice
 from kubeflow_tpu.ops.paged_attention import paged_decode_attention
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -283,20 +284,26 @@ def test_sdar_programs_fit_the_chip_and_copy_no_arena(chip, monkeypatch):
     """The cell's two device programs at the published widths (6 layers, all
     128 experts, the whole vocabulary, 64 slots, 9,216 pages): the dispatch
     of 16 block passes holds the kernel once a layer and moves no arena into
-    another layout; the chunk program of 2,048 rows holds ``chunk_attention``
+    another layout; at temperature 0 its head is ONE ``head_choice`` kernel
+    and the logits of 256 rows over 151,936 ids are never written: no array
+    ``[64, 4, 151936]`` anywhere, and no ``reshape`` or ``copy`` that ends in
+    151,936 columns (the branch a sampling slot takes reduces its logits as
+    rows too); the chunk program of 2,048 rows holds ``chunk_attention``
     once a layer and no head; weights and arenas are 10.5 GB, the
-    temporaries half a gigabyte (the logits of 256 rows over 151,936 ids and
-    the sorted expert rows), under the chip's 16."""
+    temporaries half a gigabyte (the sampling branch's logits, written only
+    where a slot samples, and the sorted expert rows), under the chip's 16."""
     import re
 
     from kubeflow_tpu.models import sdar
     from kubeflow_tpu.ops import (chunk_attention as chunk_module,
-                                  grouped_matmul as grouped_module, paged_attention)
+                                  grouped_matmul as grouped_module,
+                                  head_choice as head_module, paged_attention)
     from kubeflow_tpu.serving.family import SdarFamily
 
     monkeypatch.setattr(paged_attention, "_interpret_default", lambda: False)
     monkeypatch.setattr(chunk_module, "_interpret_default", lambda: False)
     monkeypatch.setattr(grouped_module, "_interpret_default", lambda: False)
+    monkeypatch.setattr(head_module, "_interpret_default", lambda: False)
     cfg = sdar.SdarConfig()
     family = SdarFamily(cfg, slots=64, kv_blocks=9216, kv_block_t=16)
     assert family.cursor_moves(16) == 32 and family.kv_ahead == 4
@@ -320,6 +327,13 @@ def test_sdar_programs_fit_the_chip_and_copy_no_arena(chip, monkeypatch):
     copies = [line.strip()[:160] for line in text.splitlines()
               if re.search(r"= bf16\[\d+,16,512\]\S* copy(-start)?\(", line)]
     assert not copies, copies
+    assert sum('custom_call_target="tpu_custom_call"' in line and "head_choice" in line
+               for line in text.splitlines()) == 1
+    assert "[64,4,151936]" not in text
+    # stricter than it must be: the sampling branch moves no logits either
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= \w+\[[\d,]*151936\]\S* (reshape|copy(-start)?)\(", line)]
+    assert not moved, moved
     memory = step.memory_analysis()
     assert 10.3e9 < memory.argument_size_in_bytes < 10.8e9
     assert memory.temp_size_in_bytes < 0.7e9
@@ -332,6 +346,14 @@ def test_sdar_programs_fit_the_chip_and_copy_no_arena(chip, monkeypatch):
     # no head reads the last layer's output, so its experts are not computed
     assert _grouped_kernels(text) == 2 * (cfg.n_layers - 1) and "ragged-dot" not in text
     assert chunk.memory_analysis().temp_size_in_bytes < 0.7e9
+
+
+def test_head_choice_sdar_pass_shape(chip):
+    """The SDAR cell's head at temperature 0: 64 slots x 4 positions of
+    hidden 2,048 over 151,936 ids (148 tiles of 1,024 columns and one of
+    384, masked), three numbers a row out."""
+    shapes = [((256, 2048), BF16), ((2048, 151936), BF16)]
+    assert _compile(chip, lambda x, head: head_choice(x, head, interpret=False), *shapes) == 1
 
 
 @pytest.mark.parametrize("rows,groups,d,f", [

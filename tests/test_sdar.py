@@ -188,6 +188,51 @@ def test_the_block_mask_sees_the_whole_own_block_and_no_later_one():
     assert np.abs(a[:8] - b[:8]).max() == 0 and np.abs(a[8] - b[8]).max() > 1e-3
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_greedy_pass_reveals_what_choose_over_the_written_logits_reveals(dtype):
+    """``block_pass`` at temperature 0 takes candidates and confidences
+    straight out of the head (``ops.head_choice``); the block state and the
+    arenas it leaves are those of ``choose`` and ``reveal`` over
+    ``block_logits``: a slot denoising, a dead one, one committing."""
+    cfg = dataclasses.replace(CFG, dtype=jnp.dtype(dtype))
+    params = tree(cfg.dtype)
+    family = SdarFamily(cfg, slots=3, kv_blocks=40, kv_block_t=4)
+    trash, M = 40, CFG.mask_id
+    table = np.full((3, 8), trash, np.int32)
+    table[0, :2], table[2, :3] = [5, 6], [9, 3, 11]
+    cache = dict(family.fresh_cache(),
+                 cursors=jnp.asarray([4, 0, 8], jnp.int32),
+                 block_ids=jnp.asarray([[17, M, M, 40], [M] * 4, [3, 70, 9, 21]], jnp.int32),
+                 masked=jnp.asarray([[0, 1, 1, 0], [1] * 4, [0] * 4], bool),
+                 passes=jnp.asarray([2, 0, 4], jnp.int32))
+    temps, keys = jnp.zeros((3,), jnp.float32), jnp.zeros((3, 2), jnp.uint32)
+    out, commit, ids, _, _, stats = sdar.block_pass(
+        cfg, params, cache, jnp.asarray(table), temps, keys, trash)
+    logits, want_cache, live, _ = sdar.block_logits(cfg, params, cache, jnp.asarray(table), trash)
+    x0, conf = sdar.choose(cfg, logits.reshape(3 * B, -1), temps, keys)
+    shown = np.asarray(sdar.reveal(cfg, cache["masked"], conf))
+    assert list(np.asarray(live)) == [True, False, True]
+    assert list(np.asarray(commit)) == [False, False, True] and shown[0].sum() == 1
+    at = int(np.argmax(shown[0]))
+    want_ids = np.asarray(cache["block_ids"]).copy()
+    want_ids[0, at], want_ids[2] = int(x0[0, at]), M
+    assert np.array_equal(np.asarray(out["block_ids"]), want_ids)
+    assert np.array_equal(np.asarray(out["masked"]),
+                          [[j in (1, 2) and j != at for j in range(B)], [True] * B, [True] * B])
+    assert int(out["revealed_at"][0, at]) == 3 and list(np.asarray(out["cursors"])) == [4, 0, 12]
+    for i in range(cfg.n_layers):
+        for kind in ("k", "v"):
+            assert np.array_equal(np.asarray(out[f"layer_{i}"][kind], np.float32),
+                                  np.asarray(want_cache[f"layer_{i}"][kind], np.float32))
+    # one denoising pass, one commit; nobody samples: no logits written
+    assert list(np.asarray(stats[3:7])) == [1, 1, 1, 1] and int(stats[8]) == 0
+    # a dead slot's lingering temperature draws nothing; a live one's does
+    for hot, drew in ((1, 0), (0, 1)):
+        *_, stats = sdar.block_pass(cfg, params, cache, jnp.asarray(table),
+                                    temps.at[hot].set(0.7), keys, trash)
+        assert int(stats[8]) == drew
+
+
 # -- the generation trajectory through the engine ----------------------------------
 
 def reference_run(p, new, head_scale=1.0, fault=None):
@@ -289,6 +334,23 @@ def test_sampled_slots_draw_their_own_streams():
         eng.close()
     assert a != b and all(0 <= t < CFG.vocab_size for t in a + b)
     assert all(1 <= m <= CFG.denoise_steps for f in hot for m in f.reveal_passes)
+
+
+def test_the_choice_counts_its_dispatches_by_path():
+    """``serving_block_choice_dispatches_total``: a dispatch in which no live
+    slot samples takes the streamed head, one with a sampling slot writes
+    the logits out; a prefill's event counts on neither."""
+    paths = ("streamed", "materialised")
+    read = lambda: [counter("serving_block_choice_dispatches_total", path=p) for p in paths]
+    for temperature, moved in ((0.0, 0), (0.8, 1)):
+        before = read()
+        eng = engine(slots=1)
+        try:
+            eng.submit(prompt(51, 9), 3, temperature=temperature).result(timeout=600)
+        finally:
+            eng.close()
+        rose = [b - a for a, b in zip(before, read())]
+        assert rose[moved] >= 1 and rose[1 - moved] == 0, (temperature, rose)
 
 
 def test_ttft_is_stamped_at_the_first_block_and_gaps_count_the_later_tokens():
