@@ -37,6 +37,11 @@ returns the softmax's running maximum and sum, so that a caller with more
 than one source of keys (a local window and a summary arena, each with a
 table of its own) calls once a source and joins the results under ONE
 softmax (:func:`join_softmax`).
+
+The LATENT kind of arena (models/sarvam.py: one row a position that is key
+and value at once, read in the absorbed form) is not this kernel's: handed
+one arena as both, it would fetch every page twice. ``ops/latent_attention``
+walks a slot's pages the same way and uses each page for both products.
 """
 
 from __future__ import annotations

@@ -129,7 +129,9 @@ class MoEMlp(nn.Module):
 
 # -- dropless expert layer that is told which experts it holds ----------------
 #
-# The serving path's expert layer (models/mimo.py). Unlike ``MoEMlp`` above it
+# The serving path's expert layer (models/mimo.py, models/sdar.py, and with a
+# shared expert beside it and the routed part scaled, models/sarvam.py:
+# ``shared_expert_layer``). Unlike ``MoEMlp`` above it
 # has no capacity and drops nothing: the router scores ALL experts, the
 # assignments that land on the experts held here are sorted by expert and go
 # through grouped matrix products, and the layer returns the held experts'
@@ -210,3 +212,23 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
     stats = jnp.stack([jnp.sum(sizes), jnp.max(sizes),
                        jnp.sum(sizes > 0, dtype=jnp.int32)]).astype(jnp.int32)
     return y.astype(h.dtype), stats
+
+
+def shared_expert_layer(h: jax.Array, idx: jax.Array, weights: jax.Array, p,
+                        *, routed_scale: float = 1.0, first_held: int = 0,
+                        live: Optional[jax.Array] = None,
+                        ) -> Tuple[jax.Array, jax.Array]:
+    """An expert layer with a shared expert beside the routed ones
+    (models/sarvam.py): ``shared(h) + routed_scale x`` the held experts'
+    part (:func:`held_experts_ffn`). The shared SwiGLU (``p["shared"]``:
+    ``w_gate``, ``w_up`` [d, f], ``w_down`` [f, d]) sees every token and is
+    computed whole on every chip that holds a share of the routed experts
+    (``p``'s ``w_gate``, ``w_up``, ``w_down``). h [T, d] in the compute
+    type. Returns (float32 [T, d], the held experts' stats int32 [3])."""
+    routed, stats = held_experts_ffn(h, idx, weights, p["w_gate"], p["w_up"], p["w_down"],
+                                     first_held=first_held, live=live)
+    with jax.named_scope("shared_expert"):
+        s = p["shared"]
+        mid = jax.nn.silu(jnp.dot(h, s["w_gate"])) * jnp.dot(h, s["w_up"])
+        shared = jnp.dot(mid, s["w_down"], preferred_element_type=jnp.float32)
+    return shared + routed_scale * routed.astype(jnp.float32), stats

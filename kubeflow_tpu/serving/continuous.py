@@ -531,7 +531,8 @@ class ContinuousBatcher:
     # (the family's: serving/family.py builds every program that knows what
     # a cache leaf is; the engine keeps the ones that only sequence models)
     def _launch(self, program: str, fn: Callable[..., Any], *args: Any,
-                first: Optional[_Request] = None) -> Any:
+                first: Optional[_Request] = None,
+                stats: Optional[Dict[str, int]] = None) -> Any:
         """Every call of a device program on the engine thread: one
         ``serving.engine.launch`` region, the innermost of its phase, so an
         idle gap of the device is put down to the launch of a named
@@ -539,9 +540,12 @@ class ContinuousBatcher:
         itself (``compiles``). The call returns when the program is
         enqueued, which is late where the device's queue is full. ``first``:
         the first request of a batched prefill, whose trace the call's wall
-        time is filed under in ``serving_prefill_seconds``."""
+        time is filed under in ``serving_prefill_seconds``. ``stats`` ride
+        on the region (an in-arena prefill chunk's ``start``, ``tokens`` and
+        ``view``, the positions its keys span)."""
         seen = profiling.compiles_seen()
-        with profiling.annotate("serving.engine.launch", program=program) as region:
+        with profiling.annotate("serving.engine.launch", program=program,
+                                **(stats or {})) as region:
             t0 = time.perf_counter()
             out = fn(*args)
             if first is not None:
@@ -854,10 +858,18 @@ class ContinuousBatcher:
                 span.set(outcome, int(now - before[outcome]))
 
     def close(self) -> None:
+        """Stops the engine thread and, once it has stopped, lets go of the
+        weights and the arenas: a closed engine serves nothing again, and a
+        server that unloads or reloads a model must get its device memory
+        back (PERF.md section 6, PR 39)."""
         with self._lock:
             self._closed = True
             self._queue.put(None)
         self._worker.join(timeout=30)
+        if not self._worker.is_alive():
+            self.params = self.cache = None
+            if self.spec_k:
+                self._draft_params = self.draft_cache = None
 
     def drain(self, timeout: float = 600.0) -> List[_Request]:
         """Graceful shutdown, distinct from ``close()``: stop admission,
@@ -1291,7 +1303,9 @@ class ContinuousBatcher:
             self.params, self.cache, jnp.asarray(ids),
             jnp.asarray(start, jnp.int32), jnp.asarray(end - start, jnp.int32),
             jnp.asarray(req.temperature, jnp.float32), cp.key,
-            *map(jnp.asarray, tables))
+            *map(jnp.asarray, tables),
+            stats={"start": start, "tokens": end - start,
+                   "view": len(tables[0]) * self.kv_block_t * self.family.kv_stride})
         cp.stats = stats if cp.stats is None else cp.stats + stats
         cp.pos = start + c
         METRICS.counter("serving_prefill_chunks_total").inc()

@@ -33,6 +33,16 @@ ids, which are still masked, the pass that revealed each): a dispatch is a
 fixed number of forward passes, and what it hands a slot is the blocks the
 slot committed in it, a variable number of tokens.
 
+``SarvamFamily`` (``SarvamConfig``): latent attention (MLA), so a third
+kind of paged arena: the block table alone, ONE row a position and layer
+(the normalised latent and the shared rotary key, 640 wide), no value
+arena. Prompts prefill in chunks straight into the arena in the EXPANDED
+form (the latent expanded to every head's key and value a block of keys at
+a time, ``ops/latent_attention``); the decode step attends in the ABSORBED
+form (each head's query folded into latent space, each page fetched once
+for the scores and the context). The expert layer's counters and the
+latent pages the decode attention fetched ride home with the tokens.
+
 What the engine asks of a family, beside its programs: ``rings(lookahead,
 engine_id)`` (the kind of cache that keeps a ring a slot beside the block
 table, or None), ``kv_stride`` (positions a row of the block table stands
@@ -62,8 +72,9 @@ import jax.numpy as jnp
 from ..models.evabyte import EvaConfig
 from ..models.gpt import GptConfig, GptLM
 from ..models.mimo import FULL, WINDOW, MimoConfig
+from ..models.sarvam import SarvamConfig
 from ..models.sdar import SdarConfig
-from ..models import evabyte, mimo, sdar
+from ..models import evabyte, mimo, sarvam, sdar
 from ..runtime.metrics import METRICS
 from .paged import AlignedWindows, WindowRings
 
@@ -656,10 +667,88 @@ class SdarFamily:
         return activate
 
 
+class SarvamFamily(_TokenAStep):
+    """``models/sarvam.py`` in the engine: one kind of paged cache, a
+    latent row a position, prompts prefilled chunk by chunk straight into
+    the arena (expanded), one token a slot and step (absorbed), and the
+    expert and latent-page counters riding home with the tokens."""
+
+    has_stats = True
+    prefills_in_arena = True
+    kv_stride = 1
+
+    def __init__(self, cfg: SarvamConfig, *, slots: int, paged: bool = True,
+                 kv_blocks: int = 0, kv_block_t: int = 16,
+                 kv_dtype: str = "bf16"):
+        if not paged or kv_dtype != "bf16":
+            raise ValueError("this model family prefills into a paged bf16 arena "
+                             "(paged=True, kv_dtype='bf16')")
+        self.cfg, self.slots, self.kv_block_t = cfg, slots, kv_block_t
+        self.kv_blocks = kv_blocks or slots * (cfg.max_seq // kv_block_t)
+
+    def rings(self, lookahead: int, engine_id: str = "0") -> None:
+        """One kind of cache: no ring beside the block table."""
+        return None
+
+    def account(self, stats, tokens: int) -> Dict[str, int]:
+        """An event's counters (``models/sarvam.STATS``) into the program's
+        own and the ``serving.engine.deliver`` region's stats: as the MiMo
+        family's, and the latent pages the decode attention fetched, a
+        layer (0 for a prefill chunk's event)."""
+        on_held, busiest, touched, pages = stats
+        routed = tokens * self.cfg.experts_per_token * sum(self.cfg.moe_layers)
+        METRICS.counter("serving_moe_assignments_total", held="true").inc(on_held)
+        METRICS.counter("serving_moe_assignments_total", held="false").inc(
+            max(routed - on_held, 0))
+        METRICS.counter("serving_latent_pages_read_total").inc(pages)
+        return {"expert_tokens": on_held, "expert_tokens_max": busiest,
+                "experts_touched": touched, "latent_pages_read": pages}
+
+    def fresh_cache(self) -> Dict[str, Any]:
+        return sarvam.fresh_cache(self.cfg, self.slots, self.kv_blocks, self.kv_block_t)
+
+    def build_step(self, chunk: int):
+        """``chunk`` tokens for every slot, and the counters of the whole
+        chunk. A dispatch takes the block table's first columns alone."""
+        cfg, trash = self.cfg, self.kv_blocks
+
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 4))
+        def step(params, cache, tok, temps, rngs, table):
+            def one(carry, _):
+                cache, tok, rngs, stats = carry
+                logits, cache, st = sarvam.decode_step(cfg, params, cache, tok, table, trash)
+                with jax.named_scope("sample"):
+                    nxt, rngs = sample_next(logits, temps, rngs)
+                return (cache, nxt, rngs, stats + st), nxt
+
+            (cache, tok, rngs, stats), toks = jax.lax.scan(
+                one, (cache, tok, rngs, jnp.zeros((sarvam.STATS,), jnp.int32)), None,
+                length=chunk)
+            return cache, tok, rngs, jnp.moveaxis(toks, 0, 1), stats
+
+        return step
+
+    def build_chunk_prefill(self):
+        cfg = self.cfg
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def prefill_chunk(params, cache, ids, start, n_valid, temperature, key,
+                          read, write):
+            logits, cache, stats = sarvam.prefill_chunk(
+                cfg, params, cache, ids, start, n_valid, read, write)
+            return cache, _sample_first(logits, temperature, key), stats
+
+        return prefill_chunk
+
+    def build_activate(self):
+        return _build_activate()
+
+
 _FAMILIES: Tuple[Tuple[type, type], ...] = ((GptConfig, GptFamily),
                                             (MimoConfig, MimoFamily),
                                             (EvaConfig, EvaFamily),
-                                            (SdarConfig, SdarFamily))
+                                            (SdarConfig, SdarFamily),
+                                            (SarvamConfig, SarvamFamily))
 
 
 def family_for(cfg: Any, **geometry: Any):

@@ -181,8 +181,15 @@ class ModelServer:
         return model.predict(instances)
 
     def close(self) -> None:
+        """Stops the batchers and closes every served model that has an
+        engine, then forgets the models: the routes the HTTP server keeps
+        reach this object, and through it each model's weights."""
         for b in self._batchers.values():
             b.close()
+        for model in self.models.values():
+            if isinstance(model, GenerativeModel):
+                model.close()
+        self.models.clear()
 
     def _model(self, name: str) -> ServedModel:
         model = self.models.get(name)

@@ -435,3 +435,85 @@ def test_gpt_train_step_runs_the_forward_kernel_once(chip, monkeypatch):
     assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75 * 2**30
+
+
+# The Sarvam cell's shapes (ISSUE 39): 64 heads, a latent row of 640 a
+# position (512 latent + 64 rotary key + 64 zeros), 16 slots, pages of 16.
+@pytest.mark.parametrize("columns", [512, 2048], ids=["narrowest", "widest"])
+def test_latent_decode_attention_sarvam_shape(chip, columns):
+    """The absorbed decode kernel as the step calls it: 16 slots of 64
+    heads' 640-wide queries over the arena's 32,769 pages, 32 pages a
+    group (655 KB a buffer)."""
+    from kubeflow_tpu.ops.latent_attention import latent_decode_attention
+
+    def attend(q, arena, table, lengths):
+        return latent_decode_attention(q, arena, table, lengths, scale=0.1352, latent=512,
+                                       interpret=False)
+
+    assert _compile(chip, attend, ((16, 64, 640), BF16), ((32769, 16, 640), BF16),
+                    ((16, columns), I32), ((16,), I32)) == 1
+
+
+@pytest.mark.parametrize("keys", [8192, 32768], ids=["narrowest_view", "widest_view"])
+def test_latent_prefill_attention_sarvam_shape(chip, keys):
+    """The expanded prefill kernel as the chunk program calls it: 64 heads
+    of a chunk of 4,096 queries (128 nope + 128 for the rotary query and
+    the row's zero tail) against a view of ``keys`` rows, expanded 512 rows
+    at a time through each head's 512 x 128 W_uk and W_uv."""
+    from kubeflow_tpu.ops.latent_attention import latent_prefill_attention
+
+    def attend(qn, qr, view, wuk, wuv, start):
+        return latent_prefill_attention(qn, qr, view, wuk, wuv, start, scale=0.1352,
+                                        interpret=False)
+
+    assert _compile(chip, attend, ((64, 4096, 128), BF16), ((64, 4096, 128), BF16),
+                    ((keys, 640), BF16), ((64, 512, 128), BF16), ((64, 512, 128), BF16),
+                    ((), I32)) == 1
+
+
+def test_sarvam_programs_fit_the_chip_and_copy_no_arena(chip, monkeypatch):
+    """The cell's two device programs at the published widths (5 layers,
+    32 of 128 experts held, 65,536 ids, 16 slots, 32,768 pages): the decode
+    dispatch of 16 steps holds the absorbed kernel once a layer and moves
+    no latent arena into another layout; the chunk program of 4,096 rows
+    holds the expanded kernel once a layer; weights and arenas are 12.43 GB,
+    the step's temporaries under 0.7 GB and the chunk's under 1.4 GB, under
+    the chip's 16."""
+    import re
+
+    from kubeflow_tpu.models import sarvam
+    from kubeflow_tpu.ops import grouped_matmul as grouped_module, latent_attention
+    from kubeflow_tpu.serving.family import SarvamFamily
+
+    monkeypatch.setattr(latent_attention, "_interpret_default", lambda: False)
+    monkeypatch.setattr(grouped_module, "_interpret_default", lambda: False)
+    cfg = sarvam.SarvamConfig()
+    family = SarvamFamily(cfg, slots=16, kv_blocks=32768, kv_block_t=16)
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+
+    one = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    params = described(jax.eval_shape(lambda: sarvam.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = described(jax.eval_shape(family.fresh_cache))
+    step = family.build_step(16).lower(
+        params, cache, one((16,), I32), one((16,), F32), one((16, 2), jnp.uint32),
+        one((16, 2048), I32)).compile()
+    text = step.as_text()
+    kernel = lambda name: sum('custom_call_target="tpu_custom_call"' in line and name in line
+                              for line in text.splitlines())
+    assert kernel("latent_decode_attention") == cfg.n_layers
+    assert _grouped_kernels(text) == 2 * sum(cfg.moe_layers) and "ragged-dot" not in text
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= bf16\[32769,16,640\]\S* copy(-start)?\(", line)]
+    assert not copies, copies
+    memory = step.memory_analysis()
+    assert 12.3e9 < memory.argument_size_in_bytes < 12.6e9
+    assert memory.temp_size_in_bytes < 0.7e9
+    chunk = family.build_chunk_prefill().lower(
+        params, cache, one((4096,), I32), one((), I32), one((), I32), one((), F32),
+        one((2,), jnp.uint32), one((2048,), I32), one((256,), I32)).compile()
+    text = chunk.as_text()
+    assert kernel("latent_prefill_attention") == cfg.n_layers
+    assert chunk.memory_analysis().temp_size_in_bytes < 1.4e9

@@ -136,6 +136,34 @@ def test_generative_model_continuous_predict_surface(params):
         served.close()
 
 
+def test_closing_the_server_lets_go_of_the_engine_state(params):
+    """``ModelServer.close()`` closes each generative model's engine, which
+    drops its weights and arena once its thread has stopped, and forgets
+    the models: an HTTP server that outlives it (its routes reach the
+    ModelServer) keeps no device memory of theirs."""
+    import gc
+    import weakref
+
+    from kubeflow_tpu.serving.server import GenerativeModel, ModelServer
+
+    served = GenerativeModel(name="gpt-close", apply_fn=None, params=params,
+                             cfg=CFG, max_new_tokens=4, continuous=True, slots=2)
+    server = ModelServer()
+    server.add(served)
+    resp = server.app.call("POST", "/v1/models/gpt-close:predict",
+                           {"instances": [prompt(2, 5).tolist()]})
+    assert resp.status == 200, resp.body
+    engine = served._continuous_engine()
+    arena = weakref.ref(jax.tree.leaves(engine.cache)[0])
+    server.close()
+    assert not engine._worker.is_alive()
+    assert engine.params is None and engine.cache is None
+    assert server.models == {} and served._engine is None
+    gc.collect()
+    assert arena() is None
+    assert server.app.call("GET", "/v1/models/gpt-close").status == 404
+
+
 def test_failed_admission_does_not_leak_the_slot(params):
     """A prompt that passes the submit length check but exceeds every
     prefill bucket fails ONLY its own request; the slot stays usable."""

@@ -186,6 +186,17 @@ F32_MATMUL_ALLOWLIST = {
     # the SDAR family: the float32 logits head (bf16 operands, float32
     # accumulation); its router's float32 product lives in parallel/moe.py
     ("sdar.py", "_head"),
+    # the Sarvam family: bf16 operands, float32 ACCUMULATION where the
+    # result is a norm's input (the query, the latent), a part of the
+    # float32 residual stream (the output projection, the dense SwiGLU's
+    # down product) or the float32 logits head; the absorbed query and the
+    # latent context are bf16 operands of the kernel and of W_uv
+    ("sarvam.py", "_queries"),
+    ("sarvam.py", "_latent_rows"),
+    ("sarvam.py", "_ffn"),
+    ("sarvam.py", "_head"),
+    ("sarvam.py", "_decode_attention"),
+    ("sarvam.py", "_chunk_attention"),
 }
 
 _MATMUL_CALLEES = {"einsum", "matmul", "dot", "tensordot", "dot_general"}
