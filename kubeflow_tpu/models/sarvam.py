@@ -315,10 +315,9 @@ def _chunk_attention(cfg: SarvamConfig, layer, arena, h, start, read, write):
     with jax.named_scope("attn_mla_prefill"):
         view = arena[read].reshape(read.shape[0] * bt, cfg.row)
         tail = cfg.row - cfg.kv_lora_rank - cfg.qk_rope_dim
-        qr = jnp.pad(q_rope, ((0, 0), (0, 0), (0, tail)))
+        q = jnp.pad(jnp.concatenate([q_nope, q_rope], axis=-1), ((0, 0), (0, 0), (0, tail)))
         ctx = latent_prefill_attention(
-            jnp.swapaxes(q_nope, 0, 1).astype(cfg.dtype),
-            jnp.swapaxes(qr, 0, 1).astype(cfg.dtype), view, layer["w_uk"], layer["w_uv"],
+            jnp.swapaxes(q, 0, 1).astype(cfg.dtype), view, layer["w_uk"], layer["w_uv"],
             start, scale=cfg.softmax_scale)                              # [H, C, v]
     out = jnp.einsum("hcv,hvm->cm", ctx, layer["wo"], preferred_element_type=jnp.float32)
     return out, arena

@@ -34,7 +34,18 @@ no query of the chunk reaches is neither fetched nor computed. Expanded,
 attention costs ``nope + rope + v`` = 320 multiply-adds a key and head where
 the absorbed form costs ``2 latent + rope`` = 1,088: at a chunk of 2,048
 queries the expansion (``2 latent x (nope + v)`` a key and head, once a
-chunk) is the cheaper side.
+chunk) is the cheaper side. A tile's scores are ONE product of the query
+``[q_nope | q_rope | 0]`` with the block's ``[k | k_r | 0]``, and the
+softmax scale rides in the multiply ``exp2`` takes anyway (``scale x
+log2 e``): no pass over the scores is the scale's alone, and the query
+keeps the rounding of the unscaled one (a query scaled before its rounding
+to bfloat16 runs no faster on the chip, but rounds otherwise and moves
+near-ties of the served tokens). Only the tiles that cross the causal
+diagonal (some key of the block past some query of the tile) build and
+apply the mask; every other tile that sees the block sees all of it. The
+running maximum and sum are one lane wide: written broadcast over 128
+lanes, as a tile's whole state, they cost a quarter of the kernel at tiles
+of 256 x 512.
 
 Scores, maxima and sums are float32; every product takes the arena's type,
 the probabilities rounded to it before the value product.
@@ -53,11 +64,12 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import _interpret_default
 
 _NEG_BIG = -1e30
+_LOG2E = 1.4426950408889634
 _LANE = 128
 #: pages a group: 512 positions of 16, 655 KB of 640-wide rows a buffer
 PAGES_PER_GROUP = 32
 #: key rows a prefill step expands, and query rows a tile scores at once
-BLOCK_K, BLOCK_Q = 512, 256
+BLOCK_K, BLOCK_Q = 1024, 512
 
 
 def row_width(latent: int, rope: int) -> int:
@@ -168,13 +180,24 @@ def latent_decode_attention(q: jax.Array, arena: jax.Array, table: jax.Array,
 
 # -- prefill: expanded a key block at a time ----------------------------------------
 
+def _tile_ranges(j, start, nq: int, bq: int, bk: int):
+    """Of key block ``j``, the query tiles ``first .. inner - 1`` that cross
+    the causal diagonal and ``inner .. nq - 1`` that see the whole block:
+    ``first`` is the first tile whose last query reaches the block's first
+    key, ``inner`` the first whose first query reaches its last."""
+    first = jnp.maximum(j * bk - start, 0) // bq
+    inner = jnp.minimum(jnp.maximum(-(-(j * bk + bk - 1 - start) // bq), first), nq)
+    return first, inner
+
+
 def _prefill_kernel(start_ref,                              # prefetched scalar
-                    qn_ref, qr_ref, view_ref, wuk_ref, wuv_ref, o_ref,
+                    q_ref, view_ref, wuk_ref, wuv_ref, o_ref,
                     acc, m_scr, l_scr, *, scale: float, latent: int, block_q: int,
                     block_k: int, nk: int):
     j = pl.program_id(1)
     start = start_ref[0]
-    chunk = qn_ref.shape[1]
+    chunk = q_ref.shape[1]
+    nq = chunk // block_q
 
     @pl.when(j == 0)
     def _init():
@@ -190,60 +213,62 @@ def _prefill_kernel(start_ref,                              # prefetched scalar
         c = rows[:, :latent]
         k = jnp.dot(c, wuk_ref[0], preferred_element_type=jnp.float32).astype(rows.dtype)
         v = jnp.dot(c, wuv_ref[0], preferred_element_type=jnp.float32).astype(rows.dtype)
-        kr = rows[:, latent:]                                         # rotary key, zeros
-        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-        # the first query tile whose last row reaches the block's first key
-        first = jnp.maximum(j * block_k - start, 0) // block_q
+        key = jnp.concatenate([k, rows[:, latent:]], axis=1)         # [k | k_r | 0]
+        first, inner = _tile_ranges(j, start, nq, block_q, block_k)
 
-        def tile(i, _):
+        def tile(i, masked: bool):
             at = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
-            sc = (jax.lax.dot_general(qn_ref[0, at], k, (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-                  + jax.lax.dot_general(qr_ref[0, at], kr, (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32)) * scale
-            q_pos = start + i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-            sc = jnp.where(k_pos <= q_pos, sc, _NEG_BIG)
-            m_prev, l_prev = m_scr[at, :1], l_scr[at, :1]
+            sc = jax.lax.dot_general(q_ref[0, at], key, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            if masked:
+                k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+                q_pos = start + i * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, 1), 0)
+                sc = jnp.where(k_pos <= q_pos, sc, _NEG_BIG)
+            m_prev, l_prev = m_scr[at], l_scr[at]
             m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
-            p = jnp.where(sc > 0.5 * _NEG_BIG, jnp.exp(sc - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
+            # exp((sc - m) x scale), the scores and their maxima unscaled
+            p = jnp.exp2((sc - m_new) * (scale * _LOG2E))
+            if masked:
+                # a row that sees no key of the block takes nothing from it
+                p = jnp.where(sc > 0.5 * _NEG_BIG, p, 0.0)
+            alpha = jnp.exp2((m_prev - m_new) * (scale * _LOG2E))
             pv = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
             acc[at, :] = acc[at, :] * alpha + pv
-            m_scr[at, :] = jnp.broadcast_to(m_new, (block_q, m_scr.shape[1]))
-            l_scr[at, :] = jnp.broadcast_to(l_prev * alpha + p.sum(axis=-1, keepdims=True),
-                                            (block_q, l_scr.shape[1]))
-            return None
+            m_scr[at] = m_new
+            l_scr[at] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
 
-        jax.lax.fori_loop(first, chunk // block_q, tile, None)
+        jax.lax.fori_loop(first, inner, lambda i, _: tile(i, True), None)
+        jax.lax.fori_loop(inner, nq, lambda i, _: tile(i, False), None)
 
     @pl.when(j == nk - 1)
     def _finish():
-        l = l_scr[:, :1]
+        l = l_scr[...]
         o_ref[0] = (acc[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-def latent_prefill_attention(q_nope: jax.Array, q_rope: jax.Array, view: jax.Array,
-                             w_uk: jax.Array, w_uv: jax.Array, start: jax.Array, *,
-                             scale: float, block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
+def latent_prefill_attention(q: jax.Array, view: jax.Array, w_uk: jax.Array,
+                             w_uv: jax.Array, start: jax.Array, *, scale: float,
+                             block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
                              interpret: Optional[bool] = None) -> jax.Array:
     """A chunk's queries at positions ``start .. start + chunk - 1`` against
-    the latent rows of positions ``0 .. keys - 1``, causal. ``q_nope``
-    ``[heads, chunk, nope]``; ``q_rope`` ``[heads, chunk, width - latent]``
-    (the rotary query, zeros after it, as the row's tail lies); ``view``
-    ``[keys, width]`` (the row's blocks in order: what lies past the chunk is
-    never read); ``w_uk`` ``[heads, latent, nope]``, ``w_uv`` ``[heads,
-    latent, v]``. Returns ``[heads, chunk, v]`` in the queries' type: the
-    softmax over keys ``j <= position`` of ``(q_nope . c_j W_uk_h + q_rope .
-    k_r_j) * scale``, times ``c_j W_uv_h``.
+    the latent rows of positions ``0 .. keys - 1``, causal. ``q`` ``[heads,
+    chunk, nope + width - latent]``: each head's query as ``[q_nope | q_rope
+    | 0]`` (the rotary query and zeros as the row's tail lies);
+    ``view`` ``[keys, width]`` (the row's blocks in order: what lies past the
+    chunk is never read); ``w_uk`` ``[heads, latent, nope]``, ``w_uv``
+    ``[heads, latent, v]``. Returns ``[heads, chunk, v]`` in the queries'
+    type: the softmax over keys ``j <= position`` of ``(q_nope . c_j W_uk_h +
+    q_rope . k_r_j) * scale``, times ``c_j W_uv_h``.
 
     ``chunk`` must be a multiple of ``min(block_q, chunk)``; the keys are
     padded here to a multiple of the key block."""
-    heads, chunk, nope = q_nope.shape
+    heads, chunk, qw = q.shape
     keys, width = view.shape
-    latent, dv = w_uv.shape[1], w_uv.shape[2]
-    if q_rope.shape[2] != width - latent or w_uk.shape != (heads, latent, nope):
-        raise ValueError(f"queries {q_nope.shape} / {q_rope.shape} do not match rows "
-                         f"{view.shape} and expansions {w_uk.shape}")
+    latent, nope, dv = w_uk.shape[1], w_uk.shape[2], w_uv.shape[2]
+    if qw != nope + width - latent or w_uk.shape[0] != heads:
+        raise ValueError(f"queries {q.shape} do not match rows {view.shape} and "
+                         f"expansions {w_uk.shape}")
     bq = min(block_q, chunk)
     if chunk % bq:
         raise ValueError(f"{chunk} query rows are not a multiple of {bq}")
@@ -265,25 +290,24 @@ def latent_prefill_attention(q_nope: jax.Array, q_rope: jax.Array, view: jax.Arr
         num_scalar_prefetch=1,
         grid=(heads, nk),
         in_specs=[
-            pl.BlockSpec((1, chunk, nope), lambda h, j, *_: (h, 0, 0)),
-            pl.BlockSpec((1, chunk, width - latent), lambda h, j, *_: (h, 0, 0)),
+            pl.BlockSpec((1, chunk, qw), lambda h, j, *_: (h, 0, 0)),
             pl.BlockSpec((bk, width), key_block),
             pl.BlockSpec((1, latent, nope), lambda h, j, *_: (h, 0, 0)),
             pl.BlockSpec((1, latent, dv), lambda h, j, *_: (h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, dv), lambda h, j, *_: (h, 0, 0)),
         scratch_shapes=[pltpu.VMEM((chunk, dv), jnp.float32),
-                        pltpu.VMEM((chunk, _LANE), jnp.float32),
-                        pltpu.VMEM((chunk, _LANE), jnp.float32)],
+                        pltpu.VMEM((chunk, 1), jnp.float32),
+                        pltpu.VMEM((chunk, 1), jnp.float32)],
     )
     return pl.pallas_call(
         functools.partial(_prefill_kernel, scale=float(scale), latent=latent, block_q=bq,
                           block_k=bk, nk=nk),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((heads, chunk, dv), q_nope.dtype),
+        out_shape=jax.ShapeDtypeStruct((heads, chunk, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
         name="latent_prefill_attention",
-    )(start, q_nope, q_rope, view, w_uk, w_uv)
+    )(start, q, view, w_uk, w_uv)

@@ -457,18 +457,17 @@ def test_latent_decode_attention_sarvam_shape(chip, columns):
 @pytest.mark.parametrize("keys", [8192, 32768], ids=["narrowest_view", "widest_view"])
 def test_latent_prefill_attention_sarvam_shape(chip, keys):
     """The expanded prefill kernel as the chunk program calls it: 64 heads
-    of a chunk of 4,096 queries (128 nope + 128 for the rotary query and
-    the row's zero tail) against a view of ``keys`` rows, expanded 512 rows
-    at a time through each head's 512 x 128 W_uk and W_uv."""
+    of a chunk of 4,096 queries, each ``[q_nope | q_rope | 0]`` (128 + 64 +
+    64), against a view of ``keys`` rows, expanded ``BLOCK_K`` rows at a
+    time through each head's 512 x 128 W_uk and W_uv."""
     from kubeflow_tpu.ops.latent_attention import latent_prefill_attention
 
-    def attend(qn, qr, view, wuk, wuv, start):
-        return latent_prefill_attention(qn, qr, view, wuk, wuv, start, scale=0.1352,
+    def attend(q, view, wuk, wuv, start):
+        return latent_prefill_attention(q, view, wuk, wuv, start, scale=0.1352,
                                         interpret=False)
 
-    assert _compile(chip, attend, ((64, 4096, 128), BF16), ((64, 4096, 128), BF16),
-                    ((keys, 640), BF16), ((64, 512, 128), BF16), ((64, 512, 128), BF16),
-                    ((), I32)) == 1
+    assert _compile(chip, attend, ((64, 4096, 256), BF16), ((keys, 640), BF16),
+                    ((64, 512, 128), BF16), ((64, 512, 128), BF16), ((), I32)) == 1
 
 
 def test_sarvam_programs_fit_the_chip_and_copy_no_arena(chip, monkeypatch):

@@ -24,7 +24,7 @@ from benchmark import weights_sarvam
 from benchmark.reference import sarvam as ref
 from kubeflow_tpu.models import sarvam
 from kubeflow_tpu.models.sarvam import SarvamConfig
-from kubeflow_tpu.ops.latent_attention import (latent_decode_attention,
+from kubeflow_tpu.ops.latent_attention import (_tile_ranges, latent_decode_attention,
                                                latent_prefill_attention)
 from kubeflow_tpu.parallel.moe import shared_expert_layer, sigmoid_top_k
 from kubeflow_tpu.serving.continuous import ContinuousBatcher
@@ -216,26 +216,98 @@ def test_the_decode_kernel_interpreted_against_a_plain_reading(pages):
     assert float(jnp.abs(got[0]).max()) == 0.0
 
 
-@pytest.mark.parametrize("start,keys", [(0, 16), (8, 24), (24, 64)])
-def test_the_prefill_kernel_interpreted_against_a_plain_reading(start, keys):
-    """A chunk of 16 queries at ``start`` against the rows of ``keys``
-    positions, in key blocks of 8 and query tiles of 4: causal by position,
-    every block expanded where the chunk reaches it."""
+def _prefill_operands(start, keys, chunk, dtype):
+    """A kernel call's operands and the plain float32 reading of the same
+    numbers at a scale of 0.2."""
     heads, nope, rope_w, latent, dv = 3, 16, 16, 32, 8
-    width = latent + rope_w
     ks = jax.random.split(jax.random.PRNGKey(11), 5)
-    f = lambda k, shape: jax.random.normal(k, shape, jnp.float32)
-    qn, qr = f(ks[0], (heads, 16, nope)), f(ks[1], (heads, 16, rope_w))
-    view, wuk, wuv = f(ks[2], (keys, width)), f(ks[3], (heads, latent, nope)) * 0.2, \
-        f(ks[4], (heads, latent, dv)) * 0.2
-    got = latent_prefill_attention(qn, qr, view, wuk, wuv, jnp.asarray(start), scale=0.2,
-                                   block_q=4, block_k=8, interpret=True)
-    k = jnp.einsum("jc,hcn->hjn", view[:, :latent], wuk)
-    v = jnp.einsum("jc,hcv->hjv", view[:, :latent], wuv)
-    sc = (jnp.einsum("hqn,hjn->hqj", qn, k) + jnp.einsum("hqr,jr->hqj", qr, view[:, latent:])) * 0.2
-    mask = jnp.arange(keys)[None, :] <= (start + jnp.arange(16))[:, None]
+    f = lambda k, shape: jax.random.normal(k, shape, jnp.float32).astype(dtype)
+    q = f(ks[0], (heads, chunk, nope + rope_w))
+    view, wuk, wuv = (f(ks[2], (keys, latent + rope_w)), f(ks[3], (heads, latent, nope)) * 0.2,
+                      f(ks[4], (heads, latent, dv)) * 0.2)
+    q32, view32, wuk32, wuv32 = (a.astype(jnp.float32) for a in (q, view, wuk, wuv))
+    k = jnp.einsum("jc,hcn->hjn", view32[:, :latent], wuk32)
+    v = jnp.einsum("jc,hcv->hjv", view32[:, :latent], wuv32)
+    sc = (jnp.einsum("hqn,hjn->hqj", q32[..., :nope], k)
+          + jnp.einsum("hqr,jr->hqj", q32[..., nope:], view32[:, latent:])) * 0.2
+    mask = jnp.arange(keys)[None, :] <= (start + jnp.arange(chunk))[:, None]
     want = jnp.einsum("hqj,hjv->hqv", jax.nn.softmax(jnp.where(mask, sc, -jnp.inf), -1), v)
+    return (q, view, wuk, wuv), want
+
+
+@pytest.mark.parametrize("start,keys,chunk", [
+    pytest.param(0, 16, 16, id="0-16"), pytest.param(8, 24, 16, id="8-24"),
+    pytest.param(24, 64, 16, id="24-64"),
+    pytest.param(16, 40, 16, id="two_blocks_in"),
+    pytest.param(13, 29, 16, id="start_on_neither_grid"),
+    pytest.param(10, 14, 4, id="one_tile"),
+    pytest.param(5, 45, 16, id="keys_padded_past_the_chunk")])
+def test_the_prefill_kernel_interpreted_against_a_plain_reading(start, keys, chunk):
+    """A chunk of ``chunk`` queries at ``start`` against the rows of
+    ``keys`` positions, in key blocks of 8 and query tiles of 4: causal by
+    position, every block expanded where the chunk reaches it, the tiles
+    across the diagonal masked and those below it not (every case has
+    both)."""
+    tiles = [_by_hand(start, chunk, j, 4, 8) for j in range(-(-keys // 8))]
+    assert any(m for _, m in tiles) and any(set(c) - set(m) for c, m in tiles)
+    (q, view, wuk, wuv), want = _prefill_operands(start, keys, chunk, jnp.float32)
+    got = latent_prefill_attention(q, view, wuk, wuv, jnp.asarray(start), scale=0.2,
+                                   block_q=4, block_k=8, interpret=True)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+def test_the_bfloat16_prefill_kernel_lies_within_the_served_tolerance():
+    """In bfloat16 (keys, values and probabilities rounded to it, scores,
+    maxima and sums float32), against the float32 reading of the same
+    operands: within ``GAP_LIMIT_SD`` standard deviations of the output."""
+    (q, view, wuk, wuv), want = _prefill_operands(13, 45, 16, jnp.bfloat16)
+    got = latent_prefill_attention(q, view, wuk, wuv, jnp.asarray(13), scale=0.2, block_q=4,
+                                   block_k=8, interpret=True).astype(jnp.float32)
+    assert float(jnp.abs(got - want).max() / want.std()) <= GAP_LIMIT_SD
+
+
+def _by_hand(start, chunk, j, bq, bk):
+    """Of key block ``j``, the query tiles that hold some key at or before
+    some query (computed), and of those the ones that also hold some key
+    past some query (masked)."""
+    k = j * bk + np.arange(bk)
+    computed, masked = [], []
+    for i in range(chunk // bq):
+        q = start + i * bq + np.arange(bq)
+        if (k[:, None] <= q[None, :]).any():
+            computed.append(i)
+            if (k[:, None] > q[None, :]).any():
+                masked.append(i)
+    return computed, masked
+
+
+def _ranges(start, chunk, j, bq, bk):
+    first, inner = (int(x) for x in _tile_ranges(j, start, chunk // bq, bq, bk))
+    return list(range(first, chunk // bq)), list(range(first, inner))
+
+
+@pytest.mark.parametrize("chunk", [4, 16, 32])
+def test_the_tile_ranges_split_where_a_brute_force_reading_does(chunk):
+    """Every key block the kernel expands (some query of the chunk reaches
+    it): the tiles it computes and the ones it masks."""
+    for start in range(0, 41, 3):
+        for j in range((start + chunk - 1) // 8 + 1):
+            assert _ranges(start, chunk, j, 4, 8) == _by_hand(start, chunk, j, 4, 8), (start, j)
+
+
+def test_the_tile_ranges_at_the_cell_s_chunk():
+    """Chunks of 4,096 in the module's tiles of 512 x 1,024: each of the
+    chunk's own 4 blocks masks 2 tiles; a block wholly below the chunk
+    masks none and computes all 8."""
+    from kubeflow_tpu.ops.latent_attention import BLOCK_K, BLOCK_Q
+
+    assert (BLOCK_Q, BLOCK_K) == (512, 1024)
+    for start in (0, 28672):
+        for j in range(start // 1024, start // 1024 + 4):
+            assert _ranges(start, 4096, j, 512, 1024) == (
+                list(range(2 * (j - start // 1024), 8)),
+                list(range(2 * (j - start // 1024), 2 * (j - start // 1024) + 2)))
+    assert _ranges(28672, 4096, 27, 512, 1024) == (list(range(8)), [])
 
 
 # -- YaRN at the published sizes, computed here -------------------------------------
