@@ -81,7 +81,7 @@ def _visits(group_sizes: jax.Array, m: int, tm: int) -> jax.Array:
                            ).astype(jnp.int32)
 
 
-def _kernel(plan_ref, x_ref, *refs, tm: int, group_at: int, tile_at: int):
+def _kernel(plan_ref, x_ref, *refs, tm: int, group_at: int, tile_at: int, relu2=False):
     *w_refs, o_ref = refs
     v = pl.program_id(1)
     g, t = plan_ref[group_at + v], plan_ref[tile_at + v]
@@ -91,8 +91,8 @@ def _kernel(plan_ref, x_ref, *refs, tm: int, group_at: int, tile_at: int):
     x = x_ref[...]
     y = jnp.dot(x, w_refs[0][...], preferred_element_type=jnp.float32)
     if len(w_refs) == 2:
-        y = jax.nn.silu(y) * jnp.dot(x, w_refs[1][...],
-                                     preferred_element_type=jnp.float32)
+        y = jax.nn.silu(y) * jnp.dot(x, w_refs[1][...], preferred_element_type=jnp.float32)
+    y = jnp.square(jnp.maximum(y, 0.0)) if relu2 else y
     # the other groups' rows: what their visits made them, zeros before
     kept = jnp.where(opened, jnp.zeros((), o_ref.dtype), o_ref[...])
     o_ref[...] = jnp.where(mine, y.astype(o_ref.dtype), kept)
@@ -108,9 +108,9 @@ def _column_tile(k: int, n: int, itemsize: int) -> int:
     return max(fits, default=_LANE)
 
 
-@functools.partial(jax.jit, static_argnames=("out_dtype", "name", "interpret"))
+@functools.partial(jax.jit, static_argnames=("out_dtype", "name", "interpret", "relu2"))
 def _call(lhs: jax.Array, stacks: Tuple[jax.Array, ...], group_sizes: jax.Array,
-          out_dtype, name: str, interpret: bool) -> jax.Array:
+          out_dtype, name: str, interpret: bool, relu2: bool = False) -> jax.Array:
     m, k = lhs.shape
     groups, k_rhs, n = stacks[0].shape
     if k != k_rhs or any(w.shape != stacks[0].shape for w in stacks) \
@@ -127,7 +127,7 @@ def _call(lhs: jax.Array, stacks: Tuple[jax.Array, ...], group_sizes: jax.Array,
                    + 2 * tm * k * lhs.dtype.itemsize
                    + (2 + 2 * len(stacks)) * tm * tn * 4)
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm, group_at=group_at, tile_at=tile_at),
+        functools.partial(_kernel, tm=tm, group_at=group_at, tile_at=tile_at, relu2=relu2),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n // tn, plan[0]),
@@ -165,3 +165,14 @@ def grouped_swiglu(lhs: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     :func:`grouped_matmul`. Returns [m, n] in ``lhs``'s type."""
     return _call(lhs, (w_gate, w_up), group_sizes, lhs.dtype, "grouped_swiglu",
                  _interpret_default() if interpret is None else interpret)
+
+
+def grouped_relu2(lhs: jax.Array, w_up: jax.Array, group_sizes: jax.Array, *,
+                  interpret: Optional[bool] = None) -> jax.Array:
+    """``relu(lhs[r] @ w_up[g]) ** 2`` for every row r of group g (an expert
+    with one matrix in and no gate: models/nemotron.py), the product and the
+    square in float32, rounded once to ``lhs``'s type; shapes and the rows
+    past the groups as :func:`grouped_matmul`. Returns [m, n] in ``lhs``'s
+    type."""
+    return _call(lhs, (w_up,), group_sizes, lhs.dtype, "grouped_relu2",
+                 _interpret_default() if interpret is None else interpret, relu2=True)

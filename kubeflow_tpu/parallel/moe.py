@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kubeflow_tpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
+from kubeflow_tpu.ops.grouped_matmul import grouped_matmul, grouped_relu2, grouped_swiglu
 from kubeflow_tpu.parallel.mesh import AXIS_EXPERT, BATCH_AXES
 
 
@@ -130,8 +130,9 @@ class MoEMlp(nn.Module):
 # -- dropless expert layer that is told which experts it holds ----------------
 #
 # The serving path's expert layer (models/mimo.py, models/sdar.py, and with a
-# shared expert beside it and the routed part scaled, models/sarvam.py:
-# ``shared_expert_layer``). Unlike ``MoEMlp`` above it
+# shared expert beside it and the routed part scaled, models/sarvam.py and,
+# relu² experts in a latent, models/nemotron.py: ``shared_expert_layer``).
+# Unlike ``MoEMlp`` above it
 # has no capacity and drops nothing: the router scores ALL experts, the
 # assignments that land on the experts held here are sorted by expert and go
 # through grouped matrix products, and the layer returns the held experts'
@@ -167,15 +168,17 @@ def softmax_top_k(h: jax.Array, router: jax.Array, k: int
 
 
 def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
-                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                     w_gate: Optional[jax.Array], w_up: jax.Array, w_down: jax.Array,
                      first_held: int = 0, live: Optional[jax.Array] = None,
                      ) -> Tuple[jax.Array, jax.Array]:
-    """The held experts' part of a SwiGLU expert layer, dropless.
+    """The held experts' part of an expert layer, dropless.
 
     h [T, d]; idx / weights [T, k] from the router over all experts; the
     experts ``first_held .. first_held + n_held - 1`` live here, stacked in
     ``w_gate`` / ``w_up`` [n_held, d, f] and ``w_down`` [n_held, f, d].
-    ``live`` [T] bool leaves rows out (padding, dead slots). Returns
+    An expert is ``(silu(h w_gate) * h w_up) w_down`` (SwiGLU), or with
+    ``w_gate`` None ``relu(h w_up)² w_down`` (relu²). ``live`` [T] bool
+    leaves rows out (padding, dead slots). Returns
     (``sum over held chosen experts of weight * expert(h)`` [T, d] in h's
     type, stats int32 [3] = assignments on held experts, the busiest held
     expert's, held experts touched).
@@ -187,7 +190,7 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
     and never visit the rows past the groups.
     """
     T, k = idx.shape
-    n_held = w_gate.shape[0]
+    n_held = w_down.shape[0]
     local = idx.reshape(-1) - first_held
     held = (local >= 0) & (local < n_held)
     if live is not None:
@@ -200,7 +203,8 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
         rows = h[order // k]                                     # [T*k, d]
         in_group = jnp.arange(T * k) < jnp.sum(sizes)
     with jax.named_scope("moe_experts"):
-        mid = grouped_swiglu(rows, w_gate, w_up, sizes)          # in h's type
+        mid = (grouped_relu2(rows, w_up, sizes) if w_gate is None
+               else grouped_swiglu(rows, w_gate, w_up, sizes))   # in h's type
         out = grouped_matmul(mid, w_down, sizes,
                              preferred_element_type=jnp.float32)
     with jax.named_scope("moe_combine"):
@@ -219,16 +223,32 @@ def shared_expert_layer(h: jax.Array, idx: jax.Array, weights: jax.Array, p,
                         live: Optional[jax.Array] = None,
                         ) -> Tuple[jax.Array, jax.Array]:
     """An expert layer with a shared expert beside the routed ones
-    (models/sarvam.py): ``shared(h) + routed_scale x`` the held experts'
-    part (:func:`held_experts_ffn`). The shared SwiGLU (``p["shared"]``:
-    ``w_gate``, ``w_up`` [d, f], ``w_down`` [f, d]) sees every token and is
-    computed whole on every chip that holds a share of the routed experts
-    (``p``'s ``w_gate``, ``w_up``, ``w_down``). h [T, d] in the compute
-    type. Returns (float32 [T, d], the held experts' stats int32 [3])."""
-    routed, stats = held_experts_ffn(h, idx, weights, p["w_gate"], p["w_up"], p["w_down"],
-                                     first_held=first_held, live=live)
+    (models/sarvam.py, models/nemotron.py): ``shared(h) + routed_scale x``
+    the held experts' part (:func:`held_experts_ffn`). The shared expert
+    (``p["shared"]``: ``w_gate``, ``w_up`` [d, f], ``w_down`` [f, d]) sees
+    every token and is computed whole on every chip that holds a share of
+    the routed experts (``p``'s ``w_gate``, ``w_up``, ``w_down``). Either
+    expert without a ``w_gate`` is relu², ``relu(h w_up)² w_down``. Where ``p``
+    holds ``latent_down`` [d, L] and ``latent_up`` [L, d] the routed experts
+    work in a latent of L (scope ``moe_latent``): their input is ``h
+    latent_down``, and their weighted sum goes back through ``latent_up``.
+    h [T, d] in the compute type. Returns (float32 [T, d], the held experts'
+    stats int32 [3])."""
+    x = h
+    if "latent_down" in p:
+        with jax.named_scope("moe_latent"):
+            x = jnp.dot(h, p["latent_down"])
+    routed, stats = held_experts_ffn(x, idx, weights, p.get("w_gate"), p["w_up"],
+                                     p["w_down"], first_held=first_held, live=live)
+    if "latent_up" in p:
+        with jax.named_scope("moe_latent"):
+            routed = jnp.dot(routed, p["latent_up"], preferred_element_type=jnp.float32)
     with jax.named_scope("shared_expert"):
         s = p["shared"]
-        mid = jax.nn.silu(jnp.dot(h, s["w_gate"])) * jnp.dot(h, s["w_up"])
-        shared = jnp.dot(mid, s["w_down"], preferred_element_type=jnp.float32)
+        if "w_gate" not in s:
+            up = jnp.dot(h, s["w_up"], preferred_element_type=jnp.float32)
+            mid = jnp.square(jax.nn.relu(up))
+        else:
+            mid = jax.nn.silu(jnp.dot(h, s["w_gate"])) * jnp.dot(h, s["w_up"])
+        shared = jnp.dot(mid.astype(h.dtype), s["w_down"], preferred_element_type=jnp.float32)
     return shared + routed_scale * routed.astype(jnp.float32), stats

@@ -430,11 +430,13 @@ class ContinuousBatcher:
         # second kind of cache, a ring a slot beside the block table (a
         # dispatch moves a cursor by up to ``chunk`` positions), and a row
         # of its table may stand for more than one position, and a step
-        # may work on a block of positions past the cursor.
+        # may work on a block of positions past the cursor, and a family may
+        # keep a fixed-size state a slot beside its blocks.
         self.kv = (SlotKV(slots, cfg.max_seq, self.kv_block_t, self.family.kv_blocks,
                           engine_id=self.engine_id,
                           rings=self.family.rings(self.chunk, self.engine_id),
-                          stride=self.family.kv_stride, ahead=self.family.kv_ahead)
+                          stride=self.family.kv_stride, ahead=self.family.kv_ahead,
+                          states=self.family.states())
                    if paged else ContiguousKV())
         # every view width's decode program is compiled once, at the first
         # prewarm: "no" -> "asked" (prewarm) -> "done" (the engine thread,

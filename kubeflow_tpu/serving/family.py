@@ -43,9 +43,19 @@ form (each head's query folded into latent space, each page fetched once
 for the scores and the context). The expert layer's counters and the
 latent pages the decode attention fetched ride home with the tokens.
 
+``NemotronFamily`` (``NemotronConfig``): Mamba-2 state-space layers beside
+attention and latent expert layers, so a fourth kind of cache: a FIXED-size
+state a slot and Mamba layer (``paged.SlotStates``), never paged, zeroed at
+a prompt's first chunk and carried from chunk to chunk, read and written by
+every decode step of a live row and left alone for the others. It is the
+first family whose chunk program is told the slot. The attention layers'
+arenas are the block table's, and prompts prefill in chunks straight into
+both; the expert and state counters ride home with the tokens.
+
 What the engine asks of a family, beside its programs: ``rings(lookahead,
 engine_id)`` (the kind of cache that keeps a ring a slot beside the block
-table, or None), ``kv_stride`` (positions a row of the block table stands
+table, or None), ``states()`` (the kind that keeps a fixed-size state a
+slot, or None), ``kv_stride`` (positions a row of the block table stands
 for), ``prefills_in_arena`` (every prompt in chunks straight into the
 arenas: no private cache, no adopt, no speculation or hand-off roles),
 ``has_stats`` (the step and the prefill return counters beside the tokens,
@@ -72,11 +82,12 @@ import jax.numpy as jnp
 from ..models.evabyte import EvaConfig
 from ..models.gpt import GptConfig, GptLM
 from ..models.mimo import FULL, WINDOW, MimoConfig
+from ..models.nemotron import NemotronConfig
 from ..models.sarvam import SarvamConfig
 from ..models.sdar import SdarConfig
-from ..models import evabyte, mimo, sarvam, sdar
+from ..models import evabyte, mimo, nemotron, sarvam, sdar
 from ..runtime.metrics import METRICS
-from .paged import AlignedWindows, WindowRings
+from .paged import AlignedWindows, SlotStates, WindowRings
 
 
 def sample_next(lg: jax.Array, temps: jax.Array, rngs: jax.Array):
@@ -116,6 +127,10 @@ class _TokenAStep:
     def cursor_moves(self, chunk: int) -> int:
         """A dispatch of ``chunk`` steps moves a cursor by as many positions."""
         return chunk
+
+    def states(self) -> None:
+        """No state a slot beside the blocks."""
+        return None
 
 
 class GptFamily(_TokenAStep):
@@ -562,6 +577,10 @@ class SdarFamily:
         """One kind of cache: no ring beside the block table."""
         return None
 
+    def states(self) -> None:
+        """No state a slot beside the blocks."""
+        return None
+
     def cursor_moves(self, chunk: int) -> int:
         """A block takes a denoising pass and a commit at least: of
         ``chunk`` passes at most every other one commits."""
@@ -744,11 +763,100 @@ class SarvamFamily(_TokenAStep):
         return _build_activate()
 
 
+class NemotronFamily(_TokenAStep):
+    """``models/nemotron.py`` in the engine: the attention layers' paged
+    arenas (the block table alone) and the Mamba layers' states, one a slot
+    (``paged.SlotStates``); prompts prefilled chunk by chunk straight into
+    both, one token a slot and step, the expert and state counters riding
+    home with the tokens."""
+
+    has_stats = True
+    prefills_in_arena = True
+    kv_stride = 1
+
+    def __init__(self, cfg: NemotronConfig, *, slots: int, paged: bool = True,
+                 kv_blocks: int = 0, kv_block_t: int = 16,
+                 kv_dtype: str = "bf16"):
+        if not paged or kv_dtype != "bf16":
+            raise ValueError("this model family prefills into a paged bf16 arena "
+                             "(paged=True, kv_dtype='bf16')")
+        self.cfg, self.slots, self.kv_block_t = cfg, slots, kv_block_t
+        self.kv_blocks = kv_blocks or slots * (cfg.max_seq // kv_block_t)
+
+    def rings(self, lookahead: int, engine_id: str = "0") -> None:
+        """No ring beside the block table."""
+        return None
+
+    def states(self) -> SlotStates:
+        """A state a slot beside the block table."""
+        return SlotStates(self.slots)
+
+    def account(self, stats, tokens: int) -> Dict[str, int]:
+        """An event's counters (``models/nemotron.STATS``) into the
+        program's own and the ``serving.engine.deliver`` region's stats: as
+        the MiMo family's, the Mamba states the event's steps or chunks read
+        and wrote (``ssm_rows``, summed over the layers), the prompts whose
+        first chunk zeroed their slot's states, and the KV pages the decode
+        attention fetched (``kv_pages_read``, a layer; 0 for a prefill
+        chunk's event)."""
+        on_held, busiest, touched, rows, resets, pages = stats
+        routed = tokens * self.cfg.experts_per_token * self.cfg.pattern.count(nemotron.EXPERTS)
+        METRICS.counter("serving_moe_assignments_total", held="true").inc(on_held)
+        METRICS.counter("serving_moe_assignments_total", held="false").inc(
+            max(routed - on_held, 0))
+        METRICS.counter("serving_ssm_state_rows_total").inc(rows)
+        METRICS.counter("serving_ssm_states_reset_total").inc(resets)
+        return {"expert_tokens": on_held, "expert_tokens_max": busiest,
+                "experts_touched": touched, "ssm_rows": rows, "kv_pages_read": pages}
+
+    def fresh_cache(self) -> Dict[str, Any]:
+        return nemotron.fresh_cache(self.cfg, self.slots, self.kv_blocks, self.kv_block_t)
+
+    def build_step(self, chunk: int):
+        """``chunk`` tokens for every slot, and the counters of the whole
+        chunk. A dispatch takes the block table's first columns and which
+        rows are live."""
+        cfg, trash = self.cfg, self.kv_blocks
+
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 4))
+        def step(params, cache, tok, temps, rngs, table, live):
+            def one(carry, _):
+                cache, tok, rngs, stats = carry
+                logits, cache, st = nemotron.decode_step(cfg, params, cache, tok, table,
+                                                         live, trash)
+                with jax.named_scope("sample"):
+                    nxt, rngs = sample_next(logits, temps, rngs)
+                return (cache, nxt, rngs, stats + st), nxt
+
+            (cache, tok, rngs, stats), toks = jax.lax.scan(
+                one, (cache, tok, rngs, jnp.zeros((nemotron.STATS,), jnp.int32)), None,
+                length=chunk)
+            return cache, tok, rngs, jnp.moveaxis(toks, 0, 1), stats
+
+        return step
+
+    def build_chunk_prefill(self):
+        cfg = self.cfg
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def prefill_chunk(params, cache, ids, start, n_valid, temperature, key,
+                          read, write, slot):
+            logits, cache, stats = nemotron.prefill_chunk(
+                cfg, params, cache, ids, start, n_valid, read, write, slot)
+            return cache, _sample_first(logits, temperature, key), stats
+
+        return prefill_chunk
+
+    def build_activate(self):
+        return _build_activate()
+
+
 _FAMILIES: Tuple[Tuple[type, type], ...] = ((GptConfig, GptFamily),
                                             (MimoConfig, MimoFamily),
                                             (EvaConfig, EvaFamily),
                                             (SdarConfig, SdarFamily),
-                                            (SarvamConfig, SarvamFamily))
+                                            (SarvamConfig, SarvamFamily),
+                                            (NemotronConfig, NemotronFamily))
 
 
 def family_for(cfg: Any, **geometry: Any):

@@ -11,7 +11,9 @@ advance, published as ``serving_kv_blocks_{free,used}`` gauges so arena
 sizing is an observable capacity knob rather than a silent OOM; the kinds
 of cache that keep a ring of blocks a slot beside the block table
 (:class:`WindowRings`, a sliding window; :class:`AlignedWindows`, the
-current one of a row of aligned windows); and :class:`SlotKV`, the ONE owner of
+current one of a row of aligned windows); the kind that keeps a FIXED-size
+state a slot and no block at all (:class:`SlotStates`, a state-space
+layer's); and :class:`SlotKV`, the ONE owner of
 every slot's KV on the host, whose verbs the engine
 (``serving/continuous.py``) performs without knowing table, trash or rings.
 
@@ -331,6 +333,34 @@ class AlignedWindows(WindowRings):
                 "rollovers": sum(to // self.window > at // self.window for at, to in moves)}
 
 
+class SlotStates:
+    """The state kind of cache: a FIXED-size state a slot (a state-space
+    layer's, ``models/nemotron.py``), not a block a position. It reckons no
+    positions (no block of it is reserved, granted, given back or sent to
+    trash), and a slot's state is its own from admission on: the device
+    holds one a slot, at the slot's index. The program zeroes it
+    at a prompt's first chunk and carries it from chunk to chunk, and every
+    decode step reads and writes it whole for a LIVE row only: a slot that
+    is still prefilling sits through decode dispatches between its chunks,
+    and its state must come out of them as it went in. So a chunk's program
+    is told the slot (:meth:`chunk_args`) and a dispatch which rows are live
+    (:meth:`dispatch_args`)."""
+
+    def __init__(self, slots: int):
+        self.slots = int(slots)
+
+    def chunk_args(self, slot: int) -> Tuple[np.ndarray, ...]:
+        return (np.asarray(slot, np.int32),)
+
+    def dispatch_args(self, active: Iterable[int]) -> Tuple[Any, ...]:
+        live = np.zeros((self.slots,), bool)
+        live[list(active)] = True
+        return (jnp.asarray(live),)
+
+    def warm_args(self) -> Tuple[Any, ...]:
+        return (jnp.zeros((self.slots,), bool),)
+
+
 class SlotKV:
     """The one owner of every slot's KV on the host. Only this class knows
     the trash id, the table's layout (``[slots, max_blocks]``, ONE table for
@@ -346,6 +376,9 @@ class SlotKV:
     row is WRITTEN when its chunk completes, so ``tokens`` positions need
     ``tokens // stride`` rows, and is READ only once the cursor has left its
     window, so what a step reads (:meth:`_rows_read`) lags what is written.
+
+    ``states``: a family's state kind beside the blocks (:class:`SlotStates`),
+    or None; its arguments follow the tables of a dispatch and of a chunk.
 
     ``ahead``: positions past a slot's cursor that a step writes and reads.
     0 where a step appends the position at its cursor. Over 0 is a family
@@ -369,10 +402,10 @@ class SlotKV:
     def __init__(self, slots: int, max_seq: int, block_t: int, n_blocks: int,
                  *, engine_id: str = "0",
                  rings: Optional[WindowRings] = None, stride: int = 1,
-                 ahead: int = 0):
+                 ahead: int = 0, states: Optional[SlotStates] = None):
         self.slots, self.max_seq, self.block_t = int(slots), int(max_seq), int(block_t)
         self.engine_id, self.stride, self.ahead = engine_id, int(stride), int(ahead)
-        self.rings = rings
+        self.rings, self.states = rings, states
         self.kind = ("" if rings is None else "full" if self.stride == 1 else "summary")
         self.alloc = KVBlockAllocator(n_blocks, block_t, engine_id=engine_id,
                                       kind=self.kind)
@@ -519,7 +552,8 @@ class SlotKV:
         which rows are live, the blocks in use by kind, and the pages of
         the append-only kind that the last step's attention fetches
         (``ops/paged_attention``: each live row its own pages, within the
-        view; :meth:`_rows_read`), then the ring kind's own."""
+        view; :meth:`_rows_read`), then the ring kind's own; with a state
+        kind, last, which rows are live."""
         view = view_blocks(self.tables, self.alloc.trash, self.view_widths)
         METRICS.gauge("serving_decode_view_blocks",
                       replica=self.engine_id).set(view)
@@ -539,6 +573,8 @@ class SlotKV:
             tables += (jnp.asarray(np.where(live[:, None], rings.tables,
                                             rings.trash)),
                        jnp.asarray(live))
+        if self.states is not None:
+            tables += self.states.dispatch_args(active)
         return tables, stats
 
     def warm_tables(self) -> List[Tuple[Any, ...]]:
@@ -549,6 +585,8 @@ class SlotKV:
         if self.rings is not None:
             ring = (jnp.full((self.slots, self.rings.cols), self.rings.trash,
                              jnp.int32), jnp.zeros((self.slots,), bool))
+        if self.states is not None:
+            ring += self.states.warm_args()
         return [(jnp.full((self.slots, width), self.alloc.trash, jnp.int32),)
                 + ring for width in self.view_widths]
 
@@ -563,7 +601,7 @@ class SlotKV:
         and the ring blocks the chunk writes. Grants the chunk its blocks of both kinds first; the
         ring's older blocks go back before the new ones are granted, so the
         chunk writes only what the next reader (the next chunk, or decode)
-        can still see."""
+        can still see. With a state kind, last, the slot."""
         res, bt, trash = self._res[slot], self.block_t, self.alloc.trash
         table = self._filling.get(slot)
         if table is None:
@@ -577,7 +615,8 @@ class SlotKV:
             write_full[:held - first_block] = table[first_block:held]
         rings = self.rings
         if rings is None:
-            return table[:view], write_full
+            return (table[:view], write_full) + (
+                () if self.states is None else self.states.chunk_args(slot))
         read_ring = rings.row(slot).copy()
         rings.advance(slot, end, end)
         write_ring = np.asarray(

@@ -516,3 +516,58 @@ def test_sarvam_programs_fit_the_chip_and_copy_no_arena(chip, monkeypatch):
     text = chunk.as_text()
     assert kernel("latent_prefill_attention") == cfg.n_layers
     assert chunk.memory_analysis().temp_size_in_bytes < 1.4e9
+
+
+def test_nemotron_programs_fit_the_chip_and_copy_no_state(chip, monkeypatch):
+    """The cell's two device programs at the published widths (one period
+    of 11 layers: 5 Mamba, 5 latent expert layers with 128 of 512 experts
+    held, 1 attention layer; 32,768 ids; 64 slots; 65,536 pages): the decode
+    dispatch of 16 steps holds the state update once a Mamba layer, the
+    paged attention once and the two grouped products once an expert layer,
+    and moves no Mamba state into another buffer (the update aliases it);
+    the chunk program of 2,048 rows holds ``chunk_attention`` once; weights,
+    states and arenas are 11.8 GB and each program's temporaries under 1.5
+    GB, under the chip's 16."""
+    import re
+
+    from kubeflow_tpu.models import nemotron
+    from kubeflow_tpu.ops import chunk_attention as chunk_module
+    from kubeflow_tpu.ops import grouped_matmul as grouped_module, paged_attention, ssm
+    from kubeflow_tpu.serving.family import NemotronFamily
+
+    for module in (chunk_module, grouped_module, paged_attention, ssm):
+        monkeypatch.setattr(module, "_interpret_default", lambda: False)
+    cfg = nemotron.NemotronConfig()
+    family = NemotronFamily(cfg, slots=64, kv_blocks=65536, kv_block_t=16)
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+
+    one = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    params = described(jax.eval_shape(lambda: nemotron.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = described(jax.eval_shape(family.fresh_cache))
+    step = family.build_step(16).lower(
+        params, cache, one((64,), I32), one((64,), F32), one((64, 2), jnp.uint32),
+        one((64, 1024), I32), one((64,), jnp.bool_)).compile()
+    text = step.as_text()
+    # a kernel's own line: its name left of the '=' (a consumer's line
+    # names it among its operands)
+    kernel = lambda name: sum('custom_call_target="tpu_custom_call"' in line
+                              and name in line.split("=")[0] for line in text.splitlines())
+    mamba, experts = cfg.pattern.count("M"), cfg.pattern.count("E")
+    assert kernel("ssm_decode_update") == mamba
+    assert kernel("paged_decode_attention") == cfg.pattern.count("*")
+    assert kernel("grouped_relu2") == kernel("grouped_matmul") == experts
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= f32\[64,8,16,64,128\]\S* copy(-start)?\(", line)]
+    assert not copies, copies
+    memory = step.memory_analysis()
+    assert 11.6e9 < memory.argument_size_in_bytes < 12.0e9
+    assert memory.temp_size_in_bytes < 1.5e9
+    chunk = family.build_chunk_prefill().lower(
+        params, cache, one((2048,), I32), one((), I32), one((), I32), one((), F32),
+        one((2,), jnp.uint32), one((1024,), I32), one((128,), I32), one((), I32)).compile()
+    text = chunk.as_text()
+    assert kernel("chunk_attention") == cfg.pattern.count("*")
+    assert chunk.memory_analysis().temp_size_in_bytes < 1.5e9

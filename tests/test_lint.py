@@ -197,6 +197,16 @@ F32_MATMUL_ALLOWLIST = {
     ("sarvam.py", "_head"),
     ("sarvam.py", "_decode_attention"),
     ("sarvam.py", "_chunk_attention"),
+    # the Nemotron family: bf16 operands, float32 ACCUMULATION where the
+    # result feeds the state-space recurrence (the input projection: dt,
+    # B, C and the gate stay float32 to the state), is a part of the float32
+    # residual stream (the mixers' output projections) or the float32
+    # logits head; the scan and the state update live in ops/ssm.py
+    ("nemotron.py", "_project_in"),
+    ("nemotron.py", "_project_out"),
+    ("nemotron.py", "_head"),
+    ("nemotron.py", "_decode_attention"),
+    ("nemotron.py", "_chunk_attention"),
 }
 
 _MATMUL_CALLEES = {"einsum", "matmul", "dot", "tensordot", "dot_general"}
