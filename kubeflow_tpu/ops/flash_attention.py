@@ -14,6 +14,17 @@ k/v block from another device and masks by that block's global position
 minor-most grid dim, so accumulators live in VMEM scratch across k-block
 steps (the canonical Pallas accumulation pattern).
 
+The grid's tiles stay large (up to 1,024 a side, ``_block_sizes``), so at a
+causal length of 1,024 one grid step holds a head's whole square. The two
+backward kernels classify each grid step by the diagonal (``_walk``): a step
+wholly above it is skipped, one wholly below it runs whole and unmasked, and
+only a step that the diagonal crosses walks its tile in square sub-tiles of
+``_SUB``, skipping those above the diagonal and masking only those it
+crosses. The forward runs each step it cannot skip whole and masked: walked
+in sub-tiles it measured slower, since each row of sub-tiles pays the online
+softmax's running state (``_block_sizes``). Non-causal calls run every step
+whole and unmasked.
+
 The reference has no kernels of any kind (SURVEY.md §2.9: its only compiled
 code is five Go control-plane binaries); this module is part of the
 in-workload compute path the TPU-native build adds.
@@ -32,6 +43,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_BIG = -1e30
 _LANE = 128
+#: Side of the square sub-tiles the backward kernels walk a grid step that
+#: the causal diagonal crosses in (see ``_block_sizes`` for how it was chosen).
+_SUB = 256
 
 #: ``checkpoint_name``s of the two residuals the forward kernel makes: its
 #: output and the log-sum-exp. A ``jax.checkpoint`` whose policy saves both
@@ -99,6 +113,18 @@ def _block_sizes(lq: int, lk: int, block_q: Optional[int], block_k: Optional[int
     # overlaps the flash bwd with surrounding matmuls differently than any
     # attention-only microbenchmark. The 1024 cap stands on the end-to-end
     # number; tune via explicit block_q/block_k, not the auto default.
+    # The causal work is cut inside a grid step instead: the tile stays at
+    # up to 1,024, and the backward walks a step the diagonal crosses in
+    # sub-tiles of _SUB, skipping those above it and masking only those on
+    # it (_walk). Measured on v5e at b8 h16 L1024 d64 bfloat16 (ms a call,
+    # forward / dq / dkv), the whole square 0.457 / 0.586 / 0.832; walked
+    # in sub-tiles of 128 0.491 / 0.419 / 1.060, of 256 0.642 / 0.403 /
+    # 0.618, of 512 0.622 / 0.458 / 0.654. The forward is slower walked at
+    # every size (each row of sub-tiles pays the online softmax's running
+    # state), so it runs its step whole. The backward is fastest at 256,
+    # and so is the training cell (gpt2-medium.train.b8x1024, tokens/s,
+    # the forward whole): 128 -> 32,050, 256 -> 33,492, 512 -> 33,195,
+    # against 32,237 for the whole square.
     cap = _auto_tile_cap()
     bq = _auto_block(lq, cap) if block_q is None else min(block_q, lq)
     bk = _auto_block(lk, cap) if block_k is None else min(block_k, lk)
@@ -107,6 +133,105 @@ def _block_sizes(lq: int, lk: int, block_q: Optional[int], block_k: Optional[int
             f"block sizes ({bq}, {bk}) must divide sequence lengths ({lq}, {lk})"
         )
     return bq, bk
+
+
+# ---------------------------------------------------------------------------
+# causal sub-tiles
+# ---------------------------------------------------------------------------
+
+
+def _sub_side(block: int) -> int:
+    """Side of the sub-tiles a grid step walks its block in: ``_SUB`` where
+    it divides the block, else the whole block."""
+    return _SUB if block % _SUB == 0 else block
+
+
+def _sub_tile_class(gap, rows: int, cols: int):
+    """Of ``rows`` queries against ``cols`` keys, the first query ``gap``
+    positions after the first key: whether some query sees some key
+    (``seen``; else the tile is skipped) and whether every query sees every
+    key (``full``; then it needs no mask). Python bools for a Python
+    ``gap``, traced ones where a program id enters."""
+    return gap + rows - 1 >= 0, gap >= cols - 1
+
+
+def _gap(q_offset: int, k_offset: int, block_q: int, block_k: int,
+         nq: int, nk: int, q_axis: int, k_axis: int):
+    """The grid step's first query position less its first key position,
+    and that gap in a step that crosses the diagonal. Both are Python ints
+    where the grid has one block along each axis. Where program ids enter,
+    square blocks whose offsets differ by whole blocks cross only at a gap
+    of 0; any other grid gives ``None``: its crossing steps run masked whole."""
+    base = q_offset - k_offset
+    if nq == nk == 1:
+        return base, base
+    gap = base
+    if nq > 1:
+        gap = gap + pl.program_id(q_axis) * block_q
+    if nk > 1:
+        gap = gap - pl.program_id(k_axis) * block_k
+    return gap, (0 if block_q == block_k and base % block_q == 0 else None)
+
+
+def _mask(s, gap):
+    """Scores ``s`` of queries whose first lies ``gap`` positions after the
+    first key, ``_NEG_BIG`` where a key lies past its query."""
+    rows, cols = s.shape
+    q_pos = gap + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+    return jnp.where(q_pos >= k_pos, s, _NEG_BIG)
+
+
+def _walk(group, causal: bool, gap, crossing, block_q: int, block_k: int,
+          *, keys_outer: bool = False):
+    """Call ``group(o0, size, parts)`` over a ``block_q x block_k`` grid
+    step: ``parts`` lists as ``(i0, size, mask)`` the spans along the inner
+    axis (queries with ``keys_outer``, the dk/dv accumulation; else keys)
+    that the rows ``o0 .. o0 + size - 1`` of the outer axis see, where
+    ``mask`` is ``None`` or the span's gap to pass to ``_mask``.
+
+    A step the causal mask leaves whole (or a call that is not causal) is
+    one group of one unmasked span; a step wholly above the diagonal is
+    skipped. Only a step that crosses it walks its sub-tiles: by rows of
+    sub-tiles, skipping those above the diagonal, a run of those below as
+    one unmasked span, masking those crossing it. ``gap`` and ``crossing``
+    come from ``_gap``; where ``gap`` is traced, the step's class is
+    guarded with ``pl.when``."""
+    outer_block, inner_block = (block_k, block_q) if keys_outer else (block_q, block_k)
+    whole = functools.partial(group, 0, outer_block)
+    if not causal:
+        whole([(0, inner_block, None)])
+        return
+    seen, full = _sub_tile_class(gap, block_q, block_k)
+    if isinstance(seen, bool):
+        if full:
+            whole([(0, inner_block, None)])
+        elif seen:
+            _walk_sub_tiles(group, gap, block_q, block_k, keys_outer)
+        return
+    pl.when(full)(functools.partial(whole, [(0, inner_block, None)]))
+    if crossing is None:
+        pl.when(seen & ~full)(functools.partial(whole, [(0, inner_block, gap)]))
+    else:
+        pl.when(seen & ~full)(functools.partial(
+            _walk_sub_tiles, group, crossing, block_q, block_k, keys_outer))
+
+
+def _walk_sub_tiles(group, gap: int, block_q: int, block_k: int, keys_outer: bool):
+    sq, sk = _sub_side(block_q), _sub_side(block_k)
+    outer_block, inner_block = (block_k, block_q) if keys_outer else (block_q, block_k)
+    outer, inner = (sk, sq) if keys_outer else (sq, sk)
+    for o0 in range(0, outer_block, outer):
+        parts = []
+        for i0 in range(0, inner_block, inner):
+            r0, c0 = (i0, o0) if keys_outer else (o0, i0)
+            seen, full = _sub_tile_class(gap + r0 - c0, sq, sk)
+            if full and parts and parts[-1][2] is None and sum(parts[-1][:2]) == i0:
+                parts[-1] = (parts[-1][0], parts[-1][1] + inner, None)
+            elif seen:
+                parts.append((i0, inner, None if full else gap + r0 - c0))
+        if parts:
+            group(o0, outer, parts)
 
 
 # ---------------------------------------------------------------------------
@@ -236,7 +361,7 @@ def _fwd(
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
     *, scale: float, causal: bool, q_offset: int, k_offset: int,
-    block_q: int, block_k: int, nk: int, dot_dtype,
+    block_q: int, block_k: int, nq: int, nk: int, dot_dtype,
 ):
     ik = pl.program_id(3)
 
@@ -244,38 +369,35 @@ def _bwd_dq_kernel(
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    iq = pl.program_id(2)
-    q_lo = q_offset + iq * block_q
-    k_lo = k_offset + ik * block_k
+    def _row(r0, rows, parts):
+        at = slice(r0, r0 + rows)
+        q = q_ref[0, 0, at, :].astype(dot_dtype)
+        do = do_ref[0, 0, at, :].astype(dot_dtype)
+        lse, delta = lse_ref[0, 0, at, :], delta_ref[0, 0, at, :]
+        dq = dq_acc[at, :]
+        for c0, cols, gap in parts:
+            k = k_ref[0, 0, c0:c0 + cols, :].astype(dot_dtype)
+            v = v_ref[0, 0, c0:c0 + cols, :].astype(dot_dtype)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale
+            if gap is not None:
+                s = _mask(s, gap)
+            p = jnp.exp(s - lse)
+            if gap is not None:
+                p = jnp.where(s > 0.5 * _NEG_BIG, p, 0.0)
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            ds = p * (dp - delta) * scale
+            dq = dq + jax.lax.dot_general(
+                ds.astype(dot_dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32
+            )
+        dq_acc[at, :] = dq
 
-    def _body():
-        q = q_ref[0, 0].astype(dot_dtype)
-        k = k_ref[0, 0].astype(dot_dtype)
-        v = v_ref[0, 0].astype(dot_dtype)
-        do = do_ref[0, 0].astype(dot_dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
-            q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_BIG)
-        p = jnp.exp(s - lse_ref[0, 0])
-        if causal:
-            p = jnp.where(s > 0.5 * _NEG_BIG, p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_ref[0, 0]) * scale
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(dot_dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32
-        )
-
-    if causal:
-        pl.when(q_lo + block_q - 1 >= k_lo)(_body)
-    else:
-        _body()
+    gap, crossing = _gap(q_offset, k_offset, block_q, block_k, nq, nk, 2, 3)
+    _walk(_row, causal, gap, crossing, block_q, block_k)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -286,7 +408,7 @@ def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc, dv_acc,
     *, scale: float, causal: bool, q_offset: int, k_offset: int,
-    block_q: int, block_k: int, nq: int, dot_dtype,
+    block_q: int, block_k: int, nq: int, nk: int, dot_dtype,
 ):
     iq = pl.program_id(3)
 
@@ -295,42 +417,40 @@ def _bwd_dkv_kernel(
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    ik = pl.program_id(2)
-    q_lo = q_offset + iq * block_q
-    k_lo = k_offset + ik * block_k
+    def _col(c0, cols, parts):
+        ks = slice(c0, c0 + cols)
+        k = k_ref[0, 0, ks, :].astype(dot_dtype)
+        v = v_ref[0, 0, ks, :].astype(dot_dtype)
+        dk, dv = dk_acc[ks, :], dv_acc[ks, :]
+        for r0, rows, gap in parts:
+            at = slice(r0, r0 + rows)
+            q = q_ref[0, 0, at, :].astype(dot_dtype)
+            do = do_ref[0, 0, at, :].astype(dot_dtype)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale
+            if gap is not None:
+                s = _mask(s, gap)
+            p = jnp.exp(s - lse_ref[0, 0, at, :])  # [rows, cols]
+            if gap is not None:
+                p = jnp.where(s > 0.5 * _NEG_BIG, p, 0.0)
+            dv = dv + jax.lax.dot_general(
+                p.astype(dot_dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32
+            )
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            ds = p * (dp - delta_ref[0, 0, at, :]) * scale  # [rows, cols]
+            dk = dk + jax.lax.dot_general(
+                ds.astype(dot_dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32
+            )
+        dk_acc[ks, :] = dk
+        dv_acc[ks, :] = dv
 
-    def _body():
-        q = q_ref[0, 0].astype(dot_dtype)
-        k = k_ref[0, 0].astype(dot_dtype)
-        v = v_ref[0, 0].astype(dot_dtype)
-        do = do_ref[0, 0].astype(dot_dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
-            q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_BIG)
-        p = jnp.exp(s - lse_ref[0, 0])  # [bq, bk]
-        if causal:
-            p = jnp.where(s > 0.5 * _NEG_BIG, p, 0.0)
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(dot_dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_ref[0, 0]) * scale  # [bq, bk]
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(dot_dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32
-        )
-
-    if causal:
-        pl.when(q_lo + block_q - 1 >= k_lo)(_body)
-    else:
-        _body()
+    gap, crossing = _gap(q_offset, k_offset, block_q, block_k, nq, nk, 3, 2)
+    _walk(_col, causal, gap, crossing, block_q, block_k, keys_outer=True)
 
     @pl.when(iq == nq - 1)
     def _finish():
@@ -365,7 +485,7 @@ def _bwd(
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, scale=scale, causal=causal,
-            q_offset=q_offset, k_offset=k_offset, block_q=bq, block_k=bk, nk=nk,
+            q_offset=q_offset, k_offset=k_offset, block_q=bq, block_k=bk, nq=nq, nk=nk,
             dot_dtype=dot_dtype,
         ),
         grid=(b, h, nq, nk),
@@ -384,7 +504,7 @@ def _bwd(
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, causal=causal,
-            q_offset=q_offset, k_offset=k_offset, block_q=bq, block_k=bk, nq=nq,
+            q_offset=q_offset, k_offset=k_offset, block_q=bq, block_k=bk, nq=nq, nk=nk,
             dot_dtype=dot_dtype,
         ),
         grid=(b, h, nk, nq),

@@ -86,6 +86,18 @@ def test_flash_attention_fwd_bwd_gpt_train_shape(chip):
     assert _compile(chip, jax.grad(loss, argnums=(0, 1, 2)), *qkv) == 3
 
 
+def test_flash_attention_fwd_bwd_gpt_default_length(chip):
+    """GPT's default length of 2,048: a grid of 2 x 2 blocks of 1,024, whose
+    steps the kernels classify by program id (the diagonal's two walked in
+    sub-tiles, the one below it whole, the one above skipped)."""
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(F32).sum()
+
+    qkv = [((8, 2048, 16, 64), BF16)] * 3
+    assert _compile(chip, jax.grad(loss, argnums=(0, 1, 2)), *qkv) == 3
+
+
 def test_fused_bottleneck_identity_stage4(chip):
     # hw7, 2048 -> 512 -> 2048: the widest weights (the VMEM-binding stage)
     shapes = _bottleneck_shapes(256, 7, 2048, 512, 2048, proj=False)

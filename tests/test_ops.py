@@ -154,6 +154,140 @@ def test_partial_offset_fully_masked_rows():
         np.testing.assert_allclose(a, r, atol=5e-4, rtol=5e-4, err_msg=f"d{name}")
 
 
+@pytest.mark.parametrize(
+    "length,block,q_offset,k_offset,causal",
+    [
+        (1024, None, 0, 0, True),    # the training cell's one 1,024 block: 4 x 4 sub-tiles
+        (512, 256, 0, 0, True),      # a grid of 2 x 2 blocks, one sub-tile each
+        (1024, 512, 0, 0, True),     # a grid of 2 x 2 blocks of 2 x 2 sub-tiles
+        (512, None, 256, 0, True),   # queries a sub-tile on: whole sub-tiles below
+        (512, None, 0, 256, True),   # keys a sub-tile on: whole sub-tiles above
+        (512, None, 0, 100, True),   # the diagonal inside sub-tiles
+        (512, 256, 37, 300, True),   # ... and inside a grid of blocks: crossing steps whole
+        (512, None, 0, 0, False),
+        (2048, None, 0, 0, True),    # GPT's default length: 2 x 2 blocks of 1,024
+        (512, (128, 256), 0, 0, True),  # blocks that are not square: crossing steps whole
+    ],
+)
+def test_causal_sub_tiles_match_reference(length, block, q_offset, k_offset, causal):
+    """Forward and the three gradients against the XLA reference, where
+    the kernels skip, mask or take whole the sub-tiles of a block."""
+    q, k, v = _rand_qkv(jax.random.PRNGKey(8), 1, length, length, 2, 32)
+    block_q, block_k = block if isinstance(block, tuple) else (block, block)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
+                               k_offset=k_offset, block_q=block_q, block_k=block_k)
+
+    def ref(q, k, v):
+        if causal:
+            return _offset_reference(q, k, v, q_offset, k_offset)
+        return full_attention(q, k, v)
+
+    np.testing.assert_allclose(flash(q, k, v), ref(q, k, v), atol=2e-5, rtol=2e-5)
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), argnums=(0, 1, 2))(q, k, v)
+    for a, r, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(a, r, atol=5e-4, rtol=5e-4, err_msg=f"d{name}")
+
+
+def _walked(gap, block_q, block_k, keys_outer=False):
+    """The groups a causal grid step at a static ``gap`` walks, as
+    ``(o0, size, parts)``."""
+    from kubeflow_tpu.ops.flash_attention import _walk
+
+    groups = []
+    _walk(lambda *g: groups.append(g), True, gap, gap, block_q, block_k,
+          keys_outer=keys_outer)
+    return groups
+
+
+def test_sub_tile_class_matches_the_elementwise_mask():
+    """Skipped, plain or masked, read off every element's mask, for static
+    positions and for traced ones alike."""
+    from kubeflow_tpu.ops.flash_attention import _sub_tile_class
+
+    for rows, cols in ((8, 8), (8, 16), (16, 8), (24, 24)):
+        for q_lo in range(0, 50, 3):
+            for k_lo in range(0, 50, 5):
+                mask = (q_lo + np.arange(rows))[:, None] >= (k_lo + np.arange(cols))[None, :]
+                want = (bool(mask.any()), bool(mask.all()))
+                assert _sub_tile_class(q_lo - k_lo, rows, cols) == want, (q_lo, k_lo)
+                traced = _sub_tile_class(jnp.int32(q_lo - k_lo), rows, cols)
+                assert tuple(bool(x) for x in traced) == want, (q_lo, k_lo)
+
+
+def test_gap_of_a_crossing_step(monkeypatch):
+    """Over every step of a grid, ``_gap``'s gap is the first query's
+    position less the first key's, and a step the diagonal crosses has the
+    crossing gap it names; it names none unless the blocks are square and
+    their offsets differ by whole blocks."""
+    import importlib
+    import itertools
+    from types import SimpleNamespace
+
+    fa = importlib.import_module("kubeflow_tpu.ops.flash_attention")
+
+    for (bq, bk), q_offset, k_offset in itertools.product(
+            ((256, 256), (128, 256), (256, 128)), (0, 256, 37), (0, 512, 300)):
+        for iq, ik in itertools.product(range(3), range(3)):
+            monkeypatch.setattr(fa, "pl", SimpleNamespace(program_id={2: iq, 3: ik}.get))
+            gap, crossing = fa._gap(q_offset, k_offset, bq, bk, 3, 3, 2, 3)
+            assert gap == q_offset + iq * bq - k_offset - ik * bk
+            square = bq == bk and (q_offset - k_offset) % bq == 0
+            assert (crossing is not None) == square, (bq, bk, q_offset, k_offset)
+            seen, full = fa._sub_tile_class(gap, bq, bk)
+            if square and seen and not full:
+                assert gap == crossing, (bq, q_offset, k_offset, iq, ik)
+
+
+@pytest.mark.parametrize("keys_outer", [False, True])
+def test_walk_covers_what_the_mask_lets_through(keys_outer):
+    """The spans a causal block walks hold every visible position once, and
+    are masked exactly where they also hold a hidden one; a masked span is
+    one sub-tile, and a block the diagonal does not cross is one span."""
+    from kubeflow_tpu.ops.flash_attention import _SUB
+
+    for q_lo, k_lo in ((0, 0), (_SUB, 0), (0, _SUB), (0, 100), (37, 300), (2048, 0),
+                       (0, 2048)):
+        mask = (q_lo + np.arange(1024))[:, None] >= (k_lo + np.arange(1024))[None, :]
+        covered = np.zeros(mask.shape, int)
+        groups = _walked(q_lo - k_lo, 1024, 1024, keys_outer)
+        for o0, size, parts in groups:
+            for i0, span, gap in parts:
+                at = ((slice(i0, i0 + span), slice(o0, o0 + size)) if keys_outer
+                      else (slice(o0, o0 + size), slice(i0, i0 + span)))
+                masked = gap is not None
+                assert mask[at].any() and masked == (not mask[at].all()), (q_lo, k_lo, o0, i0)
+                assert span == _SUB or not masked
+                r0, c0 = (i0, o0) if keys_outer else (o0, i0)
+                assert not masked or gap == q_lo + r0 - k_lo - c0
+                covered[at] += 1
+        assert (covered <= 1).all() and covered[mask].all(), (q_lo, k_lo)
+        if mask.all() or not mask.any():
+            assert groups == ([(0, 1024, [(0, 1024, None)])] if mask.all() else [])
+
+
+def test_walk_at_the_training_cell_s_block():
+    """One 1,024 block on the diagonal in sub-tiles of 256: 10 of 16
+    computed, the 4 on the diagonal masked, the others of a row (of a
+    column for dk/dv) taken in one span."""
+    from kubeflow_tpu.ops.flash_attention import _SUB
+
+    assert _SUB == 256
+    assert _walked(0, 1024, 1024) == [
+        (0, 256, [(0, 256, 0)]),
+        (256, 256, [(0, 256, None), (256, 256, 0)]),
+        (512, 256, [(0, 512, None), (512, 256, 0)]),
+        (768, 256, [(0, 768, None), (768, 256, 0)])]
+    assert _walked(0, 1024, 1024, keys_outer=True) == [
+        (0, 256, [(0, 256, 0), (256, 768, None)]),
+        (256, 256, [(256, 256, 0), (512, 512, None)]),
+        (512, 256, [(512, 256, 0), (768, 256, None)]),
+        (768, 256, [(768, 256, 0)])]
+    assert _walked(0, 192, 192) == [(0, 192, [(0, 192, 0)])]  # not a multiple: one tile
+
+
 class TestAutoTiling:
     """_block_sizes auto-tiling (round-3: fixed 128x128 tiles ran the
     attention core at 8 TF/s on v5e; (512,1024) reaches ~23 TF/s)."""
