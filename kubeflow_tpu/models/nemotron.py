@@ -14,9 +14,10 @@ caches:
   last convolution inputs go back to the slot.
 - :func:`decode_step` — one token for every slot: the attention layers
   through ``paged_decode_attention``, each Mamba layer's state of every
-  LIVE row read and written once by ``ops/ssm.ssm_decode_update``; a row
-  that is not live (free, or a slot still filling between its chunks)
-  keeps its state and its convolution window as they were.
+  LIVE row read and written once by ``ops/ssm.ssm_decode_update``, whose
+  walk runs over the live rows alone; a row that is not live (free, or a
+  slot still filling between its chunks) costs the update nothing and keeps
+  its state and its convolution window as they were.
 
 Layer equations (``benchmark/reference/nemotron.py`` is the plain float32
 reading of the same), every layer ``x <- x + mixer(RMSNorm(x))``:

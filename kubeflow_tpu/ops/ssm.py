@@ -22,10 +22,14 @@ same size whatever the slot's length: it is carried, not paged.
   (``ssm_decode_update``): each LIVE slot's state is fetched from HBM once,
   decayed, updated, contracted with ``C``, and written back once, in place
   (the output aliases the input); a row that is not live is neither read nor
-  written. The live rows are put first (``order``, prefetched with their
-  count), the grid walks (row, group) and fetches the next live group's state
-  while it works on this one. Bound by bytes: 4.2 MB a slot and layer at the
-  published widths, read and written.
+  written. The live rows are put first (``order``, in SMEM with their
+  count) and the kernel is one step: a loop over the ``count`` live rows,
+  each row's groups in turn, that fetches the next live group's state while
+  it works on this one, so no work at all falls to a dead row. ``decay``,
+  ``u``, ``B``, ``C`` and ``y`` sit whole in VMEM, laid out so a row is a
+  leading index and a head a lane (``y`` zeroed once, a live row's entries
+  written). Bound by bytes: 4.2 MB a slot and layer at the published widths,
+  read and written.
 """
 
 from __future__ import annotations
@@ -79,13 +83,11 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array, C: jax.Arr
     return y.reshape(T, H, P), last.reshape(H, P, N)
 
 
-def _decode_kernel(order_ref, count_ref,                       # prefetched scalars
-                   decay_ref, u_ref, b_ref, c_ref, state_hbm,
+def _decode_kernel(order_ref, count_ref, decay_ref, u_ref, b_ref, c_ref, state_hbm,
                    state_out, y_ref, buf, sems):
-    i, g = pl.program_id(0), pl.program_id(1)
-    groups = pl.num_programs(1)
-    k = i * groups + g
-    total = count_ref[0] * groups
+    groups, hg = b_ref.shape[1], buf.shape[1]
+    total = count_ref[0] * groups                                # live (row, group) pairs
+    y_ref[...] = jnp.zeros_like(y_ref)
 
     def place(kk):
         return order_ref[kk // groups], kk % groups
@@ -98,13 +100,8 @@ def _decode_kernel(order_ref, count_ref,                       # prefetched scal
         row, grp = place(kk)
         return pltpu.make_async_copy(buf.at[slot], state_out.at[row, grp], sems.at[1, slot])
 
-    @pl.when(k < total)
-    def _live():
+    def group(k, row, g, decay, u, b, c):
         slot = jax.lax.rem(k, 2)
-
-        @pl.when(k == 0)
-        def _first():
-            fetch(k, slot).start()
 
         @pl.when(k + 1 < total)
         def _next():
@@ -116,24 +113,31 @@ def _decode_kernel(order_ref, count_ref,                       # prefetched scal
             fetch(k + 1, 1 - slot).start()
 
         fetch(k, slot).wait()
-        decay, u = decay_ref[0, 0], u_ref[0, 0]                    # [1, hg], [P, hg]
-        b, c = b_ref[0, 0], c_ref[0, 0]                            # [1, N]
-        for j in range(u.shape[1]):
-            s = buf[slot, j] * decay[:, j:j + 1] + u[:, j:j + 1] * b   # [P, N]
+        for j in range(hg):
+            h = g * hg + j
+            s = buf[slot, j] * decay[:, h:h + 1] + u[:, h:h + 1] * b   # [P, N]
             buf[slot, j] = s
-            y_ref[0, 0, :, j:j + 1] = jnp.sum(s * c, axis=-1, keepdims=True)
+            y_ref[row, :, h:h + 1] = jnp.sum(s * c, axis=-1, keepdims=True)
         store(k, slot).start()
 
-        @pl.when(k + 1 == total)
-        def _last():
-            @pl.when(k > 0)
-            def _():
-                store(k - 1, 1 - slot).wait()
-            store(k, slot).wait()
+    def live_row(i, carry):
+        row = order_ref[i]
+        decay, u = decay_ref[row], u_ref[row]                      # [1, H], [P, H]
+        b, c = b_ref[row], c_ref[row]                              # [G, N]
+        for g in range(groups):
+            group(i * groups + g, row, g, decay, u, b[g:g + 1], c[g:g + 1])
+        return carry
 
-    @pl.when(k >= total)
-    def _dead():
-        y_ref[...] = jnp.zeros_like(y_ref)
+    @pl.when(total > 0)
+    def _walk():
+        fetch(0, 0).start()
+        jax.lax.fori_loop(0, count_ref[0], live_row, 0)
+        last = total - 1
+
+        @pl.when(last > 0)
+        def _():
+            store(last - 1, 1 - jax.lax.rem(last, 2)).wait()
+        store(last, jax.lax.rem(last, 2)).wait()
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -141,30 +145,22 @@ def _decode_call(state, decay, u, B, C, live, interpret: bool):
     S, G, hg, P, N = state.shape
     order = jnp.argsort(~live, stable=True).astype(jnp.int32)      # live rows first
     count = jnp.sum(live, dtype=jnp.int32)[None]
-    at = lambda i, g, order, count: (order[i], g, 0, 0)
-    small = lambda shape: pl.BlockSpec((1, 1) + shape, at)
+    smem, vmem = pltpu.MemorySpace.SMEM, pltpu.MemorySpace.VMEM
     state, y = pl.pallas_call(
         _decode_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(S, G),
-            in_specs=[small((1, hg)), small((P, hg)), small((1, N)), small((1, N)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=[pl.BlockSpec(memory_space=pl.ANY), small((P, hg))],
-            scratch_shapes=[pltpu.VMEM((2, hg, P, N), jnp.float32),
-                            pltpu.SemaphoreType.DMA((2, 2))],
-        ),
+        in_specs=[pl.BlockSpec(memory_space=smem), pl.BlockSpec(memory_space=smem)]
+                 + [pl.BlockSpec(memory_space=vmem)] * 4
+                 + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=vmem)],
+        scratch_shapes=[pltpu.VMEM((2, hg, P, N), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2, 2))],
         out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),
-                   jax.ShapeDtypeStruct((S, G, P, hg), jnp.float32)],
+                   jax.ShapeDtypeStruct((S, P, G * hg), jnp.float32)],
         input_output_aliases={6: 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="ssm_decode_update",
-    )(order, count, decay.reshape(S, G, 1, hg),
-      jnp.swapaxes(u.reshape(S, G, hg, P), 2, 3), B.reshape(S, G, 1, N),
-      C.reshape(S, G, 1, N), state)
-    return state, jnp.swapaxes(y, 2, 3).reshape(S, G * hg, P)
+    )(order, count, decay.reshape(S, 1, G * hg), jnp.swapaxes(u, 1, 2), B, C, state)
+    return state, jnp.swapaxes(y, 1, 2)
 
 
 def ssm_decode_update(state: jax.Array, decay: jax.Array, u: jax.Array, B: jax.Array,
@@ -175,7 +171,10 @@ def ssm_decode_update(state: jax.Array, decay: jax.Array, u: jax.Array, B: jax.A
     ``u`` [S, H, P] = ``dt x``; ``B``, ``C`` [S, G, N]; ``live`` [S] bool.
     Returns (the state, updated in place where live and untouched where not:
     the buffer is donated, and a dead row's bytes are never fetched or
-    written; y [S, H, P] = ``S_t C_t`` of the live rows, zeros elsewhere)."""
+    written; y [S, H, P] = ``S_t C_t`` of the live rows, zeros elsewhere).
+    The walk's trip count is the live count: a dead row costs no loop trip,
+    no DMA and no store; what grows with the slots is one copy a call of the
+    small inputs and of ``y`` (about 4.6 MB at 64 slots of the published widths)."""
     return _decode_call(state, decay.astype(jnp.float32), u.astype(jnp.float32),
                         B.astype(jnp.float32), C.astype(jnp.float32), live.astype(bool),
                         _interpret_default() if interpret is None else interpret)

@@ -22,6 +22,7 @@ from kubeflow_tpu.ops.fused_bottleneck import fused_bottleneck, fused_transition
 from kubeflow_tpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
 from kubeflow_tpu.ops.head_choice import head_choice
 from kubeflow_tpu.ops.paged_attention import paged_decode_attention
+from kubeflow_tpu.ops.ssm import ssm_decode_update
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -528,6 +529,20 @@ def test_sarvam_programs_fit_the_chip_and_copy_no_arena(chip, monkeypatch):
     text = chunk.as_text()
     assert kernel("latent_prefill_attention") == cfg.n_layers
     assert chunk.memory_analysis().temp_size_in_bytes < 1.4e9
+
+
+def test_ssm_decode_update_nemotron_shape(chip):
+    """The Mamba layers' decode update as the step calls it: 64 slots of 8
+    groups of 16 heads, each a float32 state of 64 x 128 (4.2 MB a slot),
+    the state left in HBM and its live rows walked by DMA; ``decay``, ``u``,
+    ``B``, ``C`` and ``y`` whole in VMEM (about 4.8 MB with their tiles'
+    padding)."""
+    def update(state, decay, u, B, C, live):
+        return ssm_decode_update(state, decay, u, B, C, live, interpret=False)
+
+    assert _compile(chip, update, ((64, 8, 16, 64, 128), F32), ((64, 128), F32),
+                    ((64, 128, 64), F32), ((64, 8, 128), F32), ((64, 8, 128), F32),
+                    ((64,), jnp.bool_)) == 1
 
 
 def test_nemotron_programs_fit_the_chip_and_copy_no_state(chip, monkeypatch):

@@ -270,17 +270,22 @@ def test_the_chunked_scan_equals_the_recurrence(chunk):
     np.testing.assert_allclose(last, want_last, rtol=1e-5, atol=1e-5)
 
 
-def test_the_decode_update_kernel_equals_the_update_and_skips_dead_rows():
+@pytest.mark.parametrize("pattern", [
+    [False] * 5, [False, False, True, False, False], [False, False, False, True, True],
+    [True] * 5, [True, False, True, True, False]],
+    ids=["none_live", "one_live", "live_at_the_end", "every_row_live", "scattered"])
+def test_the_decode_update_kernel_equals_the_update_and_skips_dead_rows(pattern):
     """Interpreted off the TPU: live rows' states decayed, updated and
     contracted as a plain update does; dead rows' states bit for bit as
-    they were, and their outputs zero."""
+    they were, and their outputs zero (with no row live the walk is empty:
+    every state comes back as it was and ``y`` is all zeros)."""
     k = jax.random.split(jax.random.PRNGKey(1), 6)
     S, G, hg, P, N = 5, 2, 4, 16, 128
     state = jax.random.normal(k[0], (S, G, hg, P, N))
     decay = jnp.exp(-jax.random.uniform(k[1], (S, G * hg)))
     u = jax.random.normal(k[2], (S, G * hg, P))
     B, C = jax.random.normal(k[3], (S, G, N)), jax.random.normal(k[4], (S, G, N))
-    live = jnp.asarray([True, False, True, True, False])
+    live = jnp.asarray(pattern)
     new, y = ssm_decode_update(state, decay, u, B, C, live, interpret=True)
     flat = state.reshape(S, G * hg, P, N)
     want = (decay[:, :, None, None] * flat
